@@ -14,6 +14,7 @@ import json
 import os
 
 from benchmarks.common import RESULTS_DIR, emit, save
+from repro.launch.cache import enable_compile_cache
 from repro.roofline.analysis import HW
 
 
@@ -90,4 +91,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -95,6 +95,7 @@ from benchmarks.common import RESULTS_DIR, emit, save
 from repro.core import AveragingSchedule, PhaseEngine
 from repro.data import convex_dataset
 from repro.data.pipeline import DeviceDataset, WorkerSharder
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_worker_mesh
 from repro.optim import SGD, Momentum
 from repro.telemetry import JsonlSink, run_meta_record
@@ -977,6 +978,7 @@ def run(tiny: bool = False, workers_override: int | None = None,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--save", action="store_true",

@@ -6,6 +6,7 @@ import numpy as np
 
 from benchmarks.common import emit, save, timeit
 from repro.configs.paper import PCAConfig
+from repro.launch.cache import enable_compile_cache
 
 
 def pca_error_vs_avg_steps(cfg: PCAConfig, phase_lens, seed=0):
@@ -47,4 +48,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -21,6 +21,7 @@ from repro.core import AveragingSchedule, PhaseEngine
 from repro.data import DeviceDataset, convex_dataset
 from repro.models.convex import lr_objective, ls_objective, solve_optimum
 from repro.optim import SGD
+from repro.launch.cache import enable_compile_cache
 
 
 def _schedule(phase_len: int) -> AveragingSchedule:
@@ -140,4 +141,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -19,6 +19,7 @@ from repro.data import mnist_like
 from repro.data.pipeline import DeviceDataset
 from repro.models.cnn import cnn_error, cnn_loss, init_cnn
 from repro.optim import Momentum, schedules
+from repro.launch.cache import enable_compile_cache
 
 
 def run_cnn(cfg: CNNConfig, steps: int, *, seed=0, record_every=25,
@@ -99,4 +100,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

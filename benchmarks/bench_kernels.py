@@ -13,6 +13,7 @@ from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels.rwkv6_scan import rwkv6_scan
 from repro.kernels.ref import (flash_attention_ref, rglru_scan_ref,
                                rwkv6_scan_ref)
+from repro.launch.cache import enable_compile_cache
 
 
 def run():
@@ -49,4 +50,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -6,6 +6,7 @@ from __future__ import annotations
 from benchmarks.common import emit, save, timeit
 from repro.configs.paper import QuadraticConfig
 from repro.core.theory import lemma1_asymptotic_variance, simulate_quadratic
+from repro.launch.cache import enable_compile_cache
 
 
 def run():
@@ -29,4 +30,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import emit, save, timeit
 from repro.configs.paper import QuarticConfig
+from repro.launch.cache import enable_compile_cache
 
 
 def run_quartic(cfg: QuarticConfig, avg_fracs, seed=0):
@@ -50,4 +51,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

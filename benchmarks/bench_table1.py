@@ -11,6 +11,7 @@ from repro.configs.paper import CONVEX_SUITE
 from repro.core.variance_model import empirical_variance_fn, measure_beta2, rho
 from repro.data import convex_dataset
 from repro.models.convex import solve_optimum as _w_star_impl
+from repro.launch.cache import enable_compile_cache
 
 
 def _w_star(kind, X, y):
@@ -42,4 +43,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

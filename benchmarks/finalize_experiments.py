@@ -7,6 +7,7 @@ import re
 
 from benchmarks.averaging_cost import analyze
 from benchmarks.roofline_table import load, render
+from repro.launch.cache import enable_compile_cache
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MD = os.path.join(ROOT, "EXPERIMENTS.md")
@@ -74,4 +75,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

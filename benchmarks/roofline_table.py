@@ -8,6 +8,7 @@ import os
 
 from benchmarks.common import RESULTS_DIR
 from repro.roofline import HW
+from repro.launch.cache import enable_compile_cache
 
 HDR = ("| arch | shape | mesh | avg | variant | flops/dev | bytes/dev | "
        "coll B/dev | compute s | memory s | coll s | bound | "
@@ -221,6 +222,7 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print(render())
     print()
     print(render_avg_disp())

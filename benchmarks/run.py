@@ -6,6 +6,8 @@ from __future__ import annotations
 import sys
 import traceback
 
+from repro.launch.cache import enable_compile_cache
+
 
 def main() -> None:
     from benchmarks import (averaging_cost, bench_fig1_pca,
@@ -37,4 +39,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

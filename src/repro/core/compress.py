@@ -142,7 +142,8 @@ def quantize(v, wire: str, *, u=None):
     if wire == "f32":
         return v
     if wire == "bf16":
-        return v.astype(jnp.bfloat16).astype(jnp.float32)
+        # explicit: the TPU compiler may skip an astype round trip
+        return jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
     if wire == "int8":
         assert u is not None, "int8 stochastic rounding needs row_uniforms"
         amax = jnp.max(jnp.abs(v), axis=1, keepdims=True)

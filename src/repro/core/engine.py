@@ -31,6 +31,10 @@ Two device-residency layers sit on top of the PR 1 scan:
   momentum step — instead of 3–4 params-pytree traversals
   (``repro.kernels.avg_disp`` on TPU, its jnp twin on CPU). Trees with
   dtypes that have no exact float32 image fall back to the tree path.
+  :meth:`PhaseEngine.run` packs the state into that plane form once
+  (:meth:`PhaseEngine.start_state`) and carries the planes from phase
+  to phase, so a full-width model never holds a tree copy of its
+  params beside the planes during a phase.
 - **On-device data plane**: :meth:`run` accepts a
   :class:`repro.data.pipeline.DeviceDataset` — the dataset lives on
   device, the driver ships (K, M, B) int32 index blocks, and the scan
@@ -77,7 +81,6 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.averaging import (AveragingSchedule, OuterOptimizer,
@@ -215,7 +218,9 @@ class PhaseEngine:
     fused ``opt_step`` pass (update + optional average + Eq. 4
     dispersion + broadcast) — zero per-step pack/unpack.
     ``kernel_impl`` picks the fused implementation: "auto" (jnp
-    reference on CPU, Pallas/Mosaic elsewhere), "ref", or "pallas".
+    reference on CPU; the Pallas kernels, compiled by Mosaic, on a TPU
+    — ``chip_smoke.py`` checks that a v5e phase holds them), "ref", or
+    "pallas".
 
     ``mesh`` shards the phase over a device mesh via ``shard_map``: the
     plane's worker axis M is split over the mesh's worker axes
@@ -746,7 +751,7 @@ class PhaseEngine:
         return wp, outer_c, disp
 
     # ---- the compiled phase ---------------------------------------------
-    def _phase(self, state: EngineState, xs, fetch):
+    def _phase(self, state: EngineState, xs, fetch, layout=None):
         """Trace the whole phase: scan the K entries of ``xs``
         (pre-staged batches, or index blocks that ``fetch`` gathers
         on-device), averaging fused per the schedule. Returns the new
@@ -754,11 +759,15 @@ class PhaseEngine:
         host transfer a phase needs.
 
         Three carries, picked per (flat, optimizer) support:
-          flat-native — params AND optimizer state as (M, P) planes,
-            grads via one vjp through the unpacked view, every step one
-            fused opt_step pass (zero per-step pack/unpack);
-          flat        — params plane with per-step pack/unpack around the
-            tree-mapped optimizer (optimizers without plane support);
+          flat-native — the state in plane form (:meth:`to_planes`,
+            ``layout`` from :meth:`plane_layout`): params AND optimizer
+            state as (M, P) planes, grads via one vjp through the
+            unpacked view, every step one fused opt_step pass; the phase
+            takes and returns planes, so no tree copy of the params
+            sits beside them;
+          flat        — params plane packed on entry, with per-step
+            pack/unpack around the tree-mapped optimizer (optimizers
+            without plane support);
           tree        — params pytree carry (dtypes FlatSpec can't
             embed)."""
         num_workers = jax.tree.leaves(state.worker_params)[0].shape[0]
@@ -766,13 +775,19 @@ class PhaseEngine:
         self._check_compressible(state.worker_params)
         sched = self.schedule
         comp = self._comp()
-        use_flat = self.flat and FlatSpec.supports(state.worker_params)
-        # compressed events encode on the plane even in the tree carry
-        # (pack/unpack around the event only — events are rare)
-        spec = (FlatSpec.of(state.worker_params)
-                if use_flat or comp is not None else None)
-        opt_spec = self._opt_spec(spec, state.opt_state) if use_flat else None
-        flat_native = opt_spec is not None
+        flat_native = layout is not None
+        if flat_native:
+            spec = layout[0]
+            use_flat = True
+        else:
+            assert self.plane_layout(state) is None, \
+                "a flat-native state runs in plane form: pass " \
+                "start_state()'s state and layout"
+            use_flat = self.flat and FlatSpec.supports(state.worker_params)
+            # compressed events encode on the plane even in the tree
+            # carry (pack/unpack around the event only — events are rare)
+            spec = (FlatSpec.of(state.worker_params)
+                    if use_flat or comp is not None else None)
         p_width = (spec.width if spec is not None else
                    sum(x.size // num_workers
                        for x in jax.tree.leaves(state.worker_params)))
@@ -781,10 +796,13 @@ class PhaseEngine:
         eb_all, eb_inner = (self._event_bytes(p_width, num_workers)
                             if tm is not None else (0.0, 0.0))
 
-        if use_flat:
+        if flat_native:
+            carry_p, carry_s = state.worker_params, state.opt_state
+            carry_o = state.outer_state
+            average = self._flat_average
+        elif use_flat:
             carry_p = spec.pack(state.worker_params)
-            carry_s = (opt_spec.pack(state.opt_state) if flat_native
-                       else state.opt_state)
+            carry_s = state.opt_state
             carry_o = ()
             if self.outer is not None and state.outer_state != ():
                 prev_avg, vel = state.outer_state
@@ -963,9 +981,8 @@ class PhaseEngine:
             (loss, disp, code) = \
             jax.lax.scan(body, carry0, xs, unroll=self.scan_unroll)
 
-        if use_flat:
-            wp = spec.unpack(wp_c)
-            opt_state = opt_spec.unpack(opt_c) if flat_native else opt_c
+        if use_flat and not flat_native:
+            wp, opt_state = spec.unpack(wp_c), opt_c
             outer_state = state.outer_state
             if carry_o != ():
                 outer_state = (spec.unpack1(outer_c[0]),
@@ -1188,13 +1205,20 @@ class PhaseEngine:
                 spec, args[0], args[1], "all", glob, ml, W=W,
                 alive=alive, alive_full=alive_full) + (args[2],)
 
+        # only hierarchical schedules emit the inner code; elsewhere its
+        # branch (an all_gather of the whole plane) is never taken, and
+        # tracing it would still reserve M·P bytes on every device
+        if sched.kind != "hierarchical":
+            inner_branch = none_branch
         plane, outer_c, resid = jax.lax.switch(
             code, [none_branch, inner_branch, all_branch],
             (plane, outer_c, resid))
         return plane, planes, outer_c, resid, sst, disp, code
 
-    def _phase_sharded(self, state: EngineState, xs, fetch, m_global: int):
-        """The phase body as run on ONE shard under shard_map.
+    def _phase_sharded(self, state: EngineState, xs, fetch, m_global: int,
+                       layout):
+        """The phase body as run on ONE shard under shard_map, on the
+        plane-form state (``layout`` as in :meth:`_phase`).
 
         ``collective="psum"`` (production): the local (M_l, P) slice of
         the plane scans through K fused local steps; averaging events
@@ -1216,23 +1240,17 @@ class PhaseEngine:
         per step; use gather to validate a mesh, psum to scale."""
         sched = self.schedule
         self._check_workers(m_global)
-        assert self.flat and FlatSpec.supports(state.worker_params), \
-            "sharded runs require the flat (M, P) plane carry"
+        assert layout is not None, \
+            "sharded runs require the flat (M, P) plane carry and a " \
+            "plane-protocol optimizer (SGD/Momentum/AdamW) with " \
+            "fused_opt=True"
         assert self.collective in ("psum", "gather"), self.collective
-        spec = FlatSpec.of(state.worker_params)
-        opt_spec = self._opt_spec(spec, state.opt_state)
-        assert opt_spec is not None, \
-            "sharded runs need a plane-protocol optimizer (SGD/Momentum/" \
-            "AdamW) and fused_opt=True"
+        spec = layout[0]
         self._check_compressible(state.worker_params)
         comp = self._comp()
-        ml = jax.tree.leaves(state.worker_params)[0].shape[0]
-        carry_p = spec.pack(state.worker_params)
-        carry_s = opt_spec.pack(state.opt_state)
-        carry_o = ()
-        if self.outer is not None and state.outer_state != ():
-            prev_avg, vel = state.outer_state
-            carry_o = (spec.pack1(prev_avg), spec.pack1(vel))
+        ml = state.worker_params.shape[0]
+        carry_p, carry_s = state.worker_params, state.opt_state
+        carry_o = state.outer_state
         grads_fn = make_plane_step(self.loss_fn, spec)
         ax = self._worker_axes()
         i0 = self._shard_index() * ml
@@ -1380,13 +1398,7 @@ class PhaseEngine:
             (loss, disp, code) = \
             jax.lax.scan(body, carry0, xs, unroll=self.scan_unroll)
 
-        wp = spec.unpack(wp_c)
-        opt_state = opt_spec.unpack(opt_c)
-        outer_state = state.outer_state
-        if carry_o != ():
-            outer_state = (spec.unpack1(outer_c[0]),
-                           spec.unpack1(outer_c[1], dtypes=jnp.float32))
-        new_state = EngineState(wp, opt_state, outer_state, key,
+        new_state = EngineState(wp_c, opt_c, outer_c, key,
                                 state.dec_key, step, sst, resid, fst)
         trace = {"loss": loss, "dispersion": disp, "avg_code": code}
         if tm is not None:
@@ -1412,55 +1424,137 @@ class PhaseEngine:
             specs["metrics"] = P()
         return specs
 
-    def shard_state(self, state: EngineState) -> EngineState:
-        """Place an EngineState onto the mesh: worker-axis leaves split
-        over the worker axes (``repro.sharding.specs.plane_sharding``
-        layout), the rest replicated."""
-        from repro.sharding.specs import engine_state_sharding
-        return jax.device_put(
-            state, engine_state_sharding(self.mesh, state,
-                                         axes=self._worker_axes()))
-
-    @partial(jax.jit, static_argnums=0, donate_argnums=1)
-    def run_phase(self, state: EngineState, batches):
+    @partial(jax.jit, static_argnums=0, static_argnames="layout",
+             donate_argnums=1)
+    def run_phase(self, state: EngineState, batches, layout=None):
         """One compiled dispatch over a pre-staged (K, M, ...) batch
-        block."""
+        block. ``layout`` (:meth:`plane_layout`) runs a plane-form
+        state (:meth:`to_planes`) and returns one."""
         if self.mesh is None:
-            return self._phase(state, batches, lambda b: b)
+            return self._phase(state, batches, lambda b: b, layout)
         m = jax.tree.leaves(state.worker_params)[0].shape[0]
         assert m % self._num_shards() == 0, (m, self._num_shards())
         sspec = self._state_specs(state)
         ax = self._worker_axes()
-        return shard_map(
-            lambda s, xs: self._phase_sharded(s, xs, lambda b: b, m),
+        return jax.shard_map(
+            lambda s, xs: self._phase_sharded(s, xs, lambda b: b, m,
+                                              layout),
             mesh=self.mesh,
             in_specs=(sspec, jax.tree.map(lambda _: P(None, ax), batches)),
             out_specs=(sspec, self._trace_specs()),
-            check_rep=False)(state, batches)
+            check_vma=False)(state, batches)
 
-    @partial(jax.jit, static_argnums=0, donate_argnums=1)
-    def run_phase_indexed(self, state: EngineState, dataset, idx_block):
+    @partial(jax.jit, static_argnums=0, static_argnames="layout",
+             donate_argnums=1)
+    def run_phase_indexed(self, state: EngineState, dataset, idx_block,
+                          layout=None):
         """One compiled dispatch over a (K, M, B) int32 index block:
         batches are gathered from the device-resident ``dataset``
         INSIDE the scan (``jnp.take``), so the host ships only
-        indices."""
+        indices. ``layout`` as in :meth:`run_phase`."""
         def fetch_from(ds):
+            # indices are in range by construction: "clip" lowers to a
+            # bare gather, where the default "fill" adds a bounds select
+            # that changes how XLA:CPU emits the fused loss reduction
+            # (its last ulp then differs from the list-fed program's)
             return lambda idx: jax.tree.map(
-                lambda a: jnp.take(a, idx, axis=0), ds)
+                lambda a: jnp.take(a, idx, axis=0, mode="clip"), ds)
         if self.mesh is None:
-            return self._phase(state, idx_block, fetch_from(dataset))
+            return self._phase(state, idx_block, fetch_from(dataset),
+                               layout)
         m = jax.tree.leaves(state.worker_params)[0].shape[0]
         assert m % self._num_shards() == 0, (m, self._num_shards())
         sspec = self._state_specs(state)
         ax = self._worker_axes()
-        return shard_map(
+        return jax.shard_map(
             lambda s, ds, idx: self._phase_sharded(
-                s, idx, fetch_from(ds), m),
+                s, idx, fetch_from(ds), m, layout),
             mesh=self.mesh,
             in_specs=(sspec, jax.tree.map(lambda _: P(), dataset),
                       jax.tree.map(lambda _: P(None, ax), idx_block)),
             out_specs=(sspec, self._trace_specs()),
-            check_rep=False)(state, dataset, idx_block)
+            check_vma=False)(state, dataset, idx_block)
+
+    # ---- plane-form state (what run() carries between phases) ------------
+    def plane_layout(self, state: EngineState):
+        """(FlatSpec, FlatOptSpec) of the flat-native carry for this
+        (possibly abstract) state, or None where the phase carries a
+        tree (``flat=False``, dtypes FlatSpec cannot embed, optimizers
+        without the plane protocol)."""
+        if not (self.flat and FlatSpec.supports(state.worker_params)):
+            return None
+        spec = FlatSpec.of(state.worker_params)
+        opt_spec = self._opt_spec(spec, state.opt_state)
+        return None if opt_spec is None else (spec, opt_spec)
+
+    def to_planes(self, layout, state: EngineState) -> EngineState:
+        """The plane form of ``state``: params as the (M, P) f32 plane,
+        optimizer state as its S planes, outer state as (P,) vectors —
+        exactly the carry :meth:`_phase` scans. A full-width model then
+        holds ONE copy of its params across a phase, not a tree plus
+        the planes packed from it."""
+        spec, opt_spec = layout
+        outer = state.outer_state
+        if outer != ():
+            outer = (spec.pack1(outer[0]), spec.pack1(outer[1]))
+        return state._replace(worker_params=spec.pack(state.worker_params),
+                              opt_state=opt_spec.pack(state.opt_state),
+                              outer_state=outer)
+
+    def to_tree(self, layout, state: EngineState) -> EngineState:
+        """Inverse of :meth:`to_planes` (bit-exact)."""
+        spec, opt_spec = layout
+        outer = state.outer_state
+        if outer != ():
+            outer = (spec.unpack1(outer[0]),
+                     spec.unpack1(outer[1], dtypes=jnp.float32))
+        return state._replace(worker_params=spec.unpack(state.worker_params),
+                              opt_state=opt_spec.unpack(state.opt_state),
+                              outer_state=outer)
+
+    @partial(jax.jit, static_argnums=(0, 1))
+    def _to_planes(self, layout, state):
+        return self.to_planes(layout, state)
+
+    @partial(jax.jit, static_argnums=(0, 1))
+    def _to_tree(self, layout, state):
+        return self.to_tree(layout, state)
+
+    @partial(jax.jit, static_argnums=(0, 1))
+    def _params_tree(self, spec, plane):
+        return spec.unpack(plane)
+
+    def start_state(self, params, num_workers: int, seed: int = 0,
+                    state: EngineState | None = None):
+        """The state :meth:`run` carries: :meth:`init` (or the given
+        ``state``) in plane form where the flat-native path applies.
+        On a mesh it is built by one program whose output is already
+        split over the worker axes, so each device computes only its
+        own worker rows and none ever holds the whole state. Returns
+        (state, layout)."""
+        shardings = None
+        if self.mesh is not None:
+            from repro.sharding.specs import engine_state_sharding
+            shardings = lambda tree: engine_state_sharding(
+                self.mesh, tree, axes=self._worker_axes())
+        if state is None:
+            build, args = (lambda p: self.init(p, num_workers, seed)), \
+                (params,)
+        else:
+            if shardings is not None:
+                # a resumed (or resized) state may sit on another mesh
+                state = jax.device_put(state, shardings(state))
+            build, args = (lambda s: s), (state,)
+        layout = self.plane_layout(jax.eval_shape(build, *args))
+        if shardings is None:
+            state = build(*args)
+            if layout is not None:
+                state = self._to_planes(layout, state)
+            return state, layout
+        fn = build if layout is None else \
+            (lambda *a: self.to_planes(layout, build(*a)))
+        return jax.jit(fn, out_shardings=shardings(
+            jax.eval_shape(fn, *args)))(*args), layout
 
     def default_phase_len(self) -> int:
         """Compile-size heuristic: align phase blocks with the schedule's
@@ -1530,10 +1624,7 @@ class PhaseEngine:
                 "run(sink=...) flushes the on-device metrics "
                 "accumulator, which this engine does not carry — "
                 "construct it with PhaseEngine(..., telemetry=True)")
-        if state is None:
-            state = self.init(params, num_workers, seed)
-        if self.mesh is not None:
-            state = self.shard_state(state)
+        state, layout = self.start_state(params, num_workers, seed, state)
         t0 = int(state.step)
         block = phase_len or self.default_phase_len()
         needs_eval = bool(record_every and (eval_fn or worker_eval_fn))
@@ -1548,16 +1639,23 @@ class PhaseEngine:
                 take = min(take, total - t)
             return take
 
-        def unshard(tree):
+        def worker_params():
+            wp = state.worker_params
+            if layout is not None:
+                wp = self._params_tree(layout[0], wp)
+            return wp
+
+        def gathered(x):
             # a mesh-sharded worker axis is reassembled on the default
             # device so reductions over it (consensus) lower exactly
             # like the single-device engine's
             if self.mesh is None:
-                return tree
-            return jax.tree.map(lambda x: jnp.asarray(jax.device_get(x)),
-                                tree)
+                return x
+            # host-side, on concrete arrays (tree.map here is not a trace)
+            return jnp.asarray(jax.device_get(x))  # analysis: ignore[trace-purity]
 
         def cons(wp):
+            mean = consensus
             # under a fault plan the consensus is over alive workers
             # only — dead rows hold stale (or warm-start) parameters
             if (self._faults() is not None
@@ -1566,8 +1664,10 @@ class PhaseEngine:
                 # mid-curriculum (solo) rows stay out of the consensus,
                 # exactly as they stay out of averaging events
                 alive = self._faults().mix_at(alive, int(state.step))
-                return faults_mod.masked_mean_tree(wp, alive)
-            return consensus(wp)
+                mean = lambda x: faults_mod.masked_mean_tree(x, alive)
+            # one leaf at a time: no device ever holds every worker's
+            # params at once
+            return jax.tree.map(lambda x: mean(gathered(x)), wp)
 
         def consume(t, k, trace, tw0=None):
             # THE once-per-phase host sync: traces AND (telemetry mode)
@@ -1590,13 +1690,12 @@ class PhaseEngine:
                     hist["disp_trace"].append(
                         (t, float(trace["dispersion"][i])))
             if needs_eval and t % record_every == 0:
+                wp = worker_params()
                 if eval_fn is not None:
-                    hist["eval"].append(
-                        (t, eval_fn(cons(unshard(
-                            state.worker_params)))))
+                    hist["eval"].append((t, eval_fn(cons(wp))))
                 if worker_eval_fn is not None:
                     hist["worker_eval"].append(
-                        (t, worker_eval_fn(unshard(state.worker_params))))
+                        (t, worker_eval_fn(jax.tree.map(gathered, wp))))
             if sink is not None:
                 for t_ev, d_ev, c_ev in events:
                     sink.emit(make_record(
@@ -1616,6 +1715,13 @@ class PhaseEngine:
                     disp_trace=hist["disp_trace"][n_disp:], **flushed))
             return t
 
+        def finish():
+            final = cons(worker_params())
+            if not return_state:
+                return final, hist
+            return final, hist, (state if layout is None
+                                 else self._to_tree(layout, state))
+
         if isinstance(data, DeviceDataset):
             assert data.num_workers == num_workers, \
                 (data.num_workers, num_workers)
@@ -1632,12 +1738,10 @@ class PhaseEngine:
                 take = take_at(t)
                 tw0 = time.perf_counter()
                 idx = jnp.asarray(data.index_block(take))
-                state, trace = self.run_phase_indexed(state, data.arrays,
-                                                      idx)
+                state, trace = self.run_phase_indexed(
+                    state, data.arrays, idx, layout=layout)
                 t = consume(t, take, trace, tw0)
-            final = cons(unshard(state.worker_params))
-            return (final, hist, state) if return_state else (final,
-                                                              hist)
+            return finish()
 
         def staged_blocks():
             it = iter(data)
@@ -1666,13 +1770,12 @@ class PhaseEngine:
         try:
             for k, staged in (pf if pf is not None else staged_blocks()):
                 tw0 = time.perf_counter()
-                state, trace = self.run_phase(state, staged)
+                state, trace = self.run_phase(state, staged, layout=layout)
                 t = consume(t, k, trace, tw0)
         finally:
             if pf is not None:
                 pf.close()
-        final = cons(unshard(state.worker_params))
-        return (final, hist, state) if return_state else (final, hist)
+        return finish()
 
     # ---- legacy host-driven loop (benchmark baseline / equivalence) ------
     @partial(jax.jit, static_argnums=0)
@@ -1707,19 +1810,24 @@ class PhaseEngine:
         by construction; the independent-implementation check under
         faults is the flat-native / flat / tree triple, which tier-1
         asserts bitwise."""
-        state = self.init(params, num_workers, seed)
+        state, layout = self.start_state(params, num_workers, seed)
         hist = init_history()
+
+        def worker_params(state):
+            if layout is None:
+                return state.worker_params
+            return self._params_tree(layout[0], state.worker_params)
 
         def cons(state):
             alive = jnp.asarray(jax.device_get(state.fault.alive))
             alive = self._faults().mix_at(alive, int(state.step))
-            return faults_mod.masked_mean_tree(state.worker_params,
-                                               alive)
+            return faults_mod.masked_mean_tree(worker_params(state), alive)
 
         step = 0
         for batch in batches:
             step += 1
-            state, trace = self.run_phase(state, tree_stack([batch]))
+            state, trace = self.run_phase(state, tree_stack([batch]),
+                                          layout=layout)
             trace = jax.device_get(trace)
             disp = float(trace["dispersion"][0])
             if int(trace["avg_code"][0]):
@@ -1732,7 +1840,7 @@ class PhaseEngine:
                     hist["eval"].append((step, eval_fn(cons(state))))
                 if worker_eval_fn is not None:
                     hist["worker_eval"].append(
-                        (step, worker_eval_fn(state.worker_params)))
+                        (step, worker_eval_fn(worker_params(state))))
         return cons(state), hist
 
     @partial(jax.jit, static_argnums=(0, 5))

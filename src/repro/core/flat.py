@@ -122,9 +122,16 @@ class FlatSpec:
         let a plane-resident optimizer update reproduce the pytree path's
         ``.astype(p.dtype)`` bit-exactly: a bf16/f16 leaf's columns are
         rounded through their dtype after every update, so the plane
-        always holds the exact float32 image of the tree."""
+        always holds the exact float32 image of the tree.
+
+        A tree whose leaves share one narrow dtype gets its code as a
+        Python int instead: a trace-time constant, so the kernels emit
+        that one rounding and a full-width model carries no P-wide row."""
         if all(dt == jnp.dtype(jnp.float32) for dt in self.dtypes):
             return None
+        if len(set(self.dtypes)) == 1:
+            return (ROUND_BF16 if self.dtypes[0] == jnp.dtype(jnp.bfloat16)
+                    else ROUND_F16)
         codes = np.zeros(self.width, np.float32)
         for o, s, dt in zip(self.offsets, self.shapes, self.dtypes):
             if dt == jnp.dtype(jnp.bfloat16):

@@ -8,20 +8,26 @@ separate passes costs 2–3 extra sweeps of the plane per averaging event
 and a tree-mapped optimizer apply per local step; this kernel does
 update + mean + dispersion + broadcast in ONE tiled pass.
 
-Grid (P // block_p,): each program reads full-height (M, block_p) column
-blocks of the param plane, the grad plane and the S optimizer-state
-planes (S=0 SGD, 1 Momentum, 2 AdamW — layouts from
+Grid (cdiv(P, block_p),): each program reads full-height (M, block_p)
+column blocks of the param plane, the grad plane and the S
+optimizer-state planes (S=0 SGD, 1 Momentum, 2 AdamW — layouts from
 ``repro.core.flat.FlatOptSpec``), applies the update on the VPU, reduces
 over the worker axis (M rides in-block, as in ``avg_disp``), writes the
-updated/broadcast block plus state blocks back, and emits its partial
-dispersion into an SMEM slot. Dynamic per-step scalars (lr and the AdamW
-bias corrections) arrive as one (1, 4) SMEM vector; per-column dtype
-rounding codes (``FlatSpec.rounding_codes``) ride as an f32 row so
-bf16/f16 params round exactly like the pytree optimizers.
+updated/broadcast block plus state blocks back in place (the param and
+state planes are aliased to the outputs), and adds its partial
+dispersion to one SMEM accumulator. The last block may be ragged: its
+out-of-range columns are masked out of the cross-column reductions
+(``avg_disp.col_mask``), so no padded copy of any plane is made.
+Dynamic per-step scalars (lr and the AdamW bias corrections) arrive as
+one (1, 4) SMEM vector. bf16/f16 params round exactly like the pytree
+optimizers (``FlatSpec.rounding_codes``): a one-dtype plane's code is
+static, so only its rounding is emitted; a mixed plane's per-column
+codes ride as an f32 row.
 
 On CPU the kernel runs in interpret mode for validation; the engine's
 CPU path uses the jnp twin ``repro.kernels.ref.opt_step_ref`` (identical
-math). On TPU the same call compiles to Mosaic.
+math). On a TPU v5e it compiles to Mosaic and is what the engine runs
+(``tests/test_tpu_compile.py`` compiles it at smollm-360m's width).
 """
 from __future__ import annotations
 
@@ -32,19 +38,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK_P = 1024
+from repro.kernels.avg_disp import (accumulate, block_cols, codes_row,
+                                    col_mask, disp_part, dspec,
+                                    group_bcast, round_codes)
+
 _KINDS = ("sgd", "momentum", "adamw")
 _MODES = ("none", "mean", "group", "mix")
 
 
-def _round_codes(x, codes):
-    bf = x.astype(jnp.bfloat16).astype(jnp.float32)
-    f16 = x.astype(jnp.float16).astype(jnp.float32)
-    return jnp.where(codes == 1.0, bf, jnp.where(codes == 2.0, f16, x))
-
-
 def _opt_step_kernel(*refs, kind, mode, groups, nstate, has_codes,
-                     mu, nesterov, b1, b2, eps, weight_decay,
+                     round_to, mu, nesterov, b1, b2, eps, weight_decay,
                      wire, error_feedback, p):
     compressed = wire is not None
     scaled = wire in ("int8", "one_bit")
@@ -54,7 +57,7 @@ def _opt_step_kernel(*refs, kind, mode, groups, nstate, has_codes,
     i = 2
     s_refs = refs[i:i + nstate]
     i += nstate
-    codes_ref = refs[i] if has_codes else None
+    codes = refs[i][...] if has_codes else round_to
     i += int(has_codes)
     w_ref = refs[i] if mode == "mix" else None
     i += int(mode == "mix")
@@ -72,6 +75,7 @@ def _opt_step_kernel(*refs, kind, mode, groups, nstate, has_codes,
     d_ref = refs[i]
     sc_ref = refs[i + 1] if scaled else None
 
+    j = pl.program_id(1 if compressed else 0)       # column block
     x = x_ref[...]                                   # (M, block_p) f32
     g = g_ref[...]
     lr = scal_ref[0, 0]
@@ -89,27 +93,34 @@ def _opt_step_kernel(*refs, kind, mode, groups, nstate, has_codes,
         upd = x - lr * (d + weight_decay * x)
         s_out[0][...] = m2
         s_out[1][...] = v2
-    if has_codes:
-        upd = _round_codes(upd, codes_ref[...])
+    if codes is not None:
+        upd = round_codes(upd, codes)
 
     m, bp = upd.shape
+    valid = col_mask(p, bp, j)
     glob = jnp.mean(upd, axis=0)                     # (block_p,)
     # the Eq. 4 dispersion is emitted in EVERY mode: adaptive schedules
     # and the per-step diagnostic trace consume it on non-averaging
-    # steps too (zero-padded columns are mean-0, so they contribute 0)
-    d_ref[0, 0] = jnp.sum(jnp.square(upd - glob[None])) / m
+    # steps too
     if compressed:
         # (2, nb) grid: the update is recomputed in both phases (same
-        # inputs, same values); phase 0 accumulates the per-row scale
-        # statistic across column blocks into VMEM scratch, phase 1
-        # encodes, applies the event on the decoded q and writes the
-        # plane + error-feedback residual
-        ph, j = pl.program_id(0), pl.program_id(1)
+        # inputs, same values); phase 0 sums the dispersion and
+        # accumulates the per-row scale statistic across column blocks
+        # into VMEM scratch, phase 1 encodes, applies the event on the
+        # decoded q and writes the plane + error-feedback residual
+        ph = pl.program_id(0)
+
+        @pl.when(ph == 0)
+        def _disp():
+            accumulate(d_ref, disp_part(upd, glob, valid), j == 0)
+
         ve = upd + e_ref[...] if error_feedback else upd
         if scaled:
-            part = (jnp.max(jnp.abs(ve), axis=1, keepdims=True)
-                    if wire == "int8"
-                    else jnp.sum(jnp.abs(ve), axis=1, keepdims=True))
+            av = jnp.abs(ve)
+            if valid is not None:
+                av = jnp.where(valid, av, 0.0)
+            part = (jnp.max(av, axis=1, keepdims=True) if wire == "int8"
+                    else jnp.sum(av, axis=1, keepdims=True))
 
             @pl.when((ph == 0) & (j == 0))
             def _init():
@@ -136,17 +147,15 @@ def _opt_step_kernel(*refs, kind, mode, groups, nstate, has_codes,
                 out = jnp.dot(w_ref[...], q,
                               preferred_element_type=jnp.float32)
             elif mode == "group" and groups > 1:
-                gm = jnp.mean(q.reshape(groups, m // groups, bp), axis=1)
-                out = jnp.broadcast_to(gm[:, None],
-                                       (groups, m // groups, bp))
-                out = out.reshape(m, bp)
+                out = group_bcast(q, groups)
             else:
                 out = jnp.broadcast_to(jnp.mean(q, axis=0)[None], (m, bp))
-            if has_codes:
-                out = _round_codes(out, codes_ref[...])
+            if codes is not None:
+                out = round_codes(out, codes)
             o_ref[...] = out
             r_ref[...] = ve - q if error_feedback else e_ref[...]
         return
+    accumulate(d_ref, disp_part(upd, glob, valid), j == 0)
     if mode == "none":
         o_ref[...] = upd
         return
@@ -155,44 +164,32 @@ def _opt_step_kernel(*refs, kind, mode, groups, nstate, has_codes,
         # worker keeps its own mixed row, no broadcast (the dispersion
         # above stays the pre-mix diagnostic)
         out = jnp.dot(w_ref[...], upd, preferred_element_type=jnp.float32)
-        if has_codes:
-            out = _round_codes(out, codes_ref[...])
+        if codes is not None:
+            out = round_codes(out, codes)
         o_ref[...] = out
         return
     if mode == "group" and groups > 1:
-        gm = jnp.mean(upd.reshape(groups, m // groups, bp), axis=1)
-        out = jnp.broadcast_to(gm[:, None], (groups, m // groups, bp))
-        out = out.reshape(m, bp)
+        out = group_bcast(upd, groups)
     else:
         out = jnp.broadcast_to(glob[None], (m, bp))
-    if has_codes:
-        out = _round_codes(out, codes_ref[...])
+    if codes is not None:
+        out = round_codes(out, codes)
     o_ref[...] = out
 
 
-def _pad_cols(x, p_pad):
-    p = x.shape[-1]
-    if p_pad == p:
-        return x
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, p_pad - p)])
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("kind", "mode", "groups", "mu", "nesterov", "b1", "b2",
-                     "eps", "weight_decay", "wire", "error_feedback",
-                     "block_p", "interpret"))
 def opt_step(plane, grads, planes, scalars, *, kind, mode="none",
              groups: int = 1, W=None, mu=0.9, nesterov=False, b1=0.9,
              b2=0.95, eps=1e-8, weight_decay=0.0, codes=None,
              wire=None, resid=None, u=None, error_feedback: bool = True,
              alive=None, umask=None,
-             block_p: int = DEFAULT_BLOCK_P, interpret: bool | None = None):
+             block_p: int | None = None, interpret: bool | None = None):
     """Fused optimizer step + optional averaging on the (M, P) plane.
 
     plane/grads: (M, P) f32; planes: tuple of S f32 state planes
     (``FlatOptSpec`` layout); scalars: (4,) f32 [lr, c1, c2, _];
-    codes: optional (P,) f32 rounding codes. mode: "none" | "mean" |
+    codes: ``FlatSpec.rounding_codes`` (None, a static int, or a (P,)
+    f32 row; not jitted itself, so an int stays static — callers trace
+    it inside their own jit). mode: "none" | "mean" |
     "group" | "mix" — "mix" applies the doubly-stochastic (M, M)
     mixing matrix ``W`` (``repro.topology``) after the update: each
     worker keeps its own mixed row, no broadcast. Returns
@@ -253,7 +250,7 @@ def opt_step(plane, grads, planes, scalars, *, kind, mode="none",
                 upd, groups=groups if mode == "group" else 1,
                 alive=alive, block_p=block_p, interpret=interpret)
         if codes is not None:
-            out = _round_codes(out, jnp.asarray(codes, jnp.float32)[None])
+            out = round_codes(out, codes)
             out = _faults.select_rows(out, upd, alive)
         return out, new_planes, disp
     compressed = wire is not None
@@ -267,72 +264,70 @@ def opt_step(plane, grads, planes, scalars, *, kind, mode="none",
     m, p = plane.shape
     assert groups >= 1 and m % groups == 0, (m, groups)
     nstate = len(planes)
-    block_p = min(block_p, max(p, 1))
-    p_pad = -(-max(p, 1) // block_p) * block_p
-    nb = p_pad // block_p
-    has_codes = codes is not None
+    bp, nb = block_cols(m, p, block_p)
+    has_codes = codes_row(codes)
 
     # the compressed path runs a (2, nb) grid — index maps drop the
-    # phase coordinate
+    # phase coordinate; its outputs alias the input planes, so phase 0
+    # (which writes nothing) parks them on block 0, which phase 1's
+    # first step fills before its first write-back
     if compressed:
-        blk = pl.BlockSpec((m, block_p), lambda ph, i: (0, i))
-        row = pl.BlockSpec((1, block_p), lambda ph, i: (0, i))
-        whole = lambda shape: pl.BlockSpec(shape, lambda ph, i: (0, 0))
-        dspec = pl.BlockSpec((1, 1), lambda ph, i: (i, 0),
-                             memory_space=pltpu.SMEM)
+        blk = pl.BlockSpec((m, bp), lambda ph, i: (0, i))
+        oblk = pl.BlockSpec((m, bp), lambda ph, i: (0, i * ph))
+        row = pl.BlockSpec((1, bp), lambda ph, i: (0, i))
+        const = lambda ph, i: (0, 0)
         grid = (2, nb)
     else:
-        blk = pl.BlockSpec((m, block_p), lambda i: (0, i))
-        row = pl.BlockSpec((1, block_p), lambda i: (0, i))
-        whole = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))
-        dspec = pl.BlockSpec((1, 1), lambda i: (i, 0),
-                             memory_space=pltpu.SMEM)
+        blk = oblk = pl.BlockSpec((m, bp), lambda i: (0, i))
+        row = pl.BlockSpec((1, bp), lambda i: (0, i))
+        const = lambda i: (0, 0)
         grid = (nb,)
 
-    x = _pad_cols(plane.astype(jnp.float32), p_pad)
-    g = _pad_cols(grads.astype(jnp.float32), p_pad)
-    ins = [x, g] + [_pad_cols(s.astype(jnp.float32), p_pad) for s in planes]
+    ins = ([plane.astype(jnp.float32), grads.astype(jnp.float32)]
+           + [s.astype(jnp.float32) for s in planes])
     in_specs = [blk, blk] + [blk] * nstate
     if has_codes:
-        ins.append(_pad_cols(jnp.asarray(codes, jnp.float32)[None], p_pad))
+        ins.append(jnp.asarray(codes, jnp.float32)[None])
         in_specs.append(row)
     if mode == "mix":
         assert W.shape == (m, m), (W.shape, m)
         ins.append(W.astype(jnp.float32))
-        in_specs.append(whole((m, m)))
+        in_specs.append(pl.BlockSpec((m, m), const))
     if has_u:
-        ins.append(_pad_cols(u.astype(jnp.float32), p_pad))
+        ins.append(u.astype(jnp.float32))
         in_specs.append(blk)
     if compressed:
-        ins.append(_pad_cols(resid.astype(jnp.float32), p_pad))
+        ins.append(resid.astype(jnp.float32))
         in_specs.append(blk)
     ins.append(jnp.asarray(scalars, jnp.float32).reshape(1, 4))
-    in_specs.append(pl.BlockSpec((1, 4), (lambda ph, i: (0, 0)) if compressed
-                                 else (lambda i: (0, 0)),
-                                 memory_space=pltpu.SMEM))
+    in_specs.append(pl.BlockSpec((1, 4), const, memory_space=pltpu.SMEM))
 
     nplanes_out = 1 + nstate + int(compressed)
-    out_shape = ([jax.ShapeDtypeStruct((m, p_pad), jnp.float32)]
-                 * nplanes_out
-                 + [jax.ShapeDtypeStruct((nb, 1), jnp.float32)])
-    out_specs = [blk] * nplanes_out + [dspec]
+    # update in place: plane -> out, state planes -> new state planes,
+    # residual -> new residual
+    aliases = {0: 0, **{2 + k: 1 + k for k in range(nstate)}}
+    if compressed:
+        aliases[len(ins) - 2] = 1 + nstate
     outs = pl.pallas_call(
         functools.partial(_opt_step_kernel, kind=kind, mode=mode,
                           groups=groups, nstate=nstate, has_codes=has_codes,
-                          mu=mu, nesterov=nesterov, b1=b1, b2=b2, eps=eps,
+                          round_to=None if has_codes else codes, mu=mu,
+                          nesterov=nesterov, b1=b1, b2=b2, eps=eps,
                           weight_decay=weight_decay, wire=wire,
                           error_feedback=error_feedback, p=p),
         grid=grid,
         in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        out_specs=[oblk] * nplanes_out + [dspec(const)],
+        out_shape=([jax.ShapeDtypeStruct((m, p), jnp.float32)]
+                   * nplanes_out
+                   + [jax.ShapeDtypeStruct((1, 1), jnp.float32)]),
         scratch_shapes=([pltpu.VMEM((m, 1), jnp.float32)]
                         if wire in ("int8", "one_bit") else []),
+        input_output_aliases=aliases,
         interpret=interpret,
     )(*ins)
-    out, dpart = outs[0], outs[-1]
-    new_planes = tuple(o[:, :p] for o in outs[1:1 + nstate])
+    out, disp = outs[0], outs[-1][0, 0]
+    new_planes = tuple(outs[1:1 + nstate])
     if compressed:
-        return (out[:, :p], new_planes, outs[1 + nstate][:, :p],
-                jnp.sum(dpart))
-    return out[:, :p], new_planes, jnp.sum(dpart)
+        return out, new_planes, outs[1 + nstate], disp
+    return out, new_planes, disp

@@ -82,7 +82,7 @@ def mix_disp_ref(plane, W, *, codes=None, alive=None):
         Wm = _faults.degraded_matrix(W.astype(jnp.float32), alive)
         out = jnp.dot(Wm, plane, preferred_element_type=jnp.float32)
         if codes is not None:
-            out = round_to_codes(out, codes[None])
+            out = round_to_codes(out, codes)
         return _faults.select_rows(out, plane, alive), disp
     m = plane.shape[0]
     glob = jnp.mean(plane, axis=0)
@@ -90,7 +90,7 @@ def mix_disp_ref(plane, W, *, codes=None, alive=None):
     out = jnp.dot(W.astype(jnp.float32), plane,
                   preferred_element_type=jnp.float32)
     if codes is not None:
-        out = round_to_codes(out, codes[None])
+        out = round_to_codes(out, codes)
     return out, disp
 
 
@@ -129,10 +129,26 @@ def round_to_codes(x, codes):
     """Round each column of ``x`` through its original dtype (codes from
     ``FlatSpec.rounding_codes``: 0 f32, 1 bf16, 2 f16) and back to f32 —
     the plane-resident twin of the pytree optimizers' ``.astype(p.dtype)``
-    after every update. ``codes`` broadcasts over leading axes."""
-    bf = x.astype(jnp.bfloat16).astype(jnp.float32)
-    f16 = x.astype(jnp.float16).astype(jnp.float32)
-    return jnp.where(codes == 1.0, bf, jnp.where(codes == 2.0, f16, x))
+    after every update. A one-dtype plane's code is one int (only its
+    rounding is traced); a per-column row broadcasts over leading axes.
+
+    The rounding is explicit (``reduce_precision``), not an
+    ``astype(bf16).astype(f32)`` pair: the TPU compiler may treat such a
+    pair as excess precision and skip it, leaving the plane unrounded.
+    ``reduce_precision`` flushes f16 subnormals, so below f16's smallest
+    normal the value is rounded to the 2^-24 subnormal quantum instead."""
+    def bf16():
+        return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+
+    def f16():
+        sub = jnp.round(x * 2.0 ** 24) * 2.0 ** -24
+        return jnp.where(jnp.abs(x) < 2.0 ** -14, sub,
+                         jax.lax.reduce_precision(x, exponent_bits=5,
+                                                  mantissa_bits=10))
+    if isinstance(codes, int):
+        return bf16() if codes == 1 else f16()
+    return jnp.where(codes == 1.0, bf16(),
+                     jnp.where(codes == 2.0, f16(), x))
 
 
 def plane_update_ref(plane, grads, planes, scalars, *, kind, mu=0.9,
@@ -162,7 +178,7 @@ def plane_update_ref(plane, grads, planes, scalars, *, kind, mu=0.9,
     else:
         raise ValueError(f"unknown plane optimizer kind {kind!r}")
     if codes is not None:
-        upd = round_to_codes(upd, codes[None])
+        upd = round_to_codes(upd, codes)
     return upd, planes
 
 
@@ -184,7 +200,7 @@ def plane_average_ref(plane, *, groups: int = 1, codes=None, alive=None):
             glob = _faults.masked_mean(plane, alive)
             out = jnp.broadcast_to(glob[None], (m, p))
         if codes is not None:
-            out = round_to_codes(out, codes[None])
+            out = round_to_codes(out, codes)
         return _faults.select_rows(out, plane, alive), disp
     glob = jnp.mean(plane, axis=0)
     disp = jnp.sum(jnp.square(plane - glob[None])) / m
@@ -195,7 +211,7 @@ def plane_average_ref(plane, *, groups: int = 1, codes=None, alive=None):
     else:
         out = jnp.broadcast_to(glob[None], (m, p))
     if codes is not None:
-        out = round_to_codes(out, codes[None])
+        out = round_to_codes(out, codes)
     return out, disp
 
 
@@ -300,7 +316,7 @@ def compressed_avg_ref(plane, resid, *, wire, groups: int = 1, u=None,
             out = jnp.broadcast_to(
                 _faults.masked_mean(q, alive)[None], (m, p))
         if codes is not None:
-            out = round_to_codes(out, codes[None])
+            out = round_to_codes(out, codes)
         return _faults.select_rows(out, plane, alive), resid, disp
     glob = jnp.mean(plane, axis=0)
     disp = jnp.sum(jnp.square(plane - glob[None])) / m
@@ -313,7 +329,7 @@ def compressed_avg_ref(plane, resid, *, wire, groups: int = 1, u=None,
     else:
         out = jnp.broadcast_to(jnp.mean(q, axis=0)[None], (m, p))
     if codes is not None:
-        out = round_to_codes(out, codes[None])
+        out = round_to_codes(out, codes)
     return out, resid, disp
 
 
@@ -337,7 +353,7 @@ def compressed_mix_ref(plane, resid, W, *, wire, u=None, codes=None,
         Wm = _faults.degraded_matrix(W.astype(jnp.float32), alive)
         out = jnp.dot(Wm, q, preferred_element_type=jnp.float32)
         if codes is not None:
-            out = round_to_codes(out, codes[None])
+            out = round_to_codes(out, codes)
         return _faults.select_rows(out, plane, alive), resid, disp
     glob = jnp.mean(plane, axis=0)
     disp = jnp.sum(jnp.square(plane - glob[None])) / m
@@ -346,7 +362,7 @@ def compressed_mix_ref(plane, resid, W, *, wire, u=None, codes=None,
     out = jnp.dot(W.astype(jnp.float32), q,
                   preferred_element_type=jnp.float32)
     if codes is not None:
-        out = round_to_codes(out, codes[None])
+        out = round_to_codes(out, codes)
     return out, resid, disp
 
 

@@ -1,12 +1,14 @@
 """Training CLI: local-SGD training of any assigned architecture.
 
-On this CPU container use ``--reduced`` (the full configs are exercised
-by the dry-run); on a real TPU mesh the same driver shards the worker
-axis over ("pod","data") via the dry-run's sharding rules.
+On a CPU use ``--reduced``. On one TPU v5e chip the full published
+width runs as is (``chip_smoke.py`` drives it: smollm-360m, 2 workers);
+``--shard`` splits the worker axis over every local chip.
 
 Example:
   PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \
       --reduced --steps 100 --workers 4 --avg periodic --phase-len 10
+
+Returns (consensus params, history, final EngineState).
 """
 from __future__ import annotations
 
@@ -26,6 +28,7 @@ from repro.core import (AveragingSchedule, Compression, OuterOptimizer,
 from repro.topology import KINDS as TOPOLOGY_KINDS
 from repro.topology import Topology
 from repro.data import token_stream
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_worker_mesh
 from repro.models import init_params, lm_loss
 from repro.optim import AdamW, Momentum
@@ -352,6 +355,8 @@ def main(argv=None):
                      f"{int(cfg.num_params())} params) — the schedule "
                      "would never fire")
 
+    # after every argument check: a refused command line compiles nothing
+    enable_compile_cache()
     params = init_params(cfg, jax.random.PRNGKey(args.seed))
 
     def loss_fn(p, batch, rng):
@@ -458,6 +463,8 @@ def main(argv=None):
         resume_state, at = load_engine_state(args.resume, like)
         print(f"[train] resuming from {args.resume} at step {at}")
 
+    # a run shorter than the record period still records its last step
+    record_every = min(10, args.steps)
     t0 = time.time()
     with profile_trace(args.profile_dir):
         if elastic is not None:
@@ -465,7 +472,8 @@ def main(argv=None):
             final, hist, state = run_elastic(
                 engine, params, lambda m, t_start, k: batches(m, k),
                 elastic, steps=at + args.steps, seed=args.seed,
-                record_every=10, state=resume_state, return_state=True,
+                record_every=record_every, state=resume_state,
+                return_state=True,
                 sink=sink)
             for t, old_m, new_m in hist["resizes"]:
                 kind = "shrink" if new_m < old_m else "grow"
@@ -475,7 +483,7 @@ def main(argv=None):
             final, hist, state = engine.run(
                 params, batches(args.workers, args.steps),
                 num_workers=args.workers, seed=args.seed,
-                record_every=10, prefetch=not args.no_prefetch,
+                record_every=record_every, prefetch=not args.no_prefetch,
                 state=resume_state, return_state=True, sink=sink)
     dt = time.time() - t0
     if args.profile_dir:
@@ -503,7 +511,7 @@ def main(argv=None):
                 layout_version=ENGINE_STATE_VERSION))
     if sink is not None:
         sink.close()
-    return final, hist
+    return final, hist, state
 
 
 if __name__ == "__main__":

@@ -250,8 +250,9 @@ def test_run_phase_trace_matches_host_per_step():
     idx = _index_draws()
     engine = PhaseEngine(_loss_fn, SGD(lr=0.05),
                          AveragingSchedule("periodic", 16))
-    state = engine.init(_params(), WORKERS, seed=3)
-    _, trace = engine.run_phase(state, tree_stack(_batches(X, y, idx)))
+    state, layout = engine.start_state(_params(), WORKERS, seed=3)
+    _, trace = engine.run_phase(state, tree_stack(_batches(X, y, idx)),
+                                layout=layout)
     disp = np.asarray(trace["dispersion"])
     codes = np.asarray(trace["avg_code"])
     assert disp.shape == (STEPS,)

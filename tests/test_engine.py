@@ -140,12 +140,13 @@ def test_engine_state_resumable():
     engine = PhaseEngine(_loss_fn, SGD(lr=0.05),
                          AveragingSchedule("stochastic", zeta=0.3))
     blocks = list(_batches(X, y, steps=24))
-    s1 = engine.init(_params(), WORKERS, seed=9)
-    s1, tr_a = engine.run_phase(s1, tree_stack(blocks[:10]))
-    s1, tr_b = engine.run_phase(s1, tree_stack(blocks[10:]))
-    s2 = engine.init(_params(), WORKERS, seed=9)
-    s2, tr = engine.run_phase(s2, tree_stack(blocks))
+    s1, layout = engine.start_state(_params(), WORKERS, seed=9)
+    s1, tr_a = engine.run_phase(s1, tree_stack(blocks[:10]), layout=layout)
+    s1, tr_b = engine.run_phase(s1, tree_stack(blocks[10:]), layout=layout)
+    s2, _ = engine.start_state(_params(), WORKERS, seed=9)
+    s2, tr = engine.run_phase(s2, tree_stack(blocks), layout=layout)
     assert isinstance(s1, EngineState) and int(s1.step) == int(s2.step) == 24
+    s1, s2 = engine.to_tree(layout, s1), engine.to_tree(layout, s2)
     np.testing.assert_array_equal(
         np.asarray(consensus(s1.worker_params)["w"]["inner"]),
         np.asarray(consensus(s2.worker_params)["w"]["inner"]))
@@ -220,3 +221,82 @@ def test_localsgd_wrapper_delegates_to_engine():
                                   np.asarray(f_b["w"]["inner"]))
     assert h_a["loss"] == h_b["loss"]
     assert h_a["averages"] == h_b["averages"]
+
+
+# --------------------------------------------------------------------------
+# plane-form state: what run() carries between phases
+# --------------------------------------------------------------------------
+
+def test_plane_form_roundtrip_and_phase_match_tree_form():
+    """to_planes / to_tree are exact inverses, a flat-native state runs
+    only in plane form, and a phase run on the plane form lands on the
+    state the tree carry (``flat=False``) reaches at f32 roundoff."""
+    X, y = _convex_problem()
+    mk = lambda **kw: PhaseEngine(_loss_fn, Momentum(lr=0.05, mu=0.9),
+                                  SCHEDULES["periodic"],
+                                  outer=OuterOptimizer(lr=0.8, momentum=0.5),
+                                  **kw)
+    engine = mk()
+    tree = engine.init(_params(), WORKERS, seed=4)
+    layout = engine.plane_layout(tree)
+    assert layout is not None
+    planes = engine.to_planes(layout, tree)
+    assert planes.worker_params.shape == (WORKERS, DIM)
+    back = engine.to_tree(layout, planes)
+    for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(tree)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    block = tree_stack(list(_batches(X, y, steps=16)))
+    with pytest.raises(AssertionError, match="plane form"):
+        engine.run_phase(jax.tree.map(jnp.array, tree), block)
+    s_tree, tr_tree = mk(flat=False).run_phase(
+        jax.tree.map(jnp.array, tree), block)
+    s_pl, tr_pl = engine.run_phase(planes, block, layout=layout)
+    s_pl = engine.to_tree(layout, s_pl)
+    for a, b in zip(jax.tree.leaves(s_pl), jax.tree.leaves(s_tree)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-7)
+    np.testing.assert_array_equal(tr_pl["avg_code"], tr_tree["avg_code"])
+    np.testing.assert_allclose(tr_pl["loss"], tr_tree["loss"], rtol=1e-6)
+
+
+def test_plane_layout_none_for_tree_carries():
+    """No plane form where the phase carries a tree: flat=False, or an
+    optimizer without the plane protocol (fused_opt=False)."""
+    for kw in (dict(flat=False), dict(fused_opt=False)):
+        engine = PhaseEngine(_loss_fn, Momentum(lr=0.05, mu=0.9),
+                             SCHEDULES["periodic"], **kw)
+        state = engine.init(_params(), WORKERS)
+        assert engine.plane_layout(state) is None
+        started, layout = engine.start_state(_params(), WORKERS)
+        assert layout is None
+        assert isinstance(started.worker_params, dict)
+
+
+@pytest.mark.parametrize("name", ["minibatch", "periodic", "hierarchical",
+                                  "adaptive_threshold"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_pallas_engine_matches_ref_engine(name, dtype):
+    """The engine's Pallas path (interpret mode here; Mosaic on a TPU)
+    against its jnp reference path, through run(): the same decisions,
+    params and losses at f32 roundoff. bf16 params take the kernels'
+    rounding path with the one-dtype static code."""
+    X, y = _convex_problem()
+    params = jax.tree.map(lambda x: x.astype(dtype), _params())
+    kw = dict(num_workers=WORKERS, seed=2, record_every=1)
+    out = {}
+    for impl in ("pallas", "ref"):
+        engine = PhaseEngine(_loss_fn, Momentum(lr=0.05, mu=0.9),
+                             SCHEDULES[name], kernel_impl=impl)
+        out[impl] = engine.run(params, list(_batches(X, y, steps=24)),
+                               **kw)
+    (f_p, h_p), (f_r, h_r) = out["pallas"], out["ref"]
+    assert f_p["w"]["inner"].dtype == dtype
+    assert h_p["averages"] == h_r["averages"]
+    assert [t for t, _ in h_p["dispersion"]] == \
+        [t for t, _ in h_r["dispersion"]]
+    np.testing.assert_allclose(
+        np.asarray(f_p["w"]["inner"], np.float32),
+        np.asarray(f_r["w"]["inner"], np.float32), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose([v for _, v in h_p["loss"]],
+                               [v for _, v in h_r["loss"]], rtol=1e-5)

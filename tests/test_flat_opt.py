@@ -104,6 +104,44 @@ class TestFlatOptSpec:
         codes = mixed.rounding_codes()
         np.testing.assert_array_equal(codes, [0, 0, 0, 1, 1, 1, 1, 2, 2])
 
+    @pytest.mark.parametrize("dt,code", [(jnp.bfloat16, 1),
+                                         (jnp.float16, 2)])
+    def test_rounding_codes_one_dtype_is_scalar(self, dt, code):
+        """A one-dtype tree needs no P-wide codes row: its code is one
+        Python int, a trace-time constant."""
+        codes = FlatSpec.of(_worker_tree(dt)).rounding_codes()
+        assert type(codes) is int and codes == code
+
+
+# an all-f32 plane has no codes at all, so no static 0
+@pytest.mark.parametrize("code,static", [(0, False), (1, False), (2, False),
+                                         (1, True), (2, True)])
+def test_kernel_round_codes_bit_exact(code, static):
+    """The kernels round through f16 in f32 arithmetic (Mosaic has no f16
+    vectors on v5e); that emulation equals ``astype`` bit for bit — over
+    normals, f16 subnormals, ties, overflow to inf and f32 denormals —
+    whether the code is a per-column row or a one-dtype static int."""
+    from repro.kernels.avg_disp import round_codes
+    from repro.kernels.ref import round_to_codes
+    rng = np.random.default_rng(0)
+    x = (rng.standard_normal(65536)
+         * 10.0 ** rng.uniform(-12, 6, 65536)).astype(np.float32)
+    edges = np.array([0.0, -0.0, 65504, 65505, 65519.99, 65520, -65520,
+                      65536, 1e30, np.inf, -np.inf, 2 ** -24, 2 ** -25,
+                      3 * 2 ** -25, 2 ** -14, 1.5 * 2 ** -15, 6e-8, 1e-40,
+                      -1e-40, 1.5 * 2 ** -24, 2.5 * 2 ** -24, 1 + 2 ** -11,
+                      1 + 3 * 2 ** -11, 3.4e38], np.float32)
+    x = jnp.asarray(np.concatenate([x, edges]).reshape(2, -1))
+    codes = code if static else jnp.full((x.shape[1],), code, jnp.float32)
+    got = jax.jit(lambda v: round_codes(v, codes))(x)
+    dt = {0: jnp.float32, 1: jnp.bfloat16, 2: jnp.float16}[code]
+    want = x.astype(dt).astype(jnp.float32)
+    np.testing.assert_array_equal(np.asarray(got).view(np.int32),
+                                  np.asarray(want).view(np.int32))
+    np.testing.assert_array_equal(
+        np.asarray(round_to_codes(x, codes)).view(np.int32),
+        np.asarray(want).view(np.int32))
+
 
 # --------------------------------------------------------------------------
 # 2. plane update == pytree optimizer.apply, bit-exact

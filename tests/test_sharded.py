@@ -176,6 +176,18 @@ assert h0["averages"] == h2["averages"]
 np.testing.assert_allclose(np.asarray(f0["w"]), np.asarray(f2["w"]),
                            rtol=1e-5, atol=1e-7)
 print("ok compressed ring mix")
+
+# the run's state is built already split over the mesh (plane form):
+# every device computes and holds only its own worker rows
+eng = PhaseEngine(loss_fn, opt(), sch, mesh=mesh)
+st, layout = eng.start_state(params, WORKERS)
+assert layout is not None
+for plane in (st.worker_params, *st.opt_state):
+    assert plane.shape[0] == WORKERS
+    assert len(plane.addressable_shards) == 8
+    assert all(s.data.shape[0] == WORKERS // 8
+               for s in plane.addressable_shards)
+print("ok sharded start_state")
 print("ALL-OK")
 """
 

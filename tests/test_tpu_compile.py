@@ -1,0 +1,125 @@
+"""Mosaic compiles of the engine's Pallas kernels for a TPU v5e.
+
+Interpret mode (every other kernel test) cannot see what the TPU
+compiler refuses: block shapes off the (8, 128) tiling, vector ops
+Mosaic cannot legalize, programs that do not fit HBM. Each test here
+compiles one kernel entry point with ``interpret=False`` for one chip of
+a described ``v5e:2x2`` topology — no chip is attached — at smollm-360m's
+real plane width (P = 361,821,120 columns, M = 2 worker rows), and
+checks that the compiled program fits the chip's 15.75 GiB of HBM.
+
+The planes the kernels update in place are donated, as they are inside
+the engine's phase scan. The topology is described inside a fixture (a
+described topology loads the TPU compiler library, which one process
+holds at a time), and the persistent compilation cache is off around
+these compiles: a described device's executable cannot be read back.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.avg_disp import (avg_disp, avg_disp_outer, compressed_mix,
+                                    mix_disp)
+from repro.kernels.opt_step import opt_step
+
+P_FULL = 361_821_120     # smollm-360m: FlatSpec(init_params(cfg)).width
+M = 2
+HBM_BYTES = int(15.75 * 2 ** 30)   # v5e HBM the compiler may allocate
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler library here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _plane():
+    return jax.ShapeDtypeStruct((M, P_FULL), jnp.float32)
+
+
+def _row():
+    return jax.ShapeDtypeStruct((P_FULL,), jnp.float32)
+
+
+def _opt(kind, mode, nstate, **kw):
+    def f(x, g, *rest):
+        states, rest = rest[:nstate], rest[nstate:]
+        extra = dict(zip(kw.get("extra", ()), rest[1:]))
+        return opt_step(x, g, states, rest[0], kind=kind, mode=mode,
+                        interpret=False,
+                        **{k: v for k, v in kw.items() if k != "extra"},
+                        **extra)
+    shapes = ([_plane(), _plane()] + [_plane()] * nstate
+              + [jax.ShapeDtypeStruct((4,), jnp.float32)])
+    for name in kw.get("extra", ()):
+        shapes.append({"W": jax.ShapeDtypeStruct((M, M), jnp.float32),
+                       "codes": _row(),
+                       "resid": _plane(), "u": _plane()}[name])
+    donate = (0,) + tuple(range(2, 2 + nstate))
+    if "resid" in kw.get("extra", ()):
+        donate += (3 + nstate + list(kw["extra"]).index("resid"),)
+    return f, shapes, donate
+
+
+CASES = {
+    "opt_step-momentum-none": _opt("momentum", "none", 1),
+    "opt_step-momentum-mean": _opt("momentum", "mean", 1),
+    "opt_step-momentum-mix": _opt("momentum", "mix", 1, extra=("W",)),
+    "opt_step-momentum-mean-codes": _opt("momentum", "mean", 1,
+                                         extra=("codes",)),
+    # a one-dtype model's static code: only its own rounding is emitted
+    "opt_step-momentum-mean-bf16": _opt("momentum", "mean", 1, codes=1),
+    "opt_step-momentum-mean-f16": _opt("momentum", "mean", 1, codes=2),
+    "opt_step-adamw-none": _opt("adamw", "none", 2),
+    "opt_step-momentum-mean-int8": _opt(
+        "momentum", "mean", 1, wire="int8", extra=("resid", "u")),
+    "avg_disp-mean": (lambda x: avg_disp(x, interpret=False), [_plane()],
+                      (0,)),
+    "avg_disp-groups2": (lambda x: avg_disp(x, groups=2, interpret=False),
+                         [_plane()], (0,)),
+    "avg_disp_outer": (
+        lambda x, a, v: avg_disp_outer(x, a, v, lr=0.7, momentum=0.5,
+                                       interpret=False),
+        [_plane(), _row(), _row()], (0, 1, 2)),
+    "mix_disp": (lambda x, w: mix_disp(x, w, interpret=False),
+                 [_plane(), jax.ShapeDtypeStruct((M, M), jnp.float32)],
+                 (0,)),
+    "compressed_mix-int8": (
+        lambda x, e, u: compressed_mix(x, e, wire="int8", u=u,
+                                       interpret=False),
+        [_plane(), _plane(), _plane()], (0, 1)),
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_compiles_at_full_width(one_chip, name):
+    f, shapes, donate = CASES[name]
+    args = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+            for s in shapes]
+    compiled = jax.jit(f, donate_argnums=donate).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert total <= HBM_BYTES, (name, total / 2 ** 30)
+    # the kernels never copy a plane: no temp beyond a few scalars
+    assert ma.temp_size_in_bytes < 2 ** 20, (name, ma.temp_size_in_bytes)
