@@ -67,6 +67,18 @@ from the full mean to one doubly-stochastic mixing-matrix application
 disconnected), fused into the same passes; ``full`` and ``groups``
 topologies lower to the existing mean / block-mean code bit-exactly.
 
+Names on the profiler's clock (docs/TELEMETRY.md): each part of the
+phase step runs under one ``jax.named_scope`` — ``engine.batch`` (step
+counter, rng splits, batch fetch, fault transition), ``engine.unpack``,
+``engine.fwd_bwd``, ``engine.pack``, ``engine.update`` (optimizer
+update, dispersion, schedule decision) and ``engine.average`` (the
+event) — so every op of the compiled phase carries at most one of them
+in its ``op_name``. :meth:`PhaseEngine.run` marks its host loop with
+``jax.profiler.TraceAnnotation`` spans ``engine.next_block``,
+``engine.dispatch``, ``engine.fetch`` and ``engine.record`` (and
+``engine.stage`` on the staging thread), each with ``step=`` the
+phase's first step. Both are inert unless a profiler trace is running.
+
 :meth:`PhaseEngine.run` is the production driver (one compiled dispatch
 per phase); :meth:`PhaseEngine.run_host` keeps the legacy per-step
 host-driven loop — same numerics, same decision stream — as the baseline
@@ -102,6 +114,9 @@ from repro.kernels.ref import (avg_disp_outer_ref, avg_disp_ref,
                                plane_average_ref, plane_update_ref,
                                round_to_codes)
 from repro.topology import MIX_KINDS, Topology, comm_bytes, mix_tree
+
+# a host span on the profiler's clock; inert while no trace is recorded
+span = jax.profiler.TraceAnnotation
 
 
 # --------------------------------------------------------------------------
@@ -140,9 +155,11 @@ def make_worker_step(loss_fn: Callable, optimizer) -> Callable:
     -> (worker_params, opt_state, per-worker losses, aux).
     """
     def one(params, ostate, batch, rng, step):
-        (loss, aux), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, batch, rng)
-        params, ostate = optimizer.apply(params, grads, ostate, step)
+        with jax.named_scope("engine.fwd_bwd"):
+            (loss, aux), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, batch, rng)
+        with jax.named_scope("engine.update"):
+            params, ostate = optimizer.apply(params, grads, ostate, step)
         return params, ostate, loss, aux
 
     def step_fn(worker_params, opt_state, batch, step, rngs=None):
@@ -169,10 +186,14 @@ def make_plane_step(loss_fn: Callable, spec: FlatSpec) -> Callable:
     grad plane (M, P) f32). ``rngs=None`` supports rng-free losses
     (launch/dryrun abstract paths)."""
     def one(row, batch, rng):
-        params = spec.unpack1(row)
-        (loss, aux), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, batch, rng)
-        return loss, aux, spec.pack1(grads)
+        with jax.named_scope("engine.unpack"):
+            params = spec.unpack1(row)
+        with jax.named_scope("engine.fwd_bwd"):
+            (loss, aux), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, batch, rng)
+        with jax.named_scope("engine.pack"):
+            gplane = spec.pack1(grads)
+        return loss, aux, gplane
 
     def grads_fn(plane, batch, rngs=None):
         if rngs is None:
@@ -665,21 +686,26 @@ class PhaseEngine:
         ec = self._sched_event_cost(spec.width, plane.shape[0])
         if sched.kind == "minibatch":
             # the all-average is unconditional — fuse it into the update
-            # pass; the (static) decision still advances the sched state
+            # pass (so its time is engine.update's); the (static)
+            # decision still advances the sched state
+            with jax.named_scope("engine.update"):
+                plane, planes, outer_c, resid, disp = \
+                    self._fused_step_average(
+                        spec, plane, gplane, planes, outer_c, scalars,
+                        "all", W=self._event_W(step, dec_key), resid=resid,
+                        step=step, dec_key=dec_key, alive=alive,
+                        umask=umask)
+                code, sst = sched.decision_state(step, sst, disp, dec_key,
+                                                 event_cost=ec,
+                                                 disp_scale=dscale)
+            return plane, planes, outer_c, resid, sst, disp, code
+        with jax.named_scope("engine.update"):
             plane, planes, outer_c, resid, disp = self._fused_step_average(
-                spec, plane, gplane, planes, outer_c, scalars, "all",
-                W=self._event_W(step, dec_key), resid=resid, step=step,
-                dec_key=dec_key, alive=alive, umask=umask)
+                spec, plane, gplane, planes, outer_c, scalars, "none",
+                resid=resid, alive=alive, umask=umask)
             code, sst = sched.decision_state(step, sst, disp, dec_key,
                                              event_cost=ec,
                                              disp_scale=dscale)
-            return plane, planes, outer_c, resid, sst, disp, code
-        plane, planes, outer_c, resid, disp = self._fused_step_average(
-            spec, plane, gplane, planes, outer_c, scalars, "none",
-            resid=resid, alive=alive, umask=umask)
-        code, sst = sched.decision_state(step, sst, disp, dec_key,
-                                         event_cost=ec,
-                                         disp_scale=dscale)
         if sched.kind == "oneshot":
             return plane, planes, outer_c, resid, sst, disp, code
         comp = self._comp()
@@ -708,9 +734,10 @@ class PhaseEngine:
                                          W=W,
                                          alive=alive)[:2] + (args[2],)
 
-        plane, outer_c, resid = jax.lax.switch(
-            code, [none_branch, inner_branch, all_branch],
-            (plane, outer_c, resid))
+        with jax.named_scope("engine.average"):
+            plane, outer_c, resid = jax.lax.switch(
+                code, [none_branch, inner_branch, all_branch],
+                (plane, outer_c, resid))
         return plane, planes, outer_c, resid, sst, disp, code
 
     # ---- tree-path averaging (flat=False, and FlatSpec fallback) ---------
@@ -853,27 +880,30 @@ class PhaseEngine:
 
         def body(carry, xs_t):
             wp_c, opt_c, outer_c, key, step, sst, resid, fst, acc = carry
-            step = step + 1
-            key, sub = jax.random.split(key)
-            rngs = jax.random.split(sub, num_workers)
-            batch = fetch(xs_t)
             alive = umask = dscale = None
-            if fp is not None:
-                alive_prev = fst.alive
-                fst, _, alive, umask, rejoined = fp.transition(
-                    fst, step, state.dec_key)
-                if fp.has_rejoin:
-                    # the warm-start consensus is the PREVIOUS step's
-                    # mixing cohort: mid-curriculum (solo) rows train
-                    # but their unrepresentative iterates stay out of it
+            with jax.named_scope("engine.batch"):
+                step = step + 1
+                key, sub = jax.random.split(key)
+                rngs = jax.random.split(sub, num_workers)
+                batch = fetch(xs_t)
+                if fp is not None:
+                    alive_prev = fst.alive
+                    fst, _, alive, umask, rejoined = fp.transition(
+                        fst, step, state.dec_key)
+                    if sched.straggle_aware:
+                        dscale = fp.disp_scale(alive, state.dec_key, step)
+            if fp is not None and fp.has_rejoin:
+                # the warm-start consensus is the PREVIOUS step's mixing
+                # cohort: mid-curriculum (solo) rows train but their
+                # unrepresentative iterates stay out of it
+                with jax.named_scope("engine.average"):
                     wp_c, opt_c, resid = warm_start(
                         wp_c, opt_c, resid,
                         fp.mix_at(alive_prev, step - 1), rejoined)
-                if sched.straggle_aware:
-                    dscale = fp.disp_scale(alive, state.dec_key, step)
             if flat_native:
                 losses, _, gplane = grads_fn(wp_c, batch, rngs)
-                scal = self.optimizer.plane_scalars(step)
+                with jax.named_scope("engine.update"):
+                    scal = self.optimizer.plane_scalars(step)
                 wp_c, opt_c, outer_c, resid, sst, disp, code = \
                     self._flat_native_step(
                         spec, wp_c, gplane, opt_c, outer_c, scal, step,
@@ -881,54 +911,60 @@ class PhaseEngine:
                         fmask=None if fp is None else (alive, umask),
                         dscale=dscale)
             else:
-                wp = spec.unpack(wp_c) if use_flat else wp_c
+                if use_flat:
+                    with jax.named_scope("engine.unpack"):
+                        wp = spec.unpack(wp_c)
+                else:
+                    wp = wp_c
                 wp_new, opt_new, losses, _ = self.worker_step(
                     wp, opt_c, batch, step, rngs)
-                if fp is not None:
-                    # dead/straggling rows keep params AND optimizer
-                    # state (zeroed grads would still advance momentum)
-                    if use_flat:
+                if use_flat:
+                    with jax.named_scope("engine.pack"):
                         wp_new_c = spec.pack(wp_new)
-                        wp_c = faults_mod.select_rows(wp_new_c, wp_c,
-                                                      umask)
+                with jax.named_scope("engine.update"):
+                    if fp is not None:
+                        # dead/straggling rows keep params AND optimizer
+                        # state (zeroed grads would still advance
+                        # momentum)
+                        wp_c = (faults_mod.select_rows(wp_new_c, wp_c,
+                                                       umask)
+                                if use_flat else
+                                faults_mod.select_rows_tree(wp_new, wp,
+                                                            umask))
+                        opt_c = faults_mod.select_rows_tree(opt_new, opt_c,
+                                                            umask)
                     else:
-                        wp_c = faults_mod.select_rows_tree(wp_new, wp,
-                                                           umask)
-                    opt_c = faults_mod.select_rows_tree(opt_new, opt_c,
-                                                        umask)
-                else:
-                    opt_c = opt_new
-                    wp_c = spec.pack(wp_new) if use_flat else wp_new
-                # the Eq. 4 dispersion is measured EVERY step (post
-                # update, pre average): the stateful decision consumes
-                # it and the trace records the true diagnostic on
-                # non-averaging steps too
-                if fp is not None:
-                    disp = (faults_mod.masked_dispersion(wp_c, alive)
-                            if use_flat else
-                            faults_mod.masked_dispersion_tree(wp_c,
-                                                              alive))
-                elif use_flat:
-                    glob = jnp.mean(wp_c, axis=0)
-                    disp = (jnp.sum(jnp.square(wp_c - glob[None]))
-                            / num_workers)
-                else:
-                    disp = worker_dispersion(wp_c)
-                code, sst = sched.decision_state(step, sst, disp,
-                                                 state.dec_key,
-                                                 event_cost=ec,
-                                                 disp_scale=dscale)
-                if sched.kind == "oneshot":
-                    pass
-                elif sched.kind == "minibatch":
-                    W = self._event_W(step, state.dec_key)
-                    if comp is not None:
-                        wp_c, resid = comp_event(wp_c, resid, "all",
-                                                 step, W=W, alive=alive)
+                        opt_c = opt_new
+                        wp_c = wp_new_c if use_flat else wp_new
+                    # the Eq. 4 dispersion is measured EVERY step (post
+                    # update, pre average): the stateful decision
+                    # consumes it and the trace records the true
+                    # diagnostic on non-averaging steps too
+                    if fp is not None:
+                        disp = (faults_mod.masked_dispersion(wp_c, alive)
+                                if use_flat else
+                                faults_mod.masked_dispersion_tree(wp_c,
+                                                                  alive))
+                    elif use_flat:
+                        glob = jnp.mean(wp_c, axis=0)
+                        disp = (jnp.sum(jnp.square(wp_c - glob[None]))
+                                / num_workers)
                     else:
-                        wp_c, outer_c, _ = average(wp_c, outer_c, "all",
-                                                   W=W, alive=alive)
-                else:
+                        disp = worker_dispersion(wp_c)
+                    code, sst = sched.decision_state(step, sst, disp,
+                                                     state.dec_key,
+                                                     event_cost=ec,
+                                                     disp_scale=dscale)
+                if sched.kind == "minibatch":
+                    with jax.named_scope("engine.average"):
+                        W = self._event_W(step, state.dec_key)
+                        if comp is not None:
+                            wp_c, resid = comp_event(wp_c, resid, "all",
+                                                     step, W=W, alive=alive)
+                        else:
+                            wp_c, outer_c, _ = average(wp_c, outer_c, "all",
+                                                       W=W, alive=alive)
+                elif sched.kind != "oneshot":
                     def none_branch(args):
                         return args
 
@@ -951,9 +987,10 @@ class PhaseEngine:
                         return average(args[0], args[1], "all",
                                        W=W, alive=alive)[:2] + (args[2],)
 
-                    wp_c, outer_c, resid = jax.lax.switch(
-                        code, [none_branch, inner_branch, all_branch],
-                        (wp_c, outer_c, resid))
+                    with jax.named_scope("engine.average"):
+                        wp_c, outer_c, resid = jax.lax.switch(
+                            code, [none_branch, inner_branch, all_branch],
+                            (wp_c, outer_c, resid))
             loss_t = (jnp.mean(losses) if fp is None
                       else jnp.sum(losses * alive) / jnp.sum(alive))
             if tm is not None:
@@ -1144,41 +1181,44 @@ class PhaseEngine:
         ax = self._worker_axes()
         alive_full, alive, umask = (fmask if fmask is not None
                                     else (None, None, None))
-        upd, new_planes = plane_update_ref(
-            plane, gplane, planes, scalars, kind=self.optimizer.plane_kind,
-            codes=spec.rounding_codes(), **self.optimizer.plane_hypers())
-        if fmask is None:
-            plane, planes = upd, new_planes
-            glob = jax.lax.psum(jnp.sum(plane, axis=0), ax) / m_global
-            disp = jax.lax.psum(
-                jnp.sum(jnp.square(plane - glob[None])), ax) / m_global
-        else:
-            # dead / straggling rows keep params AND state planes
-            plane = faults_mod.select_rows(upd, plane, umask)
-            planes = tuple(faults_mod.select_rows(n, o, umask)
-                           for n, o in zip(new_planes, planes))
-            n_alive = jax.lax.psum(jnp.sum(alive), ax)
-            glob = jax.lax.psum(
-                jnp.sum(plane * alive[:, None], axis=0), ax) / n_alive
-            disp = jax.lax.psum(
-                jnp.sum(jnp.square(plane - glob[None]) * alive[:, None]),
-                ax) / n_alive
-        ec = self._sched_event_cost(spec.width, m_global)
-        code, sst = sched.decision_state(step, sst, disp, dec_key,
-                                         event_cost=ec,
-                                         disp_scale=dscale)
+        with jax.named_scope("engine.update"):
+            upd, new_planes = plane_update_ref(
+                plane, gplane, planes, scalars,
+                kind=self.optimizer.plane_kind,
+                codes=spec.rounding_codes(), **self.optimizer.plane_hypers())
+            if fmask is None:
+                plane, planes = upd, new_planes
+                glob = jax.lax.psum(jnp.sum(plane, axis=0), ax) / m_global
+                disp = jax.lax.psum(
+                    jnp.sum(jnp.square(plane - glob[None])), ax) / m_global
+            else:
+                # dead / straggling rows keep params AND state planes
+                plane = faults_mod.select_rows(upd, plane, umask)
+                planes = tuple(faults_mod.select_rows(n, o, umask)
+                               for n, o in zip(new_planes, planes))
+                n_alive = jax.lax.psum(jnp.sum(alive), ax)
+                glob = jax.lax.psum(
+                    jnp.sum(plane * alive[:, None], axis=0), ax) / n_alive
+                disp = jax.lax.psum(
+                    jnp.sum(jnp.square(plane - glob[None])
+                            * alive[:, None]), ax) / n_alive
+            ec = self._sched_event_cost(spec.width, m_global)
+            code, sst = sched.decision_state(step, sst, disp, dec_key,
+                                             event_cost=ec,
+                                             disp_scale=dscale)
         if sched.kind == "oneshot":
             return plane, planes, outer_c, resid, sst, disp, code
         if sched.kind == "minibatch":
-            W = self._event_W(step, dec_key)
-            if comp is not None:
-                plane, resid = self._psum_compressed_event(
-                    spec, plane, resid, "all", step, dec_key, ml,
-                    m_global, W=W, alive=alive, alive_full=alive_full)
-            else:
-                plane, outer_c = self._psum_avg_event(
-                    spec, plane, outer_c, "all", glob, ml, W=W,
-                    alive=alive, alive_full=alive_full)
+            with jax.named_scope("engine.average"):
+                W = self._event_W(step, dec_key)
+                if comp is not None:
+                    plane, resid = self._psum_compressed_event(
+                        spec, plane, resid, "all", step, dec_key, ml,
+                        m_global, W=W, alive=alive, alive_full=alive_full)
+                else:
+                    plane, outer_c = self._psum_avg_event(
+                        spec, plane, outer_c, "all", glob, ml, W=W,
+                        alive=alive, alive_full=alive_full)
             return plane, planes, outer_c, resid, sst, disp, code
 
         def none_branch(args):
@@ -1210,9 +1250,10 @@ class PhaseEngine:
         # tracing it would still reserve M·P bytes on every device
         if sched.kind != "hierarchical":
             inner_branch = none_branch
-        plane, outer_c, resid = jax.lax.switch(
-            code, [none_branch, inner_branch, all_branch],
-            (plane, outer_c, resid))
+        with jax.named_scope("engine.average"):
+            plane, outer_c, resid = jax.lax.switch(
+                code, [none_branch, inner_branch, all_branch],
+                (plane, outer_c, resid))
         return plane, planes, outer_c, resid, sst, disp, code
 
     def _phase_sharded(self, state: EngineState, xs, fetch, m_global: int,
@@ -1262,11 +1303,15 @@ class PhaseEngine:
 
         def body(carry, xs_t):
             wp_c, opt_c, outer_c, key, step, sst, resid, fst, acc = carry
-            step = step + 1
-            key, sub = jax.random.split(key)
-            rngs = jax.random.split(sub, m_global)
-            batch = fetch(xs_t)
-            scal = self.optimizer.plane_scalars(step)
+            with jax.named_scope("engine.batch"):
+                step = step + 1
+                key, sub = jax.random.split(key)
+                rngs = jax.random.split(sub, m_global)
+                batch = fetch(xs_t)
+            with jax.named_scope("engine.update"):
+                scal = self.optimizer.plane_scalars(step)
+            # the gather collective's state gathers and row slices are
+            # none of the step's parts: they stay unscoped
             if exact:
                 wp_full = jax.lax.all_gather(wp_c, ax, axis=0, tiled=True)
                 opt_full = tuple(
@@ -1290,23 +1335,26 @@ class PhaseEngine:
                         jax.lax.all_gather(fst.staleness, ax, axis=0,
                                            tiled=True))
                     alive_prev = fst_full.alive
-                    fst_full, _, alive_f, umask_f, rejoined_f = \
-                        fp.transition(fst_full, step, state.dec_key)
+                    with jax.named_scope("engine.batch"):
+                        fst_full, _, alive_f, umask_f, rejoined_f = \
+                            fp.transition(fst_full, step, state.dec_key)
                     if fp.has_rejoin:
-                        glob_p = faults_mod.masked_mean(
-                            wp_full, fp.mix_at(alive_prev, step - 1))
-                        codes = spec.rounding_codes()
-                        if codes is not None:
-                            glob_p = round_to_codes(glob_p, codes)
-                        wp_full = faults_mod.select_rows(
-                            jnp.broadcast_to(glob_p[None], wp_full.shape),
-                            wp_full, rejoined_f)
-                        opt_full = tuple(
-                            faults_mod.zero_rows(s, rejoined_f)
-                            for s in opt_full)
-                        if comp is not None:
-                            resid_full = faults_mod.zero_rows(
-                                resid_full, rejoined_f)
+                        with jax.named_scope("engine.average"):
+                            glob_p = faults_mod.masked_mean(
+                                wp_full, fp.mix_at(alive_prev, step - 1))
+                            codes = spec.rounding_codes()
+                            if codes is not None:
+                                glob_p = round_to_codes(glob_p, codes)
+                            wp_full = faults_mod.select_rows(
+                                jnp.broadcast_to(glob_p[None],
+                                                 wp_full.shape),
+                                wp_full, rejoined_f)
+                            opt_full = tuple(
+                                faults_mod.zero_rows(s, rejoined_f)
+                                for s in opt_full)
+                            if comp is not None:
+                                resid_full = faults_mod.zero_rows(
+                                    resid_full, rejoined_f)
                     fst = FaultState(
                         jax.lax.dynamic_slice_in_dim(
                             fst_full.alive, i0, ml, 0),
@@ -1336,30 +1384,35 @@ class PhaseEngine:
                 dscale = None
                 if fp is not None:
                     alive_prev = fst.alive
-                    fst, alive_fl, alive_l, umask_l, rejoined_l = \
-                        fp.transition(fst, step, state.dec_key,
-                                      row0=i0, num_rows=ml)
-                    if fp.has_rejoin:
-                        aprev = fp.mix_at(alive_prev, step - 1,
+                    with jax.named_scope("engine.batch"):
+                        fst, alive_fl, alive_l, umask_l, rejoined_l = \
+                            fp.transition(fst, step, state.dec_key,
                                           row0=i0, num_rows=ml)
-                        glob_p = (jax.lax.psum(jnp.sum(
-                            wp_c * aprev[:, None], axis=0), ax)
-                            / jax.lax.psum(jnp.sum(aprev), ax))
-                        codes = spec.rounding_codes()
-                        if codes is not None:
-                            glob_p = round_to_codes(glob_p, codes)
-                        wp_c = faults_mod.select_rows(
-                            jnp.broadcast_to(glob_p[None], wp_c.shape),
-                            wp_c, rejoined_l)
-                        opt_c = tuple(faults_mod.zero_rows(s, rejoined_l)
-                                      for s in opt_c)
-                        if comp is not None:
-                            resid = faults_mod.zero_rows(resid, rejoined_l)
+                    if fp.has_rejoin:
+                        with jax.named_scope("engine.average"):
+                            aprev = fp.mix_at(alive_prev, step - 1,
+                                              row0=i0, num_rows=ml)
+                            glob_p = (jax.lax.psum(jnp.sum(
+                                wp_c * aprev[:, None], axis=0), ax)
+                                / jax.lax.psum(jnp.sum(aprev), ax))
+                            codes = spec.rounding_codes()
+                            if codes is not None:
+                                glob_p = round_to_codes(glob_p, codes)
+                            wp_c = faults_mod.select_rows(
+                                jnp.broadcast_to(glob_p[None], wp_c.shape),
+                                wp_c, rejoined_l)
+                            opt_c = tuple(
+                                faults_mod.zero_rows(s, rejoined_l)
+                                for s in opt_c)
+                            if comp is not None:
+                                resid = faults_mod.zero_rows(resid,
+                                                             rejoined_l)
                     fmask = (alive_fl, alive_l, umask_l)
                     if sched.straggle_aware:
                         dscale = fp.disp_scale(alive_fl, state.dec_key,
                                                step)
-                rngs = jax.lax.dynamic_slice_in_dim(rngs, i0, ml, 0)
+                with jax.named_scope("engine.batch"):
+                    rngs = jax.lax.dynamic_slice_in_dim(rngs, i0, ml, 0)
                 losses, _, gplane = grads_fn(wp_c, batch, rngs)
                 wp_c, opt_c, outer_c, resid, sst, disp, code = \
                     self._flat_native_step_psum(spec, wp_c, gplane, opt_c,
@@ -1624,7 +1677,9 @@ class PhaseEngine:
                 "run(sink=...) flushes the on-device metrics "
                 "accumulator, which this engine does not carry — "
                 "construct it with PhaseEngine(..., telemetry=True)")
-        state, layout = self.start_state(params, num_workers, seed, state)
+        with span("engine.start_state"):
+            state, layout = self.start_state(params, num_workers, seed,
+                                             state)
         t0 = int(state.step)
         block = phase_len or self.default_phase_len()
         needs_eval = bool(record_every and (eval_fn or worker_eval_fn))
@@ -1672,7 +1727,12 @@ class PhaseEngine:
         def consume(t, k, trace, tw0=None):
             # THE once-per-phase host sync: traces AND (telemetry mode)
             # the metrics accumulator come back in this one fetch
-            trace = jax.device_get(trace)
+            with span("engine.fetch", step=t + 1):
+                trace = jax.device_get(trace)
+            with span("engine.record", step=t + 1):
+                return record(t, k, trace, tw0)
+
+        def record(t, k, trace, tw0):
             wall = 0.0 if tw0 is None else time.perf_counter() - tw0
             t_first = t
             n_loss, n_disp = len(hist["loss"]), len(hist["disp_trace"])
@@ -1716,11 +1776,12 @@ class PhaseEngine:
             return t
 
         def finish():
-            final = cons(worker_params())
-            if not return_state:
-                return final, hist
-            return final, hist, (state if layout is None
-                                 else self._to_tree(layout, state))
+            with span("engine.finish"):
+                final = cons(worker_params())
+                if not return_state:
+                    return final, hist
+                return final, hist, (state if layout is None
+                                     else self._to_tree(layout, state))
 
         if isinstance(data, DeviceDataset):
             assert data.num_workers == num_workers, \
@@ -1737,9 +1798,11 @@ class PhaseEngine:
             while t < total:
                 take = take_at(t)
                 tw0 = time.perf_counter()
-                idx = jnp.asarray(data.index_block(take))
-                state, trace = self.run_phase_indexed(
-                    state, data.arrays, idx, layout=layout)
+                with span("engine.next_block", step=t + 1):
+                    idx = jnp.asarray(data.index_block(take))
+                with span("engine.dispatch", step=t + 1):
+                    state, trace = self.run_phase_indexed(
+                        state, data.arrays, idx, layout=layout)
                 t = consume(t, take, trace, tw0)
             return finish()
 
@@ -1759,18 +1822,28 @@ class PhaseEngine:
                         break
                 if not chunk:
                     return
+                with span("engine.stage", step=t + 1):
+                    staged = tree_stack(chunk)
                 t += len(chunk)
-                yield len(chunk), tree_stack(chunk)
+                yield len(chunk), staged
 
         # a materialized in-memory source gains nothing from background
         # staging — the prefetch thread only contends with dispatch
         prefetch = prefetch and not isinstance(data, (list, tuple))
         pf = Prefetcher(staged_blocks()) if prefetch else None
+        blocks = pf if pf is not None else staged_blocks()
         t = t0
         try:
-            for k, staged in (pf if pf is not None else staged_blocks()):
+            while True:
+                with span("engine.next_block", step=t + 1):
+                    nxt = next(blocks, None)
+                if nxt is None:
+                    break
+                k, staged = nxt
                 tw0 = time.perf_counter()
-                state, trace = self.run_phase(state, staged, layout=layout)
+                with span("engine.dispatch", step=t + 1):
+                    state, trace = self.run_phase(state, staged,
+                                                  layout=layout)
                 t = consume(t, k, trace, tw0)
         finally:
             if pf is not None:

@@ -276,6 +276,7 @@ def avg_disp(plane, *, groups: int = 1, alive=None,
         ],
         input_output_aliases={0: 0},
         interpret=interpret,
+        name="avg_disp",
     )(plane.astype(jnp.float32))
     return out, d[0, 0]
 
@@ -316,6 +317,7 @@ def mix_disp(plane, W, *, alive=None, block_p: int | None = None,
         ],
         input_output_aliases={0: 0},
         interpret=interpret,
+        name="mix_disp",
     )(plane.astype(jnp.float32), W.astype(jnp.float32))
     return out, d[0, 0]
 
@@ -352,6 +354,7 @@ def avg_disp_outer(plane, prev_avg, vel, *, lr: float, momentum: float,
         ],
         input_output_aliases={0: 0, 1: 1, 2: 2},
         interpret=interpret,
+        name="avg_disp_outer",
     )(plane.astype(jnp.float32), prev_avg.astype(jnp.float32)[None],
       vel.astype(jnp.float32)[None])
     return out, avg[0], new_vel[0], d[0, 0]
@@ -443,5 +446,6 @@ def compressed_mix(plane, resid, *, wire, mode="mean", groups: int = 1,
         scratch_shapes=[pltpu.VMEM((m, 1), jnp.float32)],
         input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
+        name="compressed_mix",
     )(*ins)
     return out, r, d[0, 0]

@@ -124,5 +124,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, hd), jnp.float32),     # accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qh, kh, vh)
     return out.transpose(0, 2, 1, 3)[:, :s]

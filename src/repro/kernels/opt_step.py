@@ -325,6 +325,7 @@ def opt_step(plane, grads, planes, scalars, *, kind, mode="none",
                         if wire in ("int8", "one_bit") else []),
         input_output_aliases=aliases,
         interpret=interpret,
+        name="opt_step",
     )(*ins)
     out, disp = outs[0], outs[-1][0, 0]
     new_planes = tuple(outs[1:1 + nstate])

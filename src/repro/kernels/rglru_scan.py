@@ -76,5 +76,6 @@ def rglru_scan(a, b, *, block_w: int = DEFAULT_BLOCK_W,
         out_shape=jax.ShapeDtypeStruct((bsz, s_pad, w), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
         interpret=interpret,
+        name="rglru_scan",
     )(af, bf)
     return out[:, :s].astype(a.dtype)

@@ -75,5 +75,6 @@ def rwkv6_scan(r, k, v, log_w, u, *, block_s: int = DEFAULT_BLOCK_S,
         out_shape=jax.ShapeDtypeStruct((bsz, h, s_pad, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
+        name="rwkv6_scan",
     )(rf, kf, vf, wf, u2)
     return out[:, :, :s].transpose(0, 2, 1, 3)
