@@ -10,16 +10,23 @@ One chip:
      width (32 layers, d_model 960, bf16 params, f32 planes) with 2
      workers for 8 steps, periodic-4 averaging: finite loss, at least 2
      averaging events, a finite final dispersion.
-  2. The same full-width bf16 phase, compiled: the engine takes Pallas
-     and the program holds Mosaic kernels (``tpu_custom_call``).
+  2. The same full-width bf16 phase, compiled: on one chip the engine
+     carries the leaves (``PhaseEngine.carry``), so the program holds
+     no (M, P) plane and no Mosaic update kernel.
   3. The dtype rounding (bf16, and f16 in f32 arithmetic) compiled by
      Mosaic in a kernel and by XLA in the jnp twin, against numpy's
      casts, bit for bit.
   4. smollm-360m ``--reduced`` with 4 workers, periodic-2, at its own
-     bf16 params and in f32: the Pallas engine (``kernel_impl="auto"``)
-     against the jnp reference engine (``kernel_impl="ref"``): losses
-     and dispersions agree at f32 roundoff (model matmuls at full f32
-     precision).
+     bf16 params and in f32 (model matmuls at full f32 precision). On
+     the plane carry, asked for by its layout, the Pallas kernels
+     (Mosaic ``opt_step`` and event) against their jnp twins: losses
+     and dispersions agree at f32 roundoff. The leaf carry the engine
+     takes against that plane carry: at f32 to roundoff; at bf16 within
+     the benchmark's limits for the one-chip cell
+     (``bench/limits/smollm360m-m2-s128-k4.json``), since XLA may skip
+     a bf16 rounding inside the leaf carry's fusions as excess
+     precision where the kernel rounds, and one flipped rounding moves
+     the loss by more than f32 roundoff.
 
 Four chips (``--chips 4``), and nothing else:
   1. ``train.main`` at full width with 8 workers sharded 2 rows per chip
@@ -105,6 +112,16 @@ def _batches(cfg, workers: int, steps: int, seq: int):
             for _ in range(steps)]
 
 
+def _reduced(jax, dtype: str):
+    """smollm-360m --reduced at ``dtype``: (config, seeded params)."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.models import init_params
+    cfg = dataclasses.replace(get_config("smollm-360m", reduced=True),
+                              dtype=dtype)
+    return cfg, init_params(cfg, jax.random.PRNGKey(0))
+
+
 def _reduced_run(jax, workers: int, kernel_impl: str, mesh=None,
                  dtype: str = "float32"):
     """Six steps of smollm-360m --reduced at ``dtype`` through
@@ -115,12 +132,7 @@ def _reduced_run(jax, workers: int, kernel_impl: str, mesh=None,
     difference between two paths (a psum-ordered average against an
     in-kernel mean) can flip a bf16 rounding and show up as a 1e-5
     loss difference; at f32 the paths agree at f32 roundoff."""
-    import dataclasses
-    from repro.configs import get_config
-    from repro.models import init_params
-    cfg = dataclasses.replace(get_config("smollm-360m", reduced=True),
-                              dtype=dtype)
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    cfg, params = _reduced(jax, dtype)
     engine = _engine(cfg, kernel_impl, mesh)
     with jax.default_matmul_precision("highest"):
         _, hist = engine.run(params, _batches(cfg, workers, 6, 64),
@@ -132,27 +144,22 @@ def _reduced_run(jax, workers: int, kernel_impl: str, mesh=None,
 def _full_width_phase(jax):
     """Compile one phase of full-width smollm-360m (bf16 params, 2
     workers, batch 4 x seq 128, an averaging event) on abstract state
-    and check that it runs the Mosaic kernels."""
+    and check that it carries the leaves: no plane, no Mosaic update."""
     from repro.configs import get_config
     from repro.core.engine import tree_stack
     from repro.models import init_params
     cfg = get_config("smollm-360m")
     engine = _engine(cfg, "auto")
-    assert engine._use_pallas(), "the default engine must take Pallas"
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     tree = jax.eval_shape(lambda p: engine.init(p, 2), params)
-    layout = engine.plane_layout(tree)
-    assert layout is not None, "full width must run flat-native"
-    assert layout[0].rounding_codes() == 1, "bf16 params round statically"
-    state = jax.eval_shape(lambda s: engine.to_planes(layout, s), tree)
+    assert engine.carry(tree) == "leaf", "one chip must carry the leaves"
     compiled = type(engine).run_phase.lower(
-        engine, state, tree_stack(_batches(cfg, 2, 2, 128)),
-        layout=layout).compile()
-    assert "tpu_custom_call" in compiled.as_text(), \
-        "no Mosaic kernel in the phase"
+        engine, tree, tree_stack(_batches(cfg, 2, 2, 128))).compile()
+    assert "tpu_custom_call" not in compiled.as_text(), \
+        "a Mosaic kernel in the leaf-carry phase"
     mem = compiled.memory_analysis()
-    print("[smoke] full-width bf16 phase holds tpu_custom_call (Pallas "
-          f"opt_step); arguments {mem.argument_size_in_bytes} B, temp "
+    print("[smoke] full-width bf16 phase carries the leaves; arguments "
+          f"{mem.argument_size_in_bytes} B, temp "
           f"{mem.temp_size_in_bytes} B", flush=True)
 
 
@@ -203,11 +210,16 @@ def _rounding_on_chip(jax):
           "(kernel with static code and code row; jnp twin)", flush=True)
 
 
-def _compare(a, b, what: str):
+#: the relative gaps of loss and dispersion that ``bench/check.py``
+#: allows the one-chip cell against its reference
+BENCH_RTOLS = (6e-4, 0.03)
+
+
+def _compare(a, b, what: str, rtols=(1e-5, 1e-4)):
     import numpy as np
     assert a["averages"] == b["averages"] == 3, (a["averages"],
                                                  b["averages"])
-    for key, rtol in (("loss", 1e-5), ("disp_trace", 1e-4)):
+    for key, rtol in zip(("loss", "disp_trace"), rtols):
         x = np.array([v for _, v in a[key]])
         y = np.array([v for _, v in b[key]])
         assert np.all(np.isfinite(x)) and np.all(np.isfinite(y)), key
@@ -221,9 +233,39 @@ def one_chip(jax):
     _full_width_phase(jax)
     _rounding_on_chip(jax)
     for dtype in ("bfloat16", "float32"):
-        _compare(_reduced_run(jax, 4, "auto", dtype=dtype),
-                 _reduced_run(jax, 4, "ref", dtype=dtype),
-                 f"pallas vs ref ({dtype})")
+        plane = _reduced_plane_run(jax, 4, dtype, "auto")
+        _compare(plane, _reduced_plane_run(jax, 4, dtype, "ref"),
+                 f"plane carry, pallas vs ref ({dtype})")
+        _compare(_reduced_run(jax, 4, "auto", dtype=dtype), plane,
+                 f"leaf carry vs plane carry ({dtype})",
+                 **({"rtols": BENCH_RTOLS} if dtype == "bfloat16" else {}))
+
+
+def _reduced_plane_run(jax, workers: int, dtype: str, kernel_impl: str):
+    """:func:`_reduced_run`'s six steps on the plane carry, with the
+    Pallas kernels (``kernel_impl`` "auto") or their jnp twins ("ref"):
+    one chip's rule carries the leaves, so the plane is asked for by its
+    layout and driven a phase at a time."""
+    from repro.core import FlatOptSpec, FlatSpec, tree_stack
+    cfg, params = _reduced(jax, dtype)
+    engine = _engine(cfg, kernel_impl)
+    state = engine.init(params, workers)
+    spec = FlatSpec.of(state.worker_params)
+    layout = (spec, FlatOptSpec.of(spec, state.opt_state))
+    state = engine.to_planes(layout, state)
+    batches = _batches(cfg, workers, 6, 64)
+    hist = {"loss": [], "disp_trace": [], "averages": 0}
+    with jax.default_matmul_precision("highest"):
+        for t in range(0, 6, 2):
+            state, tr = engine.run_phase(state, tree_stack(batches[t:t + 2]),
+                                         layout=layout)
+            tr = jax.device_get(tr)
+            for i in range(2):
+                hist["loss"].append((t + i + 1, float(tr["loss"][i])))
+                hist["disp_trace"].append(
+                    (t + i + 1, float(tr["dispersion"][i])))
+                hist["averages"] += int(tr["avg_code"][i] != 0)
+    return hist
 
 
 def four_chips(jax):

@@ -52,6 +52,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.ref import widen
+
 
 class SchedState(NamedTuple):
     """The stateful-schedule carry: everything an adaptive decision may
@@ -381,10 +383,12 @@ def average_inner(worker_tree, inner_groups: int):
 
 def worker_dispersion(worker_tree):
     """Mean squared distance of workers from their average — the paper's
-    E||w_i - w̄||² variance diagnostic (Eq. 4)."""
+    E||w_i - w̄||² variance diagnostic (Eq. 4), each leaf measured at
+    its own precision (:func:`repro.kernels.ref.widen`)."""
     def sq(x):
-        m = jnp.mean(x.astype(jnp.float32), axis=0, keepdims=True)
-        return jnp.sum(jnp.square(x.astype(jnp.float32) - m)) / x.shape[0]
+        xf = widen(x)
+        m = jnp.mean(xf, axis=0, keepdims=True)
+        return jnp.sum(jnp.square(xf - m)) / x.shape[0]
     return sum(jax.tree.leaves(jax.tree.map(sq, worker_tree)))
 
 

@@ -23,14 +23,17 @@ from a checkpointed ``EngineState``.
 
 Two device-residency layers sit on top of the PR 1 scan:
 
-- **Flat parameter plane** (default): inside a phase the scan carries
-  the workers as one contiguous ``(M, P)`` float32 plane
-  (:class:`repro.core.flat.FlatSpec`; bit-exact pack/unpack), so every
-  averaging event is a single fused pass — worker mean (global or
-  per-group), Eq. 4 dispersion, broadcast, and the outer-optimizer
-  momentum step — instead of 3–4 params-pytree traversals
-  (``repro.kernels.avg_disp`` on TPU, its jnp twin on CPU). Trees with
-  dtypes that have no exact float32 image fall back to the tree path.
+- **Flat parameter plane** (where it is free, :func:`carry_for`): inside
+  a phase the scan carries the workers as one contiguous ``(M, P)``
+  float32 plane (:class:`repro.core.flat.FlatSpec`; bit-exact
+  pack/unpack), so every averaging event is a single fused pass — worker
+  mean (global or per-group), Eq. 4 dispersion, broadcast, and the
+  outer-optimizer momentum step — instead of 3–4 params-pytree
+  traversals (``repro.kernels.avg_disp`` on TPU, its jnp twin on CPU).
+  A single-device TPU phase carries the leaves instead: there the
+  unpack and pack around every step cost more than the plane saves.
+  Trees with dtypes that have no exact float32 image take the leaf
+  (tree) path too.
   :meth:`PhaseEngine.run` packs the state into that plane form once
   (:meth:`PhaseEngine.start_state`) and carries the planes from phase
   to phase, so a full-width model never holds a tree copy of its
@@ -172,6 +175,20 @@ def make_worker_step(loss_fn: Callable, optimizer) -> Callable:
     return step_fn
 
 
+def carry_for(platform: str, sharded: bool) -> str:
+    """The carry a phase takes: ``"plane"`` (the (M, P) f32 plane) where
+    unpacking a plane row into the leaves is free, else ``"leaf"`` (the
+    leaves in their own dtypes).
+
+    On a TPU a flat row and a leaf are tiled differently, so a step on
+    the plane pays a relayout and a cast each way — every row unpacked
+    for the forward pass, the gradients packed for the update — which
+    outweighs the fused update and event it buys. On other platforms
+    reshaping a row is a bitcast. A mesh keeps the plane: the sharded
+    phase runs on it alone."""
+    return "leaf" if platform == "tpu" and not sharded else "plane"
+
+
 def make_plane_step(loss_fn: Callable, spec: FlatSpec) -> Callable:
     """The flat-native local step: losses and gradients straight on the
     (M, P) plane. Each worker row is unpacked to a params *view*
@@ -229,8 +246,13 @@ class PhaseEngine:
     unroll: longer compiles, per-step speed of eager dispatch). On real
     accelerator meshes leave the default rolled scan.
 
-    ``flat`` selects the (M, P) flat-plane scan carry (default; falls
-    back to the tree carry for trees FlatSpec cannot embed). With
+    ``flat`` (default) lets a phase carry the (M, P) flat plane where
+    that is free (:func:`carry_for`): on a mesh, and off a TPU. A
+    single-device TPU phase, and every tree FlatSpec cannot embed,
+    carries the leaves
+    instead — params in their dtypes, optimizer state as its own tree,
+    the tree optimizer per leaf, the event by ``_tree_average``.
+    ``flat=False`` carries leaves everywhere. With
     ``fused_opt`` (default) and an optimizer that speaks the plane
     protocol (SGD/Momentum/AdamW: ``plane_kind``/``plane_hypers``/
     ``plane_scalars`` + a ``FlatOptSpec``-alignable state), the scan is
@@ -785,7 +807,7 @@ class PhaseEngine:
         state and per-step traces {loss, dispersion, avg_code} — the only
         host transfer a phase needs.
 
-        Three carries, picked per (flat, optimizer) support:
+        Three carries, picked per :meth:`carry` and optimizer support:
           flat-native — the state in plane form (:meth:`to_planes`,
             ``layout`` from :meth:`plane_layout`): params AND optimizer
             state as (M, P) planes, grads via one vjp through the
@@ -795,8 +817,9 @@ class PhaseEngine:
           flat        — params plane packed on entry, with per-step
             pack/unpack around the tree-mapped optimizer (optimizers
             without plane support);
-          tree        — params pytree carry (dtypes FlatSpec can't
-            embed)."""
+          tree        — the leaf carry: params pytree in its dtypes
+            (``carry`` "leaf": dtypes FlatSpec can't embed, one TPU,
+            ``flat=False``)."""
         num_workers = jax.tree.leaves(state.worker_params)[0].shape[0]
         self._check_workers(num_workers)
         self._check_compressible(state.worker_params)
@@ -810,7 +833,7 @@ class PhaseEngine:
             assert self.plane_layout(state) is None, \
                 "a flat-native state runs in plane form: pass " \
                 "start_state()'s state and layout"
-            use_flat = self.flat and FlatSpec.supports(state.worker_params)
+            use_flat = self.carry(state) == "plane"
             # compressed events encode on the plane even in the tree
             # carry (pack/unpack around the event only — events are rare)
             spec = (FlatSpec.of(state.worker_params)
@@ -987,10 +1010,18 @@ class PhaseEngine:
                         return average(args[0], args[1], "all",
                                        W=W, alive=alive)[:2] + (args[2],)
 
+                    # only a hierarchical schedule emits inner events (code
+                    # 1); the others switch on (none, all), which lets the
+                    # TPU compiler pass the carry through the none branch
+                    # in place rather than copy every leaf
+                    if sched.kind == "hierarchical":
+                        idx, branches = code, [none_branch, inner_branch,
+                                               all_branch]
+                    else:
+                        idx, branches = code // 2, [none_branch, all_branch]
                     with jax.named_scope("engine.average"):
                         wp_c, outer_c, resid = jax.lax.switch(
-                            code, [none_branch, inner_branch, all_branch],
-                            (wp_c, outer_c, resid))
+                            idx, branches, (wp_c, outer_c, resid))
             loss_t = (jnp.mean(losses) if fp is None
                       else jnp.sum(losses * alive) / jnp.sum(alive))
             if tm is not None:
@@ -1529,12 +1560,22 @@ class PhaseEngine:
             check_vma=False)(state, dataset, idx_block)
 
     # ---- plane-form state (what run() carries between phases) ------------
+    def carry(self, state: EngineState) -> str:
+        """``"plane"`` or ``"leaf"``: what a phase of the tree-form
+        ``state`` carries (:func:`carry_for`, on the default backend,
+        as the kernels choose theirs). ``flat=False`` and trees FlatSpec
+        cannot embed carry leaves everywhere."""
+        wp = state.worker_params
+        if not (self.flat and FlatSpec.supports(wp)):
+            return "leaf"
+        return carry_for(jax.default_backend(), self.mesh is not None)
+
     def plane_layout(self, state: EngineState):
         """(FlatSpec, FlatOptSpec) of the flat-native carry for this
         (possibly abstract) state, or None where the phase carries a
-        tree (``flat=False``, dtypes FlatSpec cannot embed, optimizers
-        without the plane protocol)."""
-        if not (self.flat and FlatSpec.supports(state.worker_params)):
+        tree (:meth:`carry` "leaf", optimizers without the plane
+        protocol)."""
+        if self.carry(state) == "leaf":
             return None
         spec = FlatSpec.of(state.worker_params)
         opt_spec = self._opt_spec(spec, state.opt_state)
@@ -1667,7 +1708,8 @@ class PhaseEngine:
         requires ``PhaseEngine(telemetry=True)``) receives one
         ``phase_metrics`` record per compiled dispatch — flushed from
         the on-device accumulator that rode this phase's scan, on the
-        SAME once-per-phase host fetch as the traces — plus an
+        SAME once-per-phase host fetch as the traces, naming the
+        phase's ``carry`` (:meth:`carry`: "plane" or "leaf") — plus an
         ``averaging_event`` per event step and a ``fault_event`` per
         scripted crash/rejoin the phase covered.
         """
@@ -1680,6 +1722,7 @@ class PhaseEngine:
         with span("engine.start_state"):
             state, layout = self.start_state(params, num_workers, seed,
                                              state)
+        carry = "plane" if layout is not None else self.carry(state)
         t0 = int(state.step)
         block = phase_len or self.default_phase_len()
         needs_eval = bool(record_every and (eval_fn or worker_eval_fn))
@@ -1769,7 +1812,8 @@ class PhaseEngine:
                             worker=ev.worker))
                 flushed = tele_metrics.flush_metrics(trace["metrics"])
                 sink.emit(make_record(
-                    "phase_metrics", t0=t_first + 1, t1=t, wall_s=wall,
+                    "phase_metrics", t0=t_first + 1, t1=t, carry=carry,
+                    wall_s=wall,
                     steps_per_s=(k / wall if wall > 0 else None),
                     loss_trace=hist["loss"][n_loss:],
                     disp_trace=hist["disp_trace"][n_disp:], **flushed))

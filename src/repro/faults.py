@@ -43,6 +43,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import widen
+
 #: fold_in salt for the straggle uniforms ("str"), keeping the stream
 #: independent of the gossip-partner (0x676F73) and stochastic-rounding
 #: (0x656E63) streams that hang off the same dec_key
@@ -514,10 +516,11 @@ def masked_mean_tree(tree, alive):
 
 
 def masked_dispersion_tree(tree, alive):
-    """Tree twin of :func:`masked_dispersion` (per-leaf f32 sums)."""
+    """Tree twin of :func:`masked_dispersion` (per-leaf f32 sums, each
+    leaf at its own precision: :func:`repro.kernels.ref.widen`)."""
     total = jnp.float32(0.0)
     for x in jax.tree.leaves(tree):
-        xf = x.astype(jnp.float32)
+        xf = widen(x)
         glob = jnp.sum(xf * _row(alive, x), axis=0) / jnp.sum(alive)
         total = total + jnp.sum(
             jnp.square(xf - glob[None]) * _row(alive, x))

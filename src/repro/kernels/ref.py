@@ -151,6 +151,19 @@ def round_to_codes(x, codes):
                      jnp.where(codes == 2.0, f16(), x))
 
 
+def widen(x):
+    """``x`` as float32 at its own precision: a bf16 or f16 array's
+    image, rounded through its dtype explicitly (:func:`round_to_codes`).
+    The TPU compiler may take a narrowing ``astype`` followed by this
+    widening as excess precision and hand on the unrounded value; the
+    explicit rounding is an identity wherever the values are stored
+    rounded."""
+    code = {jnp.dtype(jnp.bfloat16): 1,
+            jnp.dtype(jnp.float16): 2}.get(jnp.dtype(x.dtype))
+    xf = x.astype(jnp.float32)
+    return xf if code is None else round_to_codes(xf, code)
+
+
 def plane_update_ref(plane, grads, planes, scalars, *, kind, mu=0.9,
                      nesterov=False, b1=0.9, b2=0.95, eps=1e-8,
                      weight_decay=0.0, codes=None):

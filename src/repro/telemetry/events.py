@@ -30,7 +30,9 @@ import os
 import subprocess
 import sys
 
-TELEMETRY_VERSION = 1
+#: 2: ``phase_metrics`` names the phase's ``carry`` ("plane" or "leaf");
+#: a version-1 record has no such field
+TELEMETRY_VERSION = 2
 
 RECORD_TYPES = (
     "run_meta",

@@ -14,6 +14,7 @@ described topology loads the TPU compiler library, which one process
 holds at a time), and the persistent compilation cache is off around
 these compiles: a described device's executable cannot be read back.
 """
+import dataclasses
 import os
 
 import jax
@@ -123,3 +124,54 @@ def test_kernel_compiles_at_full_width(one_chip, name):
     assert total <= HBM_BYTES, (name, total / 2 ** 30)
     # the kernels never copy a plane: no temp beyond a few scalars
     assert ma.temp_size_in_bytes < 2 ** 20, (name, ma.temp_size_in_bytes)
+
+
+def _memory_total(compiled):
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+
+
+def test_leaf_carry_phase_compiles_without_the_plane(one_chip, monkeypatch):
+    """smollm-360m at full width cut to 4 layers, 2 workers, periodic
+    K=4, Momentum: on one v5e the rule takes the leaf carry, whose phase
+    compiles with no plane-sized buffer and no Mosaic update on one, and
+    needs less HBM than the plane carry of the same state (f32 planes,
+    gradient plane, fused kernel). Each layer widens the gap (at one
+    layer the leaf carry's temporaries still outweigh it)."""
+    from repro.configs import get_config
+    from repro.core import AveragingSchedule, FlatOptSpec, FlatSpec, \
+        PhaseEngine
+    from repro.models import init_params, lm_loss
+    from repro.optim import Momentum
+    # the kernels pick interpret mode from the default backend, which is
+    # the CPU here; the described chip compiles them with Mosaic
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("smollm-360m")
+    cfg = dataclasses.replace(cfg, num_layers=4, layers=cfg.layers[:4])
+    engine = PhaseEngine(lambda p, b, r: lm_loss(cfg, p, b),
+                         Momentum(lr=0.01, mu=0.9),
+                         AveragingSchedule("periodic", 4))
+    put = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                         sharding=one_chip)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    tree = jax.tree.map(put, jax.eval_shape(lambda p: engine.init(p, M),
+                                            params))
+    batch = {"tokens": put(jax.ShapeDtypeStruct((4, M, 4, 128), jnp.int32))}
+    assert engine.carry(tree) == "leaf"
+    assert engine.plane_layout(tree) is None
+    leaf = type(engine).run_phase.lower(engine, tree, batch).compile()
+
+    spec = FlatSpec.of(tree.worker_params)
+    layout = (spec, FlatOptSpec.of(spec, tree.opt_state))
+    planes = jax.tree.map(put, jax.eval_shape(
+        lambda s: engine.to_planes(layout, s), tree))
+    plane = type(engine).run_phase.lower(engine, planes, batch,
+                                         layout=layout).compile()
+    row = f"[{M},{spec.width}]"
+    assert "tpu_custom_call" in plane.as_text() and row in plane.as_text()
+    assert "tpu_custom_call" not in leaf.as_text()
+    assert row not in leaf.as_text()
+    print(f"leaf carry {_memory_total(leaf)} B, plane carry "
+          f"{_memory_total(plane)} B")
+    assert _memory_total(leaf) < _memory_total(plane)
