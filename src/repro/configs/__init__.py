@@ -89,6 +89,9 @@ class ModelConfig:
     score_dtype: str = "float32"     # attention score traffic dtype
     # misc
     norm: str = "rmsnorm"            # rmsnorm | layernorm
+    norm_eps: float = 1e-6           # added to the variance in every norm
+    embed_scale: bool = True         # scale token embeddings by sqrt(d_model)
+    linear_bias: bool = False        # biases on q/k/v/o and MLP projections
     act: str = "silu"                # silu | gelu | relu2
     gated_mlp: bool = True
     tie_embeddings: bool = True
@@ -128,6 +131,8 @@ class ModelConfig:
         for spec in self.layers:
             if spec.mixer in ("attn", "attn_local"):
                 n += d * (self.q_dim + 2 * self.kv_dim) + self.q_dim * d
+                if self.linear_bias:
+                    n += self.q_dim + 2 * self.kv_dim + d
             elif spec.mixer == "rglru":
                 w = self.rnn_width or d
                 n += 2 * d * w + w * d + self.conv_width * w + 3 * w
@@ -138,6 +143,8 @@ class ModelConfig:
             if spec.ffn == "dense":
                 mult = 3 if self.gated_mlp else 2
                 n += mult * d * self.d_ff
+                if self.linear_bias:
+                    n += (mult - 1) * self.d_ff + d
             elif spec.ffn == "moe":
                 mult = 3 if self.gated_mlp else 2
                 n += self.num_experts * mult * d * self.moe_d_ff

@@ -2,7 +2,10 @@
 and RoPE. [arXiv:2402.19173] StarCoder 2 and The Stack v2.
 
 30 layers, d_model=3072, 24 heads (GQA kv=2, head_dim 128), d_ff=12288
-(non-gated GELU MLP), vocab 49152, window 4096, layernorm.
+(non-gated GELU MLP), vocab 49152, window 4096, RoPE theta 999,999.44,
+LayerNorm with bias and epsilon 1e-5, biases on every linear layer, no
+embedding scale, tied embeddings: the published ``config.json``
+(https://huggingface.co/bigcode/starcoder2-3b/blob/main/config.json).
 """
 from repro.configs import LayerSpec, ModelConfig, _pattern, reduce_config
 
@@ -20,8 +23,11 @@ def make_config() -> ModelConfig:
         vocab_size=49_152,
         layers=_pattern([LayerSpec(mixer="attn_local")], 30),
         sliding_window=4096,
-        rope_theta=100_000.0,
+        rope_theta=999_999.4420358813,
         norm="layernorm",
+        norm_eps=1e-5,
+        embed_scale=False,
+        linear_bias=True,
         act="gelu",
         gated_mlp=False,
         citation="arXiv:2402.19173",

@@ -1,9 +1,17 @@
 """GQA attention: training/prefill (full or sliding-window causal),
 single-token decode against a KV cache, and cross-attention.
 
-Two interchangeable compute paths:
+Interchangeable compute paths for full-sequence self-attention:
   - "xla":    plain jnp einsums (used for dry-run/cost-analysis & CPU)
-  - "pallas": repro.kernels flash attention (TPU target, interpret on CPU)
+  - "pallas": repro.kernels flash attention (forward only; TPU target,
+              interpret on CPU)
+  - "splash": repro.kernels.window_attention, causal (windowed) attention
+              with a backward pass that never writes the (S, S) scores to
+              HBM: the training path at long sequences. Shapes it does
+              not take fall back to "xla", its twin.
+
+The attention core (scores, softmax, weighted sum) of every path runs
+under the ``model.attention`` named scope.
 """
 from __future__ import annotations
 
@@ -12,7 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ModelConfig
-from repro.models.layers import cdtype, dense_init, rope_freqs, apply_rope
+from repro.models.layers import (apply_rope, cdtype, dense_init,
+                                 init_biases, linear, rope_freqs)
 
 
 def init_attn(cfg: ModelConfig, key, cross: bool = False):
@@ -23,6 +32,8 @@ def init_attn(cfg: ModelConfig, key, cross: bool = False):
         "wk": dense_init(ks[1], (d, cfg.kv_dim), 0, cdtype(cfg)),
         "wv": dense_init(ks[2], (d, cfg.kv_dim), 0, cdtype(cfg)),
         "wo": dense_init(ks[3], (cfg.q_dim, d), 0, cdtype(cfg)),
+        **init_biases(cfg, {"bq": cfg.q_dim, "bk": cfg.kv_dim,
+                            "bv": cfg.kv_dim, "bo": d}),
     }
 
 
@@ -78,6 +89,28 @@ def _banded_attention(cfg, q, k, v, *, window, scale, score_dtype,
     return jnp.concatenate(outs, axis=1)[:, :s]
 
 
+def _core(cfg, q, k, v, *, impl, causal, window, scale, same, pos_offset):
+    """Scores, softmax and weighted sum of one of the compute paths;
+    ``same``: self-attention with as many keys as queries."""
+    score_dt = jnp.dtype(cfg.score_dtype)
+    sq, sk = q.shape[1], k.shape[1]
+    if impl == "pallas" and same:
+        from repro.kernels import ops
+        return ops.flash_attention(q, k, v, causal=causal, window=window,
+                                   scale=scale)
+    if impl == "splash" and same and causal and pos_offset == 0:
+        from repro.kernels import window_attention as wa
+        if wa.usable(sq, cfg.head_dim):
+            return wa.window_attention(q, k, v, window=window, scale=scale)
+    if (cfg.attn_banded and window > 0 and causal and same
+            and pos_offset == 0):
+        return _banded_attention(cfg, q, k, v, window=window, scale=scale,
+                                 score_dtype=score_dt)
+    mask = make_mask(sq, sk, causal=causal, window=window,
+                     q_offset=pos_offset)[None, None]
+    return _sdpa_xla(q, k, v, mask, scale, score_dt)
+
+
 def make_mask(sq: int, sk: int, *, causal: bool, window: int = 0,
               q_offset: int = 0):
     """Boolean mask (sq, sk), True = attend. q position i maps to absolute
@@ -103,9 +136,9 @@ def attention(cfg: ModelConfig, p, x, *, layer, kv_x=None, impl="xla",
     b, sq, _ = x.shape
     src = x if kv_x is None else kv_x
     sk = src.shape[1]
-    q = _split_heads(x @ p["wq"], cfg.num_heads, cfg.head_dim)
-    k = _split_heads(src @ p["wk"], cfg.num_kv_heads, cfg.head_dim)
-    v = _split_heads(src @ p["wv"], cfg.num_kv_heads, cfg.head_dim)
+    q = _split_heads(linear(p, "wq", x), cfg.num_heads, cfg.head_dim)
+    k = _split_heads(linear(p, "wk", src), cfg.num_kv_heads, cfg.head_dim)
+    v = _split_heads(linear(p, "wv", src), cfg.num_kv_heads, cfg.head_dim)
 
     self_attn = kv_x is None
     if self_attn and cfg.pos_emb == "rope":
@@ -118,20 +151,11 @@ def attention(cfg: ModelConfig, p, x, *, layer, kv_x=None, impl="xla",
     window = cfg.sliding_window if (layer.mixer == "attn_local" and self_attn) else 0
     scale = 1.0 / np.sqrt(cfg.head_dim)
 
-    score_dt = jnp.dtype(cfg.score_dtype)
-    if impl == "pallas" and self_attn and sq == sk:
-        from repro.kernels import ops
-        out = ops.flash_attention(q, k, v, causal=causal, window=window,
-                                  scale=scale)
-    elif (cfg.attn_banded and window > 0 and causal and self_attn
-          and sq == sk and pos_offset == 0):
-        out = _banded_attention(cfg, q, k, v, window=window, scale=scale,
-                                score_dtype=score_dt)
-    else:
-        mask = make_mask(sq, sk, causal=causal, window=window,
-                         q_offset=pos_offset)[None, None]
-        out = _sdpa_xla(q, k, v, mask, scale, score_dt)
-    out = out.reshape(b, sq, cfg.q_dim) @ p["wo"]
+    with jax.named_scope("model.attention"):
+        out = _core(cfg, q, k, v, impl=impl, causal=causal, window=window,
+                    scale=scale, same=self_attn and sq == sk,
+                    pos_offset=pos_offset)
+    out = linear(p, "wo", out.reshape(b, sq, cfg.q_dim))
     if return_kv:
         return out, (k, v)
     return out
@@ -154,9 +178,9 @@ def decode_attention(cfg: ModelConfig, p, x, cache, pos, *, layer):
     a static-size dynamic slice (O(window) instead of O(S))."""
     b = x.shape[0]
     s_cache = cache["k"].shape[1]
-    q = _split_heads(x @ p["wq"], cfg.num_heads, cfg.head_dim)
-    k = _split_heads(x @ p["wk"], cfg.num_kv_heads, cfg.head_dim)
-    v = _split_heads(x @ p["wv"], cfg.num_kv_heads, cfg.head_dim)
+    q = _split_heads(linear(p, "wq", x), cfg.num_heads, cfg.head_dim)
+    k = _split_heads(linear(p, "wk", x), cfg.num_kv_heads, cfg.head_dim)
+    v = _split_heads(linear(p, "wv", x), cfg.num_kv_heads, cfg.head_dim)
 
     if cfg.pos_emb == "rope":
         cos, sin = rope_freqs(cfg, pos[None] if pos.ndim == 0 else pos)
@@ -179,22 +203,22 @@ def decode_attention(cfg: ModelConfig, p, x, cache, pos, *, layer):
         kpos = jnp.arange(s_cache)
     mask = (kpos <= pos)[None, None, None, :]  # (1,1,1,Sk)
     out = _sdpa_xla(q, ks, vs, mask, scale)
-    return out.reshape(b, 1, cfg.q_dim) @ p["wo"], new_cache
+    return linear(p, "wo", out.reshape(b, 1, cfg.q_dim)), new_cache
 
 
 def decode_cross_attention(cfg: ModelConfig, p, x, cache):
     """Cross-attn at decode time: the memory K/V are precomputed at
     prefill and stored in ``cache`` as {"k","v"}: (B, Sm, Hkv, hd)."""
     b = x.shape[0]
-    q = _split_heads(x @ p["wq"], cfg.num_heads, cfg.head_dim)
+    q = _split_heads(linear(p, "wq", x), cfg.num_heads, cfg.head_dim)
     sm = cache["k"].shape[1]
     mask = jnp.ones((1, 1, 1, sm), bool)
     out = _sdpa_xla(q, cache["k"], cache["v"], mask, 1.0 / np.sqrt(cfg.head_dim))
-    return out.reshape(b, 1, cfg.q_dim) @ p["wo"]
+    return linear(p, "wo", out.reshape(b, 1, cfg.q_dim))
 
 
 def cross_cache_from_memory(cfg: ModelConfig, p, memory):
     """Precompute cross-attention K/V from encoder/vision memory."""
-    k = _split_heads(memory @ p["wk"], cfg.num_kv_heads, cfg.head_dim)
-    v = _split_heads(memory @ p["wv"], cfg.num_kv_heads, cfg.head_dim)
+    k = _split_heads(linear(p, "wk", memory), cfg.num_kv_heads, cfg.head_dim)
+    v = _split_heads(linear(p, "wv", memory), cfg.num_kv_heads, cfg.head_dim)
     return {"k": k, "v": v}

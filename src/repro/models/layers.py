@@ -37,7 +37,7 @@ def apply_norm(cfg: ModelConfig, p, x):
     if cfg.norm == "layernorm":
         xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    xf = xf * jax.lax.rsqrt(var + 1e-6)
+    xf = xf * jax.lax.rsqrt(var + cfg.norm_eps)
     out = xf * (1.0 + p["scale"].astype(jnp.float32))
     if cfg.norm == "layernorm":
         out = out + p["bias"].astype(jnp.float32)
@@ -59,6 +59,23 @@ def activate(cfg: ModelConfig, x):
     raise ValueError(cfg.act)
 
 
+def linear(p, w: str, x):
+    """``x @ p[w]``, plus the bias ``p["b" + w[1:]]`` (``wq`` -> ``bq``,
+    ``w_in`` -> ``b_in``) where the layer has one
+    (``ModelConfig.linear_bias``)."""
+    y = x @ p[w]
+    b = p.get("b" + w[1:])
+    return y if b is None else y + b
+
+
+def init_biases(cfg: ModelConfig, widths: dict) -> dict:
+    """Zero biases ``name -> (width,)``, or none where the model has no
+    linear biases."""
+    if not cfg.linear_bias:
+        return {}
+    return {n: jnp.zeros((w,), cdtype(cfg)) for n, w in widths.items()}
+
+
 def init_mlp(cfg: ModelConfig, key, d_ff: int | None = None):
     d, f = cfg.d_model, d_ff or cfg.d_ff
     ks = jax.random.split(key, 3)
@@ -68,16 +85,18 @@ def init_mlp(cfg: ModelConfig, key, d_ff: int | None = None):
     }
     if cfg.gated_mlp:
         p["w_gate"] = dense_init(ks[2], (d, f), 0, cdtype(cfg))
+    p.update(init_biases(cfg, {"b_in": f, "b_out": d,
+                               **({"b_gate": f} if cfg.gated_mlp else {})}))
     return p
 
 
 def apply_mlp(cfg: ModelConfig, p, x):
-    h = x @ p["w_in"]
+    h = linear(p, "w_in", x)
     if cfg.gated_mlp:
-        h = activate(cfg, x @ p["w_gate"]) * h
+        h = activate(cfg, linear(p, "w_gate", x)) * h
     else:
         h = activate(cfg, h)
-    return h @ p["w_out"]
+    return linear(p, "w_out", h)
 
 
 # --------------------------------------------------------------------------
@@ -96,7 +115,7 @@ def init_embed(cfg: ModelConfig, key):
 
 def embed(cfg: ModelConfig, p, tokens, pos_offset=0):
     x = jnp.take(p["tok"], tokens, axis=0)
-    if cfg.family != "ssm":  # gemma-style sqrt(d) scaling for attn models
+    if cfg.embed_scale and cfg.family != "ssm":  # gemma-style sqrt(d)
         x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
     if cfg.pos_emb == "learned":
         s = tokens.shape[-1]

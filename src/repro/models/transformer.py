@@ -153,6 +153,21 @@ def forward(cfg: ModelConfig, params, batch, *, impl="xla", remat=False,
     Returns (logits fp32 (B,S,V), aux dict of scalar metrics); with
     ``return_cache`` (true prefill) additionally a decode cache sized
     ``cache_len`` (>= S), ready for repro.models.decode_step."""
+    x, aux_sum, caches = hidden(cfg, params, batch, impl=impl, remat=remat,
+                                return_cache=return_cache,
+                                cache_len=cache_len)
+    logits = L.unembed(cfg, params["embed"], x)
+    if return_cache:
+        cache = {"pos": jnp.asarray(batch["tokens"].shape[1], jnp.int32),
+                 "layers": caches}
+        return logits, aux_sum, cache
+    return logits, aux_sum
+
+
+def hidden(cfg: ModelConfig, params, batch, *, impl="xla", remat=False,
+           return_cache=False, cache_len=0):
+    """The blocks and the final norm: (B, S, d) states, the aux sums and
+    the per-layer decode caches (empty unless ``return_cache``)."""
     memory = _get_memory(cfg, params, batch, impl)
     tokens = batch["tokens"]
     x = L.embed(cfg, params["embed"], tokens)
@@ -177,28 +192,60 @@ def forward(cfg: ModelConfig, params, batch, *, impl="xla", remat=False,
         for k_ in aux:
             aux_sum[k_] = aux_sum[k_] + aux[k_]
     x = L.apply_norm(cfg, params["final_norm"], x)
-    logits = L.unembed(cfg, params["embed"], x)
-    if return_cache:
-        cache = {"pos": jnp.asarray(tokens.shape[1], jnp.int32),
-                 "layers": caches}
-        return logits, aux_sum, cache
-    return logits, aux_sum
+    return x, aux_sum, caches
 
 
-def lm_loss(cfg: ModelConfig, params, batch, *, impl="xla", remat=False):
+def _nll_sum(embed_p, x, labels, *, cfg: ModelConfig):
+    """Sum over the positions with a label (>= 0) of the next-token
+    negative log-likelihood of states ``x``."""
+    logits = L.unembed(cfg, embed_p, x)
+    mask = (labels >= 0).astype(jnp.float32)
+    labels_c = jnp.clip(labels, 0, cfg.padded_vocab - 1)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, labels_c[..., None], axis=-1)[..., 0]
+    return jnp.sum(nll * mask)
+
+
+def _chunked_nll_sum(cfg: ModelConfig, embed_p, x, labels, chunk: int):
+    """:func:`_nll_sum` over sequence chunks of ``chunk`` positions, each
+    under ``jax.checkpoint``: the backward pass recomputes one chunk's
+    logits at a time, so the (B, S, V) logits never exist whole."""
+    b, s, d = x.shape
+    if s % chunk:
+        raise ValueError(f"sequence {s} is not a whole number of "
+                         f"{chunk}-position loss chunks")
+    n = s // chunk
+    xs = x.reshape(b, n, chunk, d).swapaxes(0, 1)
+    ls = labels.reshape(b, n, chunk).swapaxes(0, 1)
+    one = jax.checkpoint(functools.partial(_nll_sum, cfg=cfg))
+
+    def body(acc, xl):
+        return acc + one(embed_p, *xl), None
+    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xs, ls))
+    return total
+
+
+def lm_loss(cfg: ModelConfig, params, batch, *, impl="xla", remat=False,
+            loss_chunk=0):
     """Next-token cross-entropy (+ MoE aux). labels default to shifted
-    tokens; positions where label < 0 are masked."""
-    logits, aux = forward(cfg, params, batch, impl=impl, remat=remat)
+    tokens; positions where label < 0 are masked. ``loss_chunk`` > 0
+    computes the cross-entropy over sequence chunks of that many
+    positions (:func:`_chunked_nll_sum`), with the same value and
+    gradient."""
+    x, aux, _ = hidden(cfg, params, batch, impl=impl, remat=remat)
     if "labels" in batch:
         labels = batch["labels"]
     else:
         labels = jnp.pad(batch["tokens"][:, 1:], ((0, 0), (0, 1)),
                          constant_values=-1)
     mask = (labels >= 0).astype(jnp.float32)
-    labels_c = jnp.clip(labels, 0, cfg.padded_vocab - 1)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, labels_c[..., None], axis=-1)[..., 0]
-    loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    if loss_chunk:
+        with jax.named_scope("model.loss"):
+            total = _chunked_nll_sum(cfg, params["embed"], x, labels,
+                                    loss_chunk)
+    else:
+        total = _nll_sum(params["embed"], x, labels, cfg=cfg)
+    loss = total / jnp.maximum(jnp.sum(mask), 1.0)
     moe_layers = max(1, sum(1 for s in cfg.layers if s.ffn == "moe"))
     aux_loss = cfg.router_aux_coef * aux["load_balance"] / moe_layers \
         + 1e-3 * aux["router_z"] / moe_layers
