@@ -30,8 +30,9 @@ Two device-residency layers sit on top of the PR 1 scan:
   mean (global or per-group), Eq. 4 dispersion, broadcast, and the
   outer-optimizer momentum step — instead of 3–4 params-pytree
   traversals (``repro.kernels.avg_disp`` on TPU, its jnp twin on CPU).
-  A single-device TPU phase carries the leaves instead: there the
-  unpack and pack around every step cost more than the plane saves.
+  A TPU phase carries the leaves instead (one device, or each shard of
+  a mesh under ``psum``): there the unpack and pack around every step
+  cost more than the plane saves.
   Trees with dtypes that have no exact float32 image take the leaf
   (tree) path too.
   :meth:`PhaseEngine.run` packs the state into that plane form once
@@ -115,7 +116,7 @@ from repro.kernels.ref import (avg_disp_outer_ref, avg_disp_ref,
                                compressed_avg_ref, compressed_mix_ref,
                                mix_disp_ref, opt_step_ref,
                                plane_average_ref, plane_update_ref,
-                               round_to_codes)
+                               round_to_codes, widen)
 from repro.topology import MIX_KINDS, Topology, comm_bytes, mix_tree
 
 # a host span on the profiler's clock; inert while no trace is recorded
@@ -131,6 +132,11 @@ def replicate(tree, num_workers: int):
     as the paper prescribes)."""
     return jax.tree.map(
         lambda x: jnp.broadcast_to(x[None], (num_workers,) + x.shape), tree)
+
+
+def _as_leaves(mean, tree):
+    """An f32 worker-mean tree rounded to ``tree``'s leaf dtypes."""
+    return jax.tree.map(lambda g, x: g.astype(x.dtype), mean, tree)
 
 
 def unreplicate(tree):
@@ -175,7 +181,7 @@ def make_worker_step(loss_fn: Callable, optimizer) -> Callable:
     return step_fn
 
 
-def carry_for(platform: str, sharded: bool) -> str:
+def carry_for(platform: str, sharded: bool, collective: str) -> str:
     """The carry a phase takes: ``"plane"`` (the (M, P) f32 plane) where
     unpacking a plane row into the leaves is free, else ``"leaf"`` (the
     leaves in their own dtypes).
@@ -183,10 +189,14 @@ def carry_for(platform: str, sharded: bool) -> str:
     On a TPU a flat row and a leaf are tiled differently, so a step on
     the plane pays a relayout and a cast each way — every row unpacked
     for the forward pass, the gradients packed for the update — which
-    outweighs the fused update and event it buys. On other platforms
-    reshaping a row is a bitcast. A mesh keeps the plane: the sharded
-    phase runs on it alone."""
-    return "leaf" if platform == "tpu" and not sharded else "plane"
+    outweighs the fused update and event it buys; a mesh under the
+    ``psum`` collective is no different, each shard stepping its own
+    rows. On other platforms reshaping a row is a bitcast. The
+    ``gather`` collective keeps the plane on any platform: it exists to
+    reproduce the single-device plane bit for bit."""
+    if sharded and collective == "gather":
+        return "plane"
+    return "leaf" if platform == "tpu" else "plane"
 
 
 def make_plane_step(loss_fn: Callable, spec: FlatSpec) -> Callable:
@@ -247,9 +257,9 @@ class PhaseEngine:
     accelerator meshes leave the default rolled scan.
 
     ``flat`` (default) lets a phase carry the (M, P) flat plane where
-    that is free (:func:`carry_for`): on a mesh, and off a TPU. A
-    single-device TPU phase, and every tree FlatSpec cannot embed,
-    carries the leaves
+    that is free (:func:`carry_for`): off a TPU, and under the
+    ``gather`` collective. A TPU phase (one device, or a mesh under
+    ``psum``), and every tree FlatSpec cannot embed, carries the leaves
     instead — params in their dtypes, optimizer state as its own tree,
     the tree optimizer per leaf, the event by ``_tree_average``.
     ``flat=False`` carries leaves everywhere. With
@@ -271,8 +281,10 @@ class PhaseEngine:
     averaging event becomes a cross-shard collective — ``collective=
     "psum"`` (production: O(P) bytes/device) or ``"gather"``
     (full-gather validation mode: bit-identical to the unsharded engine
-    for SGD/Momentum; see ``_phase_sharded``). Sharded runs require the
-    flat-native path.
+    for SGD/Momentum; see ``_phase_sharded``). A sharded phase carries
+    the flat-native plane or the leaves (:func:`carry_for`: leaves on a
+    TPU under psum, and wherever ``flat=False``); ``gather`` needs the
+    plane.
 
     ``topology`` (a :class:`repro.topology.Topology`) generalizes the
     "all"-scope averaging event from the full worker mean to one
@@ -800,12 +812,20 @@ class PhaseEngine:
         return wp, outer_c, disp
 
     # ---- the compiled phase ---------------------------------------------
-    def _phase(self, state: EngineState, xs, fetch, layout=None):
+    def _phase(self, state: EngineState, xs, fetch, layout=None,
+               m_global: int | None = None):
         """Trace the whole phase: scan the K entries of ``xs``
         (pre-staged batches, or index blocks that ``fetch`` gathers
         on-device), averaging fused per the schedule. Returns the new
         state and per-step traces {loss, dispersion, avg_code} — the only
         host transfer a phase needs.
+
+        ``m_global`` runs the body on ONE shard of a mesh holding
+        ``m_global`` workers (under shard_map, the leaf carry only): the
+        shard steps its own rows, and each worker mean is a psum over
+        the worker axes where one device takes a local mean — the Eq. 4
+        dispersion's column sums in one combined all-reduce, the
+        all-mean event reusing them (:meth:`_psum_tree_average`).
 
         Three carries, picked per :meth:`carry` and optimizer support:
           flat-native — the state in plane form (:meth:`to_planes`,
@@ -818,9 +838,11 @@ class PhaseEngine:
             pack/unpack around the tree-mapped optimizer (optimizers
             without plane support);
           tree        — the leaf carry: params pytree in its dtypes
-            (``carry`` "leaf": dtypes FlatSpec can't embed, one TPU,
+            (``carry`` "leaf": dtypes FlatSpec can't embed, a TPU,
             ``flat=False``)."""
-        num_workers = jax.tree.leaves(state.worker_params)[0].shape[0]
+        shard = m_global is not None
+        ml = jax.tree.leaves(state.worker_params)[0].shape[0]
+        num_workers = m_global if shard else ml
         self._check_workers(num_workers)
         self._check_compressible(state.worker_params)
         sched = self.schedule
@@ -834,12 +856,15 @@ class PhaseEngine:
                 "a flat-native state runs in plane form: pass " \
                 "start_state()'s state and layout"
             use_flat = self.carry(state) == "plane"
+            assert not (shard and use_flat), \
+                "a sharded phase carries the leaves or the flat-native " \
+                "plane (a plane-protocol optimizer with fused_opt=True)"
             # compressed events encode on the plane even in the tree
             # carry (pack/unpack around the event only — events are rare)
             spec = (FlatSpec.of(state.worker_params)
                     if use_flat or comp is not None else None)
         p_width = (spec.width if spec is not None else
-                   sum(x.size // num_workers
+                   sum(x.size // ml
                        for x in jax.tree.leaves(state.worker_params)))
         ec = self._sched_event_cost(p_width, num_workers)
         tm = tele_metrics if self.telemetry else None
@@ -866,14 +891,24 @@ class PhaseEngine:
         grads_fn = (make_plane_step(self.loss_fn, spec) if flat_native
                     else None)
         fp = self._faults()
+        ax = self._worker_axes() if shard else None
+        i0 = self._shard_index() * ml if shard else 0
+        # a shard's fault transitions and cohorts cover its own rows
+        rows = dict(row0=i0, num_rows=ml) if shard else {}
 
-        def comp_event(wp_c, resid, scope, step, W=None, alive=None):
+        def comp_event(wp_c, resid, scope, step, W=None, alive=None,
+                       alive_full=None):
             # encode -> event -> decode on the plane; tree carries pack
             # around the (rare) event only
             plane = wp_c if use_flat else spec.pack(wp_c)
-            plane, resid, _ = self._compressed_plane_event(
-                spec, plane, resid, scope, step, state.dec_key, W=W,
-                alive=alive)
+            if shard:
+                plane, resid = self._psum_compressed_event(
+                    spec, plane, resid, scope, step, state.dec_key, ml,
+                    num_workers, W=W, alive=alive, alive_full=alive_full)
+            else:
+                plane, resid, _ = self._compressed_plane_event(
+                    spec, plane, resid, scope, step, state.dec_key, W=W,
+                    alive=alive)
             return (plane if use_flat else spec.unpack(plane)), resid
 
         def warm_start(wp_c, opt_c, resid, alive_prev, rejoined):
@@ -881,7 +916,11 @@ class PhaseEngine:
             # optimizer state and error-feedback residual zeroed —
             # static under fp.has_rejoin, so crash-only plans trace
             # nothing extra
-            if use_flat:
+            if shard:
+                glob, _ = self._psum_mean(wp_c, alive_prev)
+                wp_c = faults_mod.select_rows_tree(
+                    replicate(_as_leaves(glob, wp_c), ml), wp_c, rejoined)
+            elif use_flat:
                 glob = faults_mod.masked_mean(wp_c, alive_prev)
                 codes = spec.rounding_codes()
                 if codes is not None:
@@ -903,18 +942,23 @@ class PhaseEngine:
 
         def body(carry, xs_t):
             wp_c, opt_c, outer_c, key, step, sst, resid, fst, acc = carry
-            alive = umask = dscale = None
+            alive = alive_full = umask = dscale = glob = None
             with jax.named_scope("engine.batch"):
                 step = step + 1
                 key, sub = jax.random.split(key)
                 rngs = jax.random.split(sub, num_workers)
+                if shard:
+                    # the global M's streams, this shard's rows of them
+                    rngs = jax.lax.dynamic_slice_in_dim(rngs, i0, ml, 0)
                 batch = fetch(xs_t)
                 if fp is not None:
                     alive_prev = fst.alive
-                    fst, _, alive, umask, rejoined = fp.transition(
-                        fst, step, state.dec_key)
+                    # one device: alive_full IS alive (the same array)
+                    fst, alive_full, alive, umask, rejoined = \
+                        fp.transition(fst, step, state.dec_key, **rows)
                     if sched.straggle_aware:
-                        dscale = fp.disp_scale(alive, state.dec_key, step)
+                        dscale = fp.disp_scale(alive_full, state.dec_key,
+                                               step)
             if fp is not None and fp.has_rejoin:
                 # the warm-start consensus is the PREVIOUS step's mixing
                 # cohort: mid-curriculum (solo) rows train but their
@@ -922,7 +966,7 @@ class PhaseEngine:
                 with jax.named_scope("engine.average"):
                     wp_c, opt_c, resid = warm_start(
                         wp_c, opt_c, resid,
-                        fp.mix_at(alive_prev, step - 1), rejoined)
+                        fp.mix_at(alive_prev, step - 1, **rows), rejoined)
             if flat_native:
                 losses, _, gplane = grads_fn(wp_c, batch, rngs)
                 with jax.named_scope("engine.update"):
@@ -963,7 +1007,11 @@ class PhaseEngine:
                     # update, pre average): the stateful decision
                     # consumes it and the trace records the true
                     # diagnostic on non-averaging steps too
-                    if fp is not None:
+                    if shard:
+                        disp, glob = self._psum_dispersion(wp_c,
+                                                           num_workers,
+                                                           alive)
+                    elif fp is not None:
                         disp = (faults_mod.masked_dispersion(wp_c, alive)
                                 if use_flat else
                                 faults_mod.masked_dispersion_tree(wp_c,
@@ -978,15 +1026,21 @@ class PhaseEngine:
                                                      state.dec_key,
                                                      event_cost=ec,
                                                      disp_scale=dscale)
+                # a shard's events reuse this step's psum'd worker mean
+                event = (average if not shard else
+                         partial(self._psum_tree_average, glob=glob,
+                                 m_global=num_workers,
+                                 alive_full=alive_full))
                 if sched.kind == "minibatch":
                     with jax.named_scope("engine.average"):
                         W = self._event_W(step, state.dec_key)
                         if comp is not None:
-                            wp_c, resid = comp_event(wp_c, resid, "all",
-                                                     step, W=W, alive=alive)
+                            wp_c, resid = comp_event(
+                                wp_c, resid, "all", step, W=W, alive=alive,
+                                alive_full=alive_full)
                         else:
-                            wp_c, outer_c, _ = average(wp_c, outer_c, "all",
-                                                       W=W, alive=alive)
+                            wp_c, outer_c = event(wp_c, outer_c, "all",
+                                                  W=W, alive=alive)[:2]
                 elif sched.kind != "oneshot":
                     def none_branch(args):
                         return args
@@ -995,20 +1049,22 @@ class PhaseEngine:
                         if comp is not None:
                             pl_, r_ = comp_event(args[0], args[2],
                                                  "inner", step,
-                                                 alive=alive)
+                                                 alive=alive,
+                                                 alive_full=alive_full)
                             return pl_, args[1], r_
-                        return average(args[0], args[1], "inner",
-                                       alive=alive)[:2] + (args[2],)
+                        return event(args[0], args[1], "inner",
+                                     alive=alive)[:2] + (args[2],)
 
                     def all_branch(args):
                         W = self._event_W(step, state.dec_key)
                         if comp is not None:
                             pl_, r_ = comp_event(args[0], args[2],
                                                  "all", step, W=W,
-                                                 alive=alive)
+                                                 alive=alive,
+                                                 alive_full=alive_full)
                             return pl_, args[1], r_
-                        return average(args[0], args[1], "all",
-                                       W=W, alive=alive)[:2] + (args[2],)
+                        return event(args[0], args[1], "all",
+                                     W=W, alive=alive)[:2] + (args[2],)
 
                     # only a hierarchical schedule emits inner events (code
                     # 1); the others switch on (none, all), which lets the
@@ -1022,8 +1078,14 @@ class PhaseEngine:
                     with jax.named_scope("engine.average"):
                         wp_c, outer_c, resid = jax.lax.switch(
                             idx, branches, (wp_c, outer_c, resid))
-            loss_t = (jnp.mean(losses) if fp is None
-                      else jnp.sum(losses * alive) / jnp.sum(alive))
+            if shard:
+                loss_t = (jax.lax.psum(jnp.sum(losses), ax) / num_workers
+                          if fp is None else
+                          jax.lax.psum(jnp.sum(losses * alive), ax)
+                          / jax.lax.psum(jnp.sum(alive), ax))
+            else:
+                loss_t = (jnp.mean(losses) if fp is None
+                          else jnp.sum(losses * alive) / jnp.sum(alive))
             if tm is not None:
                 n_alive, n_straggle = self._tele_occupancy(
                     fp, step, state.dec_key, num_workers)
@@ -1038,7 +1100,7 @@ class PhaseEngine:
         sst0 = (state.sched if isinstance(state.sched, SchedState)
                 else sched.init_sched_state())
         fst0 = (state.fault if isinstance(state.fault, FaultState)
-                else (faults_mod.init_fault_state(num_workers)
+                else (faults_mod.init_fault_state(ml)
                       if fp is not None else ()))
         # the metrics accumulator is reconstructed fresh every phase —
         # never part of EngineState, never checkpointed
@@ -1081,6 +1143,79 @@ class PhaseEngine:
         for a in self._worker_axes():
             idx = idx * self.mesh.shape[a] + jax.lax.axis_index(a)
         return idx
+
+    # ---- the leaf carry on one shard (``_phase`` with ``m_global``) -------
+    def _psum_mean(self, wp, mask=None, n=None):
+        """The worker mean of every leaf over all shards, in f32, from
+        one shard's rows: their column sums (each leaf at its own
+        precision, as :func:`worker_dispersion` measures it; rows
+        outside ``mask`` left out) psum'd over the worker axes — the
+        whole tree in ONE call, so XLA emits one combined all-reduce,
+        not one per leaf. Divides by ``n`` without a mask, else by the
+        psum'd count of ``mask``. Returns (mean tree, count)."""
+        def colsum(x):
+            xf = widen(x)
+            if mask is not None:
+                xf = xf * mask.reshape((-1,) + (1,) * (x.ndim - 1))
+            return jnp.sum(xf, axis=0)
+        sums = jax.tree.map(colsum, wp)
+        ax = self._worker_axes()
+        if mask is None:
+            sums = jax.lax.psum(sums, ax)
+        else:
+            sums, n = jax.lax.psum((sums, jnp.sum(mask)), ax)
+        return jax.tree.map(lambda s: s / n, sums), n
+
+    def _psum_dispersion(self, wp, m_global: int, alive=None):
+        """The Eq. 4 dispersion over every shard's workers (alive rows
+        only under a fault plan): the psum'd worker mean, then this
+        shard's squared distances from it summed locally and psum'd as
+        one scalar. Returns (dispersion, f32 mean tree), the mean for
+        this step's all-mean event to reuse."""
+        glob, n = self._psum_mean(wp, alive, m_global)
+
+        def sq(x, g):
+            d = jnp.square(widen(x) - g[None])
+            if alive is not None:
+                d = d * alive.reshape((-1,) + (1,) * (x.ndim - 1))
+            return jnp.sum(d)
+        local = sum(jax.tree.leaves(jax.tree.map(sq, wp, glob)))
+        return jax.lax.psum(local, self._worker_axes()) / n, glob
+
+    def _psum_tree_average(self, wp, outer_c, scope: str, W=None,
+                           alive=None, *, glob, m_global: int,
+                           alive_full=None):
+        """The averaging event on one shard's leaves — the leaf carry's
+        twin of :meth:`_psum_avg_event`, no optimizer update. The
+        all-scope mean is this step's ``glob`` (the dispersion's psum'd
+        mean) rounded to each leaf's dtype, so an event step adds no
+        second all-reduce; the outer optimizer steps on it, replicated.
+        Group means and a mixing topology's ``W @ x`` need every row:
+        each leaf is all_gathered, :meth:`_tree_average` runs on the
+        whole worker set, and this shard's rows are kept — O(M) rows
+        per leaf, on event steps only. ``alive`` (this shard's rows) /
+        ``alive_full`` (all M) mask the event under a fault plan.
+        Returns (wp, outer_c)."""
+        ml = jax.tree.leaves(wp)[0].shape[0]
+        if scope == "inner" or W is not None or self._all_groups() > 1:
+            ax, i0 = self._worker_axes(), self._shard_index() * ml
+
+            def leaf(x):
+                full = jax.lax.all_gather(x, ax, axis=0, tiled=True)
+                full = self._tree_average(full, (), scope, m_global, W=W,
+                                          alive=alive_full)[0]
+                return jax.lax.dynamic_slice_in_dim(full, i0, ml, 0)
+            return jax.tree.map(leaf, wp), outer_c
+        avg = _as_leaves(glob, wp)
+        if alive is not None:
+            # dead rows keep their last parameters
+            return faults_mod.select_rows_tree(replicate(avg, ml), wp,
+                                               alive), outer_c
+        if self.outer is not None:
+            prev_avg, vel = outer_c
+            avg, vel = self.outer.apply(prev_avg, avg, vel)
+            outer_c = (avg, vel)
+        return replicate(avg, ml), outer_c
 
     def _psum_avg_event(self, spec, plane, outer_c, scope: str, glob,
                         ml: int, W=None, alive=None, alive_full=None):
@@ -1289,8 +1424,11 @@ class PhaseEngine:
 
     def _phase_sharded(self, state: EngineState, xs, fetch, m_global: int,
                        layout):
-        """The phase body as run on ONE shard under shard_map, on the
-        plane-form state (``layout`` as in :meth:`_phase`).
+        """The phase body as run on ONE shard under shard_map. A
+        tree-form state (``layout`` None: the leaf carry) runs
+        :meth:`_phase`'s own body with ``m_global``, which reduces over
+        the worker axes by psum alone; a plane-form state (``layout`` as
+        in :meth:`_phase`) runs the plane's, below.
 
         ``collective="psum"`` (production): the local (M_l, P) slice of
         the plane scans through K fused local steps; averaging events
@@ -1310,12 +1448,16 @@ class PhaseEngine:
         differently inside the shard_map context) — those agree to f32
         roundoff. The price: redundant compute and O(M·P) gather bytes
         per step; use gather to validate a mesh, psum to scale."""
+        if layout is None:
+            if self.collective != "psum":
+                raise ValueError(
+                    f"the '{self.collective}' collective reproduces the "
+                    "single-device plane carry bit for bit, and this "
+                    "phase carries leaves (flat=False, or a tree FlatSpec "
+                    "cannot embed) — use collective='psum'")
+            return self._phase(state, xs, fetch, m_global=m_global)
         sched = self.schedule
         self._check_workers(m_global)
-        assert layout is not None, \
-            "sharded runs require the flat (M, P) plane carry and a " \
-            "plane-protocol optimizer (SGD/Momentum/AdamW) with " \
-            "fused_opt=True"
         assert self.collective in ("psum", "gather"), self.collective
         spec = layout[0]
         self._check_compressible(state.worker_params)
@@ -1568,7 +1710,8 @@ class PhaseEngine:
         wp = state.worker_params
         if not (self.flat and FlatSpec.supports(wp)):
             return "leaf"
-        return carry_for(jax.default_backend(), self.mesh is not None)
+        return carry_for(jax.default_backend(), self.mesh is not None,
+                         self.collective)
 
     def plane_layout(self, state: EngineState):
         """(FlatSpec, FlatOptSpec) of the flat-native carry for this
@@ -1640,7 +1783,9 @@ class PhaseEngine:
                 state = jax.device_put(state, shardings(state))
             build, args = (lambda s: s), (state,)
         layout = self.plane_layout(jax.eval_shape(build, *args))
-        if shardings is None:
+        # a given state the leaf carry runs as it stands (placed on the
+        # mesh above): a program rebuilding it would hold it twice
+        if shardings is None or (state is not None and layout is None):
             state = build(*args)
             if layout is not None:
                 state = self._to_planes(layout, state)

@@ -1,7 +1,8 @@
 """The phase's choice of carry: the (M, P) plane where unpacking a row
-into the leaves is free, the leaves themselves on a single TPU. The rule
-reads only what the code can observe — the default backend, as the
-kernels do, and whether a mesh shards the phase; never the model — and
+into the leaves is free, the leaves themselves on a TPU. The rule reads
+only what the code can observe — the default backend, as the kernels
+do, whether a mesh shards the phase and under which collective; never
+the model — and
 both carries train the same numbers: a bf16 decoder reaches the same
 momentum, losses and dispersion to f32 roundoff, its bf16 params at
 most one ulp apart, with and without a fault plan."""
@@ -21,16 +22,22 @@ from repro.telemetry.events import MemorySink
 M = 2
 
 
-@pytest.mark.parametrize("platform,sharded,want", [
-    ("tpu", False, "leaf"),
-    ("tpu", True, "plane"),
-    ("cpu", False, "plane"),
-    ("cpu", True, "plane"),
-    ("gpu", False, "plane"),
-    ("gpu", True, "plane"),
-], ids=["tpu", "tpu-mesh", "cpu", "cpu-mesh", "gpu", "gpu-mesh"])
-def test_carry_rule(platform, sharded, want):
-    assert carry_for(platform, sharded) == want
+@pytest.mark.parametrize("platform,sharded,collective,want", [
+    ("tpu", False, "psum", "leaf"),
+    ("tpu", True, "psum", "leaf"),
+    ("cpu", False, "psum", "plane"),
+    ("cpu", True, "psum", "plane"),
+    ("gpu", False, "psum", "plane"),
+    ("gpu", True, "psum", "plane"),
+    ("tpu", True, "gather", "plane"),
+    ("cpu", True, "gather", "plane"),
+], ids=["tpu", "tpu-mesh", "cpu", "cpu-mesh", "gpu", "gpu-mesh",
+        "tpu-mesh-gather", "cpu-mesh-gather"])
+def test_carry_rule(platform, sharded, collective, want):
+    """A TPU carries leaves, on one device or a mesh under psum; the
+    gather collective exists to reproduce the single-device plane bit
+    for bit, so it keeps the plane everywhere."""
+    assert carry_for(platform, sharded, collective) == want
 
 
 def _loss(params, batch, rng):
@@ -49,13 +56,22 @@ VECTORS = {"w": jnp.ones(6), "b": jnp.ones(2)}
      "leaf"),
     ("tpu", MATRIX, {}, "leaf"),
     ("tpu", VECTORS, {}, "leaf"),
+    ("tpu", MATRIX, {"mesh": True}, "leaf"),
+    ("tpu", MATRIX, {"mesh": True, "collective": "gather"}, "plane"),
+    ("cpu", MATRIX, {"mesh": True}, "plane"),
+    ("cpu", MATRIX, {"mesh": True, "flat": False}, "leaf"),
 ], ids=["cpu-matrix", "cpu-vectors", "flat-false", "no-f32-image",
-        "tpu-matrix", "tpu-vectors"])
+        "tpu-matrix", "tpu-vectors", "tpu-mesh", "tpu-mesh-gather",
+        "cpu-mesh", "cpu-mesh-flat-false"])
 def test_engine_carry_reads_the_state(monkeypatch, backend, params, kw,
                                       want):
     """Off a TPU the plane is free, unless ``flat=False`` or a leaf has
-    no float32 image; on one TPU every tree carries its leaves, a tree of
-    vectors as well as one of matrices (leaf ranks do not enter)."""
+    no float32 image; on a TPU every tree carries its leaves, a tree of
+    vectors as well as one of matrices (leaf ranks do not enter), on one
+    device and on a mesh under psum — the gather collective keeps the
+    plane."""
+    if kw.get("mesh"):
+        kw = dict(kw, mesh=jax.make_mesh((1,), ("data",)))
     engine = PhaseEngine(_loss, Momentum(lr=0.1), AveragingSchedule(
         "periodic", 4), **kw)
     state = engine.init(params, M)
@@ -129,3 +145,26 @@ def test_leaf_and_plane_carries_agree_on_a_bf16_decoder(workers, faults):
         differ += int(np.sum(ia != ib))
         total += ia.size
     assert differ <= total * 1e-3, (differ, total)
+
+
+def test_leaf_carry_runs_on_a_mesh_and_gather_keeps_the_plane():
+    """``flat=False`` on a mesh carries the leaves under psum (a
+    one-device mesh here; tests/test_sharded.py shards eight) and trains
+    what one device trains; the gather collective validates the plane
+    carry and refuses the leaves."""
+    mesh = jax.make_mesh((1,), ("data",))
+    _, h_one, s_one, _ = _decoder_run(flat=False)
+    _, h_mesh, s_mesh, rec = _decoder_run(flat=False, mesh=mesh)
+    assert [r["carry"] for r in rec] == ["leaf", "leaf"]
+    assert h_mesh["averages"] == h_one["averages"] == 2
+    np.testing.assert_allclose([v for _, v in h_mesh["loss"]],
+                               [v for _, v in h_one["loss"]], rtol=1e-6)
+    np.testing.assert_allclose([v for _, v in h_mesh["disp_trace"]],
+                               [v for _, v in h_one["disp_trace"]],
+                               rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(s_mesh.opt_state),
+                    jax.tree.leaves(s_one.opt_state)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-7)
+    with pytest.raises(ValueError, match="gather"):
+        _decoder_run(flat=False, mesh=mesh, collective="gather")
