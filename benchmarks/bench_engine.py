@@ -20,9 +20,9 @@ Runtimes, same periodic(K) schedule on identical sample draws:
   flat_fusedopt — PR 3 flat-NATIVE engine: optimizer state as (M, P)
                   planes in the scan carry, fused opt_step update —
                   zero per-step pack/unpack.
-  flat_sharded  — flat_fusedopt under shard_map over the available
-                  devices (psum averaging collective); needs >= 2
-                  devices (CI runs it under
+  flat_sharded  — the same engine under shard_map over the available
+                  devices, where it carries the leaves and psums its
+                  worker means; needs >= 2 devices (CI runs it under
                   XLA_FLAGS=--xla_force_host_platform_device_count=8).
 
 Two Momentum workloads: ``ls`` (single-leaf least squares — PR 1/2
@@ -34,23 +34,20 @@ much of the tree path's per-step pack/unpack, unrolled scans (the
 CPU-recommended setting for compute-heavy bodies) expose it — the
 flat-native carry is robust to both.
 
-Also times the WorkerSharder batched replacement draw and, with >= 2
-devices, records whether the gather-collective sharded run is
-bit-identical to single-device. An ``adaptive`` row compares the
-dispersion-driven schedules (adaptive_threshold with the trip level
-self-tuned to 0.7x the periodic run's mean event dispersion;
-adaptive_budget with half the periodic communication budget) against
-the periodic-8 baseline on
-identical draws: final consensus loss vs averaging-event count — the
-paper's question, answered by following the measured variance envelope
+Also times the WorkerSharder batched replacement draw. An ``adaptive``
+row compares the dispersion-driven schedules (adaptive_threshold with
+the trip level self-tuned to 0.7x the periodic run's mean event
+dispersion; adaptive_budget with half the periodic communication
+budget) against the periodic-8 baseline on identical draws: final
+consensus loss vs averaging-event count — the paper's question, answered by following the measured variance envelope
 instead of a fixed clock. A ``topology`` sweep (``repro.topology``)
 asks the same question along the mixing-matrix axis: each sparse
 topology (ring / torus / hypercube / gossip pairs) runs at the event
 period matching periodic-8 full averaging's per-worker communication
 budget, recording final loss + dispersion envelope vs spectral gap vs
 comm volume — and the ``full``-topology run is checked bit-identical
-to the plain mean path (``full_topology_bitexact``, gated like the
-sharded-gather check; the ``--tiny`` smoke keeps full+ring+gossip).
+to the plain mean path (``full_topology_bitexact``, gated in CI; the
+``--tiny`` smoke keeps full+ring+gossip).
 A ``compressed`` row (``repro.core.compress``) runs the wire-precision
 axis at matched BYTE budgets: int8 + error feedback at the event period
 whose realized bytes-on-the-wire fit within 25% of full-f32 periodic-8's
@@ -250,7 +247,7 @@ def bench_topology(arrays, idx, workers, steps, tiny: bool = False) -> dict:
     Also verifies the subsystem's bit-identity anchor: an engine with
     ``Topology.full`` must reproduce the plain mean path EXACTLY
     (params + full history) — recorded as ``full_topology_bitexact``
-    and gated in CI like the sharded-gather check."""
+    and gated in CI."""
     from repro.topology import Topology, comm_bytes
     Xn, yn = np.asarray(arrays["x"]), np.asarray(arrays["y"])
 
@@ -327,9 +324,8 @@ def bench_topology(arrays, idx, workers, steps, tiny: bool = False) -> dict:
     emit("engine_topology_sweep", 0.0 if bitexact else 1.0,
          f"full_topology_bitexact={bitexact};{headline}")
     if not bitexact:
-        # same CI contract as the sharded-gather check: a regression in
-        # the full-topology bit-identity must fail the PR, not just
-        # flip a field in the JSON artifact
+        # a regression in the full-topology bit-identity must fail the
+        # PR, not just flip a field in the JSON artifact
         raise SystemExit(
             "Topology.full engine run is NOT bit-identical to the mean "
             "path")
@@ -708,30 +704,6 @@ def bench_elastic(arrays, idx, workers, steps, labels) -> dict:
     return row
 
 
-def check_sharded_bitexact(loss_fn, params, arrays, idx, workers,
-                           mesh) -> bool:
-    """gather-collective sharded run == single-device run, bitwise —
-    final params AND the full history (losses, dispersions, decisions).
-    Holds for SGD/Momentum (mul-add update math lowers identically in
-    both compilation contexts on every backend tested); AdamW's
-    div/sqrt and deep matmul losses agree to f32 roundoff instead, so
-    the recorded guarantee is scoped to the paper's Momentum recipe on
-    the convex workload (tests/test_sharded.py covers all 5
-    schedules)."""
-    kw = dict(num_workers=workers, seed=3, record_every=1)
-    sch = AveragingSchedule("periodic", 8)
-    single = PhaseEngine(loss_fn, Momentum(lr=0.01, mu=0.9), sch)
-    f0, h0 = single.run(params, DeviceDataset(arrays, workers, indices=idx),
-                        **kw)
-    sharded = PhaseEngine(loss_fn, Momentum(lr=0.01, mu=0.9), sch,
-                          mesh=mesh, collective="gather")
-    f1, h1 = sharded.run(params, DeviceDataset(arrays, workers,
-                                               indices=idx), **kw)
-    same = all(bool((np.asarray(a) == np.asarray(b)).all())
-               for a, b in zip(jax.tree.leaves(f0), jax.tree.leaves(f1)))
-    return same and h0 == h1
-
-
 def run(tiny: bool = False, workers_override: int | None = None,
         save_json: bool | None = None):
     steps = 64 if tiny else STEPS
@@ -912,25 +884,6 @@ def run(tiny: bool = False, workers_override: int | None = None,
          f"block_us={sharder['sharder_block_us']:.0f};"
          f"speedup={sharder['sharder_speedup']:.1f}x")
 
-    sharded_bitexact = None
-    mesh = worker_mesh(max(worker_counts))
-    if mesh is not None:
-        m = max(worker_counts)
-        rng = np.random.default_rng(1)
-        cidx = rng.integers(0, samples, size=(33, m, 8))
-        sharded_bitexact = check_sharded_bitexact(
-            ls_mean_loss, {"w": jnp.zeros(dim)}, {"x": Xj, "y": yj},
-            cidx, m, mesh)
-        emit("engine_sharded_bitexact", 0.0 if sharded_bitexact else 1.0,
-             f"gather-collective == single-device: {sharded_bitexact}")
-        if not sharded_bitexact:
-            # the bench-smoke CI job gates on this: a regression in the
-            # gather-collective bit-identity must fail the PR, not just
-            # flip a field in the JSON artifact
-            raise SystemExit(
-                "sharded gather-collective run is NOT bit-identical to "
-                "single-device")
-
     fused = [r["speedup_fusedopt_vs_flat"] for r in results
              if r["workload"] == "deep"]
     heavy = [r["speedup_flat_vs_tree"] for r in results
@@ -967,7 +920,6 @@ def run(tiny: bool = False, workers_override: int | None = None,
                          "deep_layers": DEEP_LAYERS,
                          "deep_width": DEEP_WIDTH},
             "devices": len(jax.devices()),
-            "sharded_gather_bitexact": sharded_bitexact,
             "adaptive": adaptive_row,
             "topology": topology_sweep,
             "compressed": compressed_row,

@@ -30,10 +30,10 @@ One chip:
 
 Four chips (``--chips 4``), and nothing else:
   1. ``train.main`` at full width with 8 workers sharded 2 rows per chip
-     under the ``psum`` collective; the state is created sharded and
-     every chip holds exactly its 2 rows.
-  2. ``--reduced`` with 8 workers over the 4 chips under ``psum``
-     against the same run unsharded on one chip.
+     (the leaf carry, worker means psum'd); the state is created sharded
+     and every chip holds exactly its 2 rows.
+  2. ``--reduced`` with 8 workers over the 4 chips against the same run
+     unsharded on one chip.
 
 Weights come from ``init_params`` and tokens from ``token_stream``, both
 seeded. Any failure raises (non-zero exit). The last line of standard
@@ -271,8 +271,7 @@ def _reduced_plane_run(jax, workers: int, dtype: str, kernel_impl: str):
 def four_chips(jax):
     from repro.launch.mesh import make_worker_mesh
     workers = 8
-    _, state = _train_full_width(
-        jax, workers, ["--shard", "--collective", "psum"])
+    _, state = _train_full_width(jax, workers, ["--shard"])
     for leaf in jax.tree.leaves(state.worker_params):
         shards = leaf.addressable_shards
         assert len(shards) == 4, len(shards)

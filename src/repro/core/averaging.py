@@ -233,9 +233,8 @@ class AveragingSchedule:
         A run whose EMA lands within a last-ulp tie of the trip level
         at a decision step could therefore fire one step apart between
         paths on multi-leaf models; the single-buffer paths (flat vs
-        host on one leaf, gather-collective vs single-device) reduce
-        identically and replay identical decision streams — what the
-        equivalence tests pin.
+        host on one leaf) reduce identically and replay identical
+        decision streams — what the equivalence tests pin.
 
         ``disp_scale``: with ``straggle_aware=True`` the engine passes
         the fraction of the mixing cohort that applied its update this

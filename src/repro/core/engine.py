@@ -23,16 +23,16 @@ from a checkpointed ``EngineState``.
 
 Two device-residency layers sit on top of the PR 1 scan:
 
-- **Flat parameter plane** (where it is free, :func:`carry_for`): inside
-  a phase the scan carries the workers as one contiguous ``(M, P)``
-  float32 plane (:class:`repro.core.flat.FlatSpec`; bit-exact
+- **Flat parameter plane** (one device off the TPU, :func:`carry_for`):
+  inside a phase the scan carries the workers as one contiguous
+  ``(M, P)`` float32 plane (:class:`repro.core.flat.FlatSpec`; bit-exact
   pack/unpack), so every averaging event is a single fused pass — worker
   mean (global or per-group), Eq. 4 dispersion, broadcast, and the
   outer-optimizer momentum step — instead of 3–4 params-pytree
   traversals (``repro.kernels.avg_disp`` on TPU, its jnp twin on CPU).
-  A TPU phase carries the leaves instead (one device, or each shard of
-  a mesh under ``psum``): there the unpack and pack around every step
-  cost more than the plane saves.
+  A TPU phase and every shard of a mesh carry the leaves instead: on a
+  TPU the unpack and pack around every step cost more than the plane
+  saves, and a mesh runs one phase body for every shard.
   Trees with dtypes that have no exact float32 image take the leaf
   (tree) path too.
   :meth:`PhaseEngine.run` packs the state into that plane form once
@@ -62,8 +62,8 @@ Schedules lower to on-device control flow as follows:
 Because the fused passes always measure the dispersion, the per-step
 ``dispersion`` trace is the true Eq. 4 diagnostic on EVERY step (it used
 to read 0.0 between averaging events), in all four paths: flat-native,
-flat, tree, and the host loop — and in both sharded collectives (psum
-mode pays one extra psum of the per-shard squared sums per step).
+flat, tree, and the host loop — and on a mesh, where each shard psums
+its column sums and then its squared distances once per step.
 
 A :class:`repro.topology.Topology` generalizes the "all"-scope event
 from the full mean to one doubly-stochastic mixing-matrix application
@@ -115,8 +115,7 @@ from repro.telemetry.events import init_history, make_record
 from repro.kernels.ref import (avg_disp_outer_ref, avg_disp_ref,
                                compressed_avg_ref, compressed_mix_ref,
                                mix_disp_ref, opt_step_ref,
-                               plane_average_ref, plane_update_ref,
-                               round_to_codes, widen)
+                               plane_average_ref, round_to_codes, widen)
 from repro.topology import MIX_KINDS, Topology, comm_bytes, mix_tree
 
 # a host span on the profiler's clock; inert while no trace is recorded
@@ -181,7 +180,7 @@ def make_worker_step(loss_fn: Callable, optimizer) -> Callable:
     return step_fn
 
 
-def carry_for(platform: str, sharded: bool, collective: str) -> str:
+def carry_for(platform: str, sharded: bool) -> str:
     """The carry a phase takes: ``"plane"`` (the (M, P) f32 plane) where
     unpacking a plane row into the leaves is free, else ``"leaf"`` (the
     leaves in their own dtypes).
@@ -189,14 +188,11 @@ def carry_for(platform: str, sharded: bool, collective: str) -> str:
     On a TPU a flat row and a leaf are tiled differently, so a step on
     the plane pays a relayout and a cast each way — every row unpacked
     for the forward pass, the gradients packed for the update — which
-    outweighs the fused update and event it buys; a mesh under the
-    ``psum`` collective is no different, each shard stepping its own
-    rows. On other platforms reshaping a row is a bitcast. The
-    ``gather`` collective keeps the plane on any platform: it exists to
-    reproduce the single-device plane bit for bit."""
-    if sharded and collective == "gather":
-        return "plane"
-    return "leaf" if platform == "tpu" else "plane"
+    outweighs the fused update and event it buys. On other platforms
+    reshaping a row is a bitcast, so one device keeps the plane there.
+    A mesh carries leaves on every platform: each shard runs the one
+    phase body, stepping its own rows."""
+    return "leaf" if sharded or platform == "tpu" else "plane"
 
 
 def make_plane_step(loss_fn: Callable, spec: FlatSpec) -> Callable:
@@ -257,12 +253,11 @@ class PhaseEngine:
     accelerator meshes leave the default rolled scan.
 
     ``flat`` (default) lets a phase carry the (M, P) flat plane where
-    that is free (:func:`carry_for`): off a TPU, and under the
-    ``gather`` collective. A TPU phase (one device, or a mesh under
-    ``psum``), and every tree FlatSpec cannot embed, carries the leaves
-    instead — params in their dtypes, optimizer state as its own tree,
-    the tree optimizer per leaf, the event by ``_tree_average``.
-    ``flat=False`` carries leaves everywhere. With
+    that is free (:func:`carry_for`): on one device off a TPU. A TPU
+    phase, every mesh, and every tree FlatSpec cannot embed carry the
+    leaves instead — params in their dtypes, optimizer state as its own
+    tree, the tree optimizer per leaf, the event by ``_tree_average``.
+    ``flat=False`` carries leaves on one device too. With
     ``fused_opt`` (default) and an optimizer that speaks the plane
     protocol (SGD/Momentum/AdamW: ``plane_kind``/``plane_hypers``/
     ``plane_scalars`` + a ``FlatOptSpec``-alignable state), the scan is
@@ -276,15 +271,12 @@ class PhaseEngine:
     "pallas".
 
     ``mesh`` shards the phase over a device mesh via ``shard_map``: the
-    plane's worker axis M is split over the mesh's worker axes
-    (``shard_axes``; defaults to ("pod","data") ∩ mesh axes) and every
-    averaging event becomes a cross-shard collective — ``collective=
-    "psum"`` (production: O(P) bytes/device) or ``"gather"``
-    (full-gather validation mode: bit-identical to the unsharded engine
-    for SGD/Momentum; see ``_phase_sharded``). A sharded phase carries
-    the flat-native plane or the leaves (:func:`carry_for`: leaves on a
-    TPU under psum, and wherever ``flat=False``); ``gather`` needs the
-    plane.
+    worker axis M of every leaf is split over the mesh's worker axes
+    (``shard_axes``; defaults to ("pod","data") ∩ mesh axes), each
+    shard runs :meth:`_phase`'s one body on its own rows, carrying the
+    leaves, and every worker mean is a psum over the worker axes (O(P)
+    bytes a device). ``collective`` names that reduction and takes
+    only ``"psum"``.
 
     ``topology`` (a :class:`repro.topology.Topology`) generalizes the
     "all"-scope averaging event from the full worker mean to one
@@ -353,6 +345,14 @@ class PhaseEngine:
     compression: Compression | None = None
     faults: FaultPlan | None = None
     telemetry: bool = False
+
+    def __post_init__(self):
+        if self.collective != "psum":
+            raise ValueError(
+                f"collective={self.collective!r}: a mesh reduces its "
+                "worker means by 'psum', the only collective; 'gather', "
+                "which reproduced the one-device plane bit for bit, is "
+                "gone")
 
     @cached_property
     def worker_step(self):
@@ -572,17 +572,16 @@ class PhaseEngine:
             return None
         return FlatOptSpec.of(spec, opt_state)
 
-    def _event_uniforms(self, spec, m, step, dec_key, row0=None):
-        """The int8 stochastic-rounding uniforms for this event's rows
-        (global rows ``row0..row0+m``; ``row0=0`` unsharded), or None
-        for the deterministic formats."""
+    def _event_uniforms(self, spec, m, step, dec_key):
+        """The int8 stochastic-rounding uniforms for the ``m`` rows of
+        a one-device event, or None for the deterministic formats (a
+        shard keys its own by global row:
+        :meth:`_psum_compressed_event`)."""
         comp = self._comp()
         if comp is None or not comp.stochastic:
             return None
-        rows = jnp.arange(m, dtype=jnp.int32)
-        if row0 is not None:
-            rows = row0 + rows
-        return row_uniforms(dec_key, step, rows, spec.width)
+        return row_uniforms(dec_key, step, jnp.arange(m, dtype=jnp.int32),
+                            spec.width)
 
     def _compressed_plane_event(self, spec, plane, resid, scope: str,
                                 step, dec_key, W=None, alive=None):
@@ -821,25 +820,25 @@ class PhaseEngine:
         host transfer a phase needs.
 
         ``m_global`` runs the body on ONE shard of a mesh holding
-        ``m_global`` workers (under shard_map, the leaf carry only): the
-        shard steps its own rows, and each worker mean is a psum over
-        the worker axes where one device takes a local mean — the Eq. 4
-        dispersion's column sums in one combined all-reduce, the
+        ``m_global`` workers (under shard_map; a mesh carries leaves):
+        the shard steps its own rows, and each worker mean is a psum
+        over the worker axes where one device takes a local mean — the
+        Eq. 4 dispersion's column sums in one combined all-reduce, the
         all-mean event reusing them (:meth:`_psum_tree_average`).
 
         Three carries, picked per :meth:`carry` and optimizer support:
           flat-native — the state in plane form (:meth:`to_planes`,
-            ``layout`` from :meth:`plane_layout`): params AND optimizer
-            state as (M, P) planes, grads via one vjp through the
-            unpacked view, every step one fused opt_step pass; the phase
-            takes and returns planes, so no tree copy of the params
-            sits beside them;
+            ``layout`` from :meth:`plane_layout`; one device only):
+            params AND optimizer state as (M, P) planes, grads via one
+            vjp through the unpacked view, every step one fused
+            opt_step pass; the phase takes and returns planes, so no
+            tree copy of the params sits beside them;
           flat        — params plane packed on entry, with per-step
             pack/unpack around the tree-mapped optimizer (optimizers
-            without plane support);
+            without plane support; one device only);
           tree        — the leaf carry: params pytree in its dtypes
-            (``carry`` "leaf": dtypes FlatSpec can't embed, a TPU,
-            ``flat=False``)."""
+            (``carry`` "leaf": dtypes FlatSpec can't embed, a TPU, a
+            mesh, ``flat=False``)."""
         shard = m_global is not None
         ml = jax.tree.leaves(state.worker_params)[0].shape[0]
         num_workers = m_global if shard else ml
@@ -856,9 +855,6 @@ class PhaseEngine:
                 "a flat-native state runs in plane form: pass " \
                 "start_state()'s state and layout"
             use_flat = self.carry(state) == "plane"
-            assert not (shard and use_flat), \
-                "a sharded phase carries the leaves or the flat-native " \
-                "plane (a plane-protocol optimizer with fused_opt=True)"
             # compressed events encode on the plane even in the tree
             # carry (pack/unpack around the event only — events are rare)
             spec = (FlatSpec.of(state.worker_params)
@@ -1126,7 +1122,7 @@ class PhaseEngine:
             trace["metrics"] = acc
         return new_state, trace
 
-    # ---- sharded phase (shard_map over the mesh worker axes) -------------
+    # ---- one shard of a mesh (``_phase`` with ``m_global``, shard_map) ---
     def _worker_axes(self) -> tuple:
         from repro.sharding.specs import mesh_worker_axes
         return tuple(self.shard_axes) or mesh_worker_axes(self.mesh)
@@ -1144,7 +1140,6 @@ class PhaseEngine:
             idx = idx * self.mesh.shape[a] + jax.lax.axis_index(a)
         return idx
 
-    # ---- the leaf carry on one shard (``_phase`` with ``m_global``) -------
     def _psum_mean(self, wp, mask=None, n=None):
         """The worker mean of every leaf over all shards, in f32, from
         one shard's rows: their column sums (each leaf at its own
@@ -1185,11 +1180,11 @@ class PhaseEngine:
     def _psum_tree_average(self, wp, outer_c, scope: str, W=None,
                            alive=None, *, glob, m_global: int,
                            alive_full=None):
-        """The averaging event on one shard's leaves — the leaf carry's
-        twin of :meth:`_psum_avg_event`, no optimizer update. The
-        all-scope mean is this step's ``glob`` (the dispersion's psum'd
-        mean) rounded to each leaf's dtype, so an event step adds no
-        second all-reduce; the outer optimizer steps on it, replicated.
+        """The averaging event on one shard's leaves, no optimizer
+        update. The all-scope mean is this step's ``glob`` (the
+        dispersion's psum'd mean) rounded to each leaf's dtype, so an
+        event step adds no second all-reduce; the outer optimizer steps
+        on it, replicated.
         Group means and a mixing topology's ``W @ x`` need every row:
         each leaf is all_gathered, :meth:`_tree_average` runs on the
         whole worker set, and this shard's rows are kept — O(M) rows
@@ -1217,74 +1212,19 @@ class PhaseEngine:
             outer_c = (avg, vel)
         return replicate(avg, ml), outer_c
 
-    def _psum_avg_event(self, spec, plane, outer_c, scope: str, glob,
-                        ml: int, W=None, alive=None, alive_full=None):
-        """Cross-shard averaging event (no optimizer update) on this
-        shard's (M_l, P) rows. ``glob`` is the (already psum'd) global
-        worker mean — computed once per step OUTSIDE the switch, where
-        the always-on dispersion needs it anyway, so the all-scope
-        broadcast (and the outer step) is shard-local here. Group
-        (inner) averages all_gather the rows instead (group boundaries
-        need not align with shard boundaries), and so does a mixing
-        topology's ``W @ plane`` event: ONE all_gather of the (M_l, P)
-        row shards per event, then this shard's W rows contract the
-        full plane — O(M·P) bytes, only on event steps."""
-        codes = spec.rounding_codes()
-        ax = self._worker_axes()
-        if scope == "all" and W is not None:
-            if alive is not None:
-                W = faults_mod.degraded_matrix(W.astype(jnp.float32),
-                                               alive_full)
-            full = jax.lax.all_gather(plane, ax, axis=0, tiled=True)
-            rows = jax.lax.dynamic_slice_in_dim(
-                W, self._shard_index() * ml, ml, 0)
-            out = jnp.dot(rows, full, preferred_element_type=jnp.float32)
-            if codes is not None:
-                out = round_to_codes(out, codes)
-            if alive is not None:
-                out = faults_mod.select_rows(out, plane, alive)
-            return out, outer_c
-        if scope == "inner" or (scope == "all" and self._all_groups() > 1):
-            groups = (max(self.schedule.inner_groups, 1)
-                      if scope == "inner" else self._all_groups())
-            full = jax.lax.all_gather(plane, ax, axis=0, tiled=True)
-            full, _ = plane_average_ref(full, groups=groups, codes=codes,
-                                        alive=alive_full)
-            out = jax.lax.dynamic_slice_in_dim(
-                full, self._shard_index() * ml, ml, 0)
-            return out, outer_c
-        if codes is not None:
-            glob = round_to_codes(glob, codes)
-        if alive is not None:
-            # ``glob`` is the alive-masked mean (psum'd by the caller);
-            # dead rows keep their last parameters
-            return (faults_mod.select_rows(
-                jnp.broadcast_to(glob[None], plane.shape), plane, alive),
-                outer_c)
-        if self.outer is not None and outer_c != ():
-            prev, vel = outer_c
-            g = prev - glob
-            vel = self.outer.momentum * vel + g
-            step = (self.outer.momentum * vel + g if self.outer.nesterov
-                    else vel)
-            upd = prev - self.outer.lr * step
-            if codes is not None:
-                upd = round_to_codes(upd, codes)
-            return jnp.broadcast_to(upd[None], plane.shape), (upd, vel)
-        return jnp.broadcast_to(glob[None], plane.shape), outer_c
-
     def _psum_compressed_event(self, spec, plane, resid, scope: str, step,
                                dec_key, ml: int, m_global: int, W=None,
                                alive=None, alive_full=None):
-        """Compressed cross-shard averaging event on this shard's
-        (M_l, P) rows. Encoding is row-local (per-row scales, per-row
-        fold_in uniforms keyed by the GLOBAL row id ``i0 + arange``), so
-        each shard produces exactly the rows a single device would; the
-        error-feedback residual update ``v - q`` stays shard-local and
-        never crosses the wire. Mean events psum the per-shard sums of
-        the ENCODED rows — that psum is the bytes-on-the-wire win the
-        wire format buys. Mixing / group events all_gather q instead
-        (boundary-crossing contractions need the full encoded plane)."""
+        """Compressed cross-shard averaging event on this shard's rows,
+        packed as an (M_l, P) plane around the event. Encoding is
+        row-local (per-row scales, per-row fold_in uniforms keyed by the
+        GLOBAL row id ``i0 + arange``), so each shard produces exactly
+        the rows a single device would; the error-feedback residual
+        update ``v - q`` stays shard-local and never crosses the wire.
+        Mean events psum the per-shard sums of the ENCODED rows — that
+        psum is the bytes-on-the-wire win the wire format buys. Mixing /
+        group events all_gather q instead (boundary-crossing contractions
+        need the full encoded plane)."""
         comp = self._comp()
         codes = spec.rounding_codes()
         ax = self._worker_axes()
@@ -1331,305 +1271,18 @@ class PhaseEngine:
             out = faults_mod.select_rows(out, plane, alive)
         return out, resid
 
-    def _flat_native_step_psum(self, spec, plane, gplane, planes, outer_c,
-                               scalars, step, sst, dec_key,
-                               m_global: int, ml: int, resid=(),
-                               fmask=None, dscale=None):
-        """psum-mode flat-native step: shard-local plane update (hoisted
-        before the switch), then the always-on Eq. 4 dispersion — ONE
-        psum of the per-shard column sums gives the global mean, one
-        more psums the per-shard squared-distance sums — feeding the
-        stateful schedule decision, then the cross-shard averaging
-        event per the decision code. Returns (plane, state planes,
-        outer_c, resid, sched state, dispersion, code)."""
-        sched = self.schedule
-        comp = self._comp()
-        ax = self._worker_axes()
-        alive_full, alive, umask = (fmask if fmask is not None
-                                    else (None, None, None))
-        with jax.named_scope("engine.update"):
-            upd, new_planes = plane_update_ref(
-                plane, gplane, planes, scalars,
-                kind=self.optimizer.plane_kind,
-                codes=spec.rounding_codes(), **self.optimizer.plane_hypers())
-            if fmask is None:
-                plane, planes = upd, new_planes
-                glob = jax.lax.psum(jnp.sum(plane, axis=0), ax) / m_global
-                disp = jax.lax.psum(
-                    jnp.sum(jnp.square(plane - glob[None])), ax) / m_global
-            else:
-                # dead / straggling rows keep params AND state planes
-                plane = faults_mod.select_rows(upd, plane, umask)
-                planes = tuple(faults_mod.select_rows(n, o, umask)
-                               for n, o in zip(new_planes, planes))
-                n_alive = jax.lax.psum(jnp.sum(alive), ax)
-                glob = jax.lax.psum(
-                    jnp.sum(plane * alive[:, None], axis=0), ax) / n_alive
-                disp = jax.lax.psum(
-                    jnp.sum(jnp.square(plane - glob[None])
-                            * alive[:, None]), ax) / n_alive
-            ec = self._sched_event_cost(spec.width, m_global)
-            code, sst = sched.decision_state(step, sst, disp, dec_key,
-                                             event_cost=ec,
-                                             disp_scale=dscale)
-        if sched.kind == "oneshot":
-            return plane, planes, outer_c, resid, sst, disp, code
-        if sched.kind == "minibatch":
-            with jax.named_scope("engine.average"):
-                W = self._event_W(step, dec_key)
-                if comp is not None:
-                    plane, resid = self._psum_compressed_event(
-                        spec, plane, resid, "all", step, dec_key, ml,
-                        m_global, W=W, alive=alive, alive_full=alive_full)
-                else:
-                    plane, outer_c = self._psum_avg_event(
-                        spec, plane, outer_c, "all", glob, ml, W=W,
-                        alive=alive, alive_full=alive_full)
-            return plane, planes, outer_c, resid, sst, disp, code
-
-        def none_branch(args):
-            return args
-
-        def inner_branch(args):
-            if comp is not None:
-                pl_, r_ = self._psum_compressed_event(
-                    spec, args[0], args[2], "inner", step, dec_key, ml,
-                    m_global, alive=alive, alive_full=alive_full)
-                return pl_, args[1], r_
-            return self._psum_avg_event(
-                spec, args[0], args[1], "inner", glob, ml,
-                alive=alive, alive_full=alive_full) + (args[2],)
-
-        def all_branch(args):
-            W = self._event_W(step, dec_key)
-            if comp is not None:
-                pl_, r_ = self._psum_compressed_event(
-                    spec, args[0], args[2], "all", step, dec_key, ml,
-                    m_global, W=W, alive=alive, alive_full=alive_full)
-                return pl_, args[1], r_
-            return self._psum_avg_event(
-                spec, args[0], args[1], "all", glob, ml, W=W,
-                alive=alive, alive_full=alive_full) + (args[2],)
-
-        # only hierarchical schedules emit the inner code; elsewhere its
-        # branch (an all_gather of the whole plane) is never taken, and
-        # tracing it would still reserve M·P bytes on every device
-        if sched.kind != "hierarchical":
-            inner_branch = none_branch
-        with jax.named_scope("engine.average"):
-            plane, outer_c, resid = jax.lax.switch(
-                code, [none_branch, inner_branch, all_branch],
-                (plane, outer_c, resid))
-        return plane, planes, outer_c, resid, sst, disp, code
-
-    def _phase_sharded(self, state: EngineState, xs, fetch, m_global: int,
-                       layout):
-        """The phase body as run on ONE shard under shard_map. A
-        tree-form state (``layout`` None: the leaf carry) runs
-        :meth:`_phase`'s own body with ``m_global``, which reduces over
-        the worker axes by psum alone; a plane-form state (``layout`` as
-        in :meth:`_phase`) runs the plane's, below.
-
-        ``collective="psum"`` (production): the local (M_l, P) slice of
-        the plane scans through K fused local steps; averaging events
-        are the only cross-shard communication (one psum of column
-        sums). Local shapes differ from the unsharded engine's, so XLA
-        may vectorize per-worker reductions differently — results agree
-        to f32 roundoff, not bitwise.
-
-        ``collective="gather"`` (validation): every step all_gathers the
-        plane rows, state planes and batch, runs the unsharded fused
-        step on the full worker set, and keeps this shard's row slice —
-        full-shape compute on identical values, so the run reproduces
-        the single-device engine bit-for-bit for the paper's SGD /
-        Momentum recipes (mul-add update math; validated across all 5
-        schedules in tests/test_sharded.py). AdamW's div/sqrt and deep
-        matmul losses may still differ in final ulps (XLA fuses them
-        differently inside the shard_map context) — those agree to f32
-        roundoff. The price: redundant compute and O(M·P) gather bytes
-        per step; use gather to validate a mesh, psum to scale."""
-        if layout is None:
-            if self.collective != "psum":
-                raise ValueError(
-                    f"the '{self.collective}' collective reproduces the "
-                    "single-device plane carry bit for bit, and this "
-                    "phase carries leaves (flat=False, or a tree FlatSpec "
-                    "cannot embed) — use collective='psum'")
-            return self._phase(state, xs, fetch, m_global=m_global)
-        sched = self.schedule
-        self._check_workers(m_global)
-        assert self.collective in ("psum", "gather"), self.collective
-        spec = layout[0]
-        self._check_compressible(state.worker_params)
-        comp = self._comp()
-        ml = state.worker_params.shape[0]
-        carry_p, carry_s = state.worker_params, state.opt_state
-        carry_o = state.outer_state
-        grads_fn = make_plane_step(self.loss_fn, spec)
-        ax = self._worker_axes()
-        i0 = self._shard_index() * ml
-        exact = self.collective == "gather"
-        fp = self._faults()
-        tm = tele_metrics if self.telemetry else None
-        eb_all, eb_inner = (self._event_bytes(spec.width, m_global)
-                            if tm is not None else (0.0, 0.0))
-
-        def body(carry, xs_t):
-            wp_c, opt_c, outer_c, key, step, sst, resid, fst, acc = carry
-            with jax.named_scope("engine.batch"):
-                step = step + 1
-                key, sub = jax.random.split(key)
-                rngs = jax.random.split(sub, m_global)
-                batch = fetch(xs_t)
-            with jax.named_scope("engine.update"):
-                scal = self.optimizer.plane_scalars(step)
-            # the gather collective's state gathers and row slices are
-            # none of the step's parts: they stay unscoped
-            if exact:
-                wp_full = jax.lax.all_gather(wp_c, ax, axis=0, tiled=True)
-                opt_full = tuple(
-                    jax.lax.all_gather(s, ax, axis=0, tiled=True)
-                    for s in opt_c)
-                batch = jax.tree.map(
-                    lambda b: jax.lax.all_gather(b, ax, axis=0, tiled=True),
-                    batch)
-                resid_full = (jax.lax.all_gather(resid, ax, axis=0,
-                                                 tiled=True)
-                              if comp is not None else resid)
-                fmask = None
-                dscale = None
-                if fp is not None:
-                    # fault rows gather like resid: the transition and
-                    # warm start run on the FULL worker set, so the step
-                    # reproduces the single-device fault stream bitwise
-                    fst_full = FaultState(
-                        jax.lax.all_gather(fst.alive, ax, axis=0,
-                                           tiled=True),
-                        jax.lax.all_gather(fst.staleness, ax, axis=0,
-                                           tiled=True))
-                    alive_prev = fst_full.alive
-                    with jax.named_scope("engine.batch"):
-                        fst_full, _, alive_f, umask_f, rejoined_f = \
-                            fp.transition(fst_full, step, state.dec_key)
-                    if fp.has_rejoin:
-                        with jax.named_scope("engine.average"):
-                            glob_p = faults_mod.masked_mean(
-                                wp_full, fp.mix_at(alive_prev, step - 1))
-                            codes = spec.rounding_codes()
-                            if codes is not None:
-                                glob_p = round_to_codes(glob_p, codes)
-                            wp_full = faults_mod.select_rows(
-                                jnp.broadcast_to(glob_p[None],
-                                                 wp_full.shape),
-                                wp_full, rejoined_f)
-                            opt_full = tuple(
-                                faults_mod.zero_rows(s, rejoined_f)
-                                for s in opt_full)
-                            if comp is not None:
-                                resid_full = faults_mod.zero_rows(
-                                    resid_full, rejoined_f)
-                    fst = FaultState(
-                        jax.lax.dynamic_slice_in_dim(
-                            fst_full.alive, i0, ml, 0),
-                        jax.lax.dynamic_slice_in_dim(
-                            fst_full.staleness, i0, ml, 0))
-                    fmask = (alive_f, umask_f)
-                    if sched.straggle_aware:
-                        dscale = fp.disp_scale(alive_f, state.dec_key,
-                                               step)
-                losses, _, gplane = grads_fn(wp_full, batch, rngs)
-                wp_full, opt_full, outer_c, resid_full, sst, disp, code = \
-                    self._flat_native_step(spec, wp_full, gplane, opt_full,
-                                           outer_c, scal, step, sst,
-                                           state.dec_key, resid=resid_full,
-                                           fmask=fmask, dscale=dscale)
-                loss_t = (jnp.mean(losses) if fp is None else
-                          jnp.sum(losses * alive_f) / jnp.sum(alive_f))
-                wp_c = jax.lax.dynamic_slice_in_dim(wp_full, i0, ml, 0)
-                opt_c = tuple(
-                    jax.lax.dynamic_slice_in_dim(s, i0, ml, 0)
-                    for s in opt_full)
-                if comp is not None:
-                    resid = jax.lax.dynamic_slice_in_dim(
-                        resid_full, i0, ml, 0)
-            else:
-                fmask = None
-                dscale = None
-                if fp is not None:
-                    alive_prev = fst.alive
-                    with jax.named_scope("engine.batch"):
-                        fst, alive_fl, alive_l, umask_l, rejoined_l = \
-                            fp.transition(fst, step, state.dec_key,
-                                          row0=i0, num_rows=ml)
-                    if fp.has_rejoin:
-                        with jax.named_scope("engine.average"):
-                            aprev = fp.mix_at(alive_prev, step - 1,
-                                              row0=i0, num_rows=ml)
-                            glob_p = (jax.lax.psum(jnp.sum(
-                                wp_c * aprev[:, None], axis=0), ax)
-                                / jax.lax.psum(jnp.sum(aprev), ax))
-                            codes = spec.rounding_codes()
-                            if codes is not None:
-                                glob_p = round_to_codes(glob_p, codes)
-                            wp_c = faults_mod.select_rows(
-                                jnp.broadcast_to(glob_p[None], wp_c.shape),
-                                wp_c, rejoined_l)
-                            opt_c = tuple(
-                                faults_mod.zero_rows(s, rejoined_l)
-                                for s in opt_c)
-                            if comp is not None:
-                                resid = faults_mod.zero_rows(resid,
-                                                             rejoined_l)
-                    fmask = (alive_fl, alive_l, umask_l)
-                    if sched.straggle_aware:
-                        dscale = fp.disp_scale(alive_fl, state.dec_key,
-                                               step)
-                with jax.named_scope("engine.batch"):
-                    rngs = jax.lax.dynamic_slice_in_dim(rngs, i0, ml, 0)
-                losses, _, gplane = grads_fn(wp_c, batch, rngs)
-                wp_c, opt_c, outer_c, resid, sst, disp, code = \
-                    self._flat_native_step_psum(spec, wp_c, gplane, opt_c,
-                                                outer_c, scal, step, sst,
-                                                state.dec_key, m_global,
-                                                ml, resid=resid,
-                                                fmask=fmask, dscale=dscale)
-                loss_t = (jax.lax.psum(jnp.sum(losses), ax) / m_global
-                          if fp is None else
-                          jax.lax.psum(jnp.sum(losses * alive_l), ax)
-                          / jax.lax.psum(jnp.sum(alive_l), ax))
-            if tm is not None:
-                # loss_t / disp / code are already GLOBAL in both
-                # collectives, and the fault occupancy comes from pure
-                # full-plane streams — each shard accumulates the
-                # identical vector, no extra collective
-                n_alive, n_straggle = self._tele_occupancy(
-                    fp, step, state.dec_key, m_global)
-                acc = tm.accumulate(
-                    acc, loss=loss_t, disp=disp, code=code,
-                    event_bytes_all=eb_all, event_bytes_inner=eb_inner,
-                    n_alive=n_alive, n_straggle=n_straggle)
-            return ((wp_c, opt_c, outer_c, key, step, sst, resid, fst,
-                     acc),
-                    (loss_t, disp.astype(jnp.float32), code))
-
-        sst0 = (state.sched if isinstance(state.sched, SchedState)
-                else sched.init_sched_state())
-        fst0 = (state.fault if isinstance(state.fault, FaultState)
-                else (faults_mod.init_fault_state(ml)
-                      if fp is not None else ()))
-        acc0 = tm.init_metrics() if tm is not None else ()
-        carry0 = (carry_p, carry_s, carry_o, state.key, state.step, sst0,
-                  state.resid, fst0, acc0)
-        (wp_c, opt_c, outer_c, key, step, sst, resid, fst, acc), \
-            (loss, disp, code) = \
-            jax.lax.scan(body, carry0, xs, unroll=self.scan_unroll)
-
-        new_state = EngineState(wp_c, opt_c, outer_c, key,
-                                state.dec_key, step, sst, resid, fst)
-        trace = {"loss": loss, "dispersion": disp, "avg_code": code}
-        if tm is not None:
-            trace["metrics"] = acc
-        return new_state, trace
+    def _mesh_workers(self, state: EngineState, layout) -> int:
+        """The worker count M of a mesh phase's tree-form ``state``. A
+        plane-form state (``layout`` given) is refused: a mesh carries
+        the leaves, and the plane form exists on one device only."""
+        if layout is not None:
+            raise ValueError(
+                "a mesh phase carries the leaves: pass the tree-form "
+                "state with layout=None (the (M, P) plane form exists on "
+                "one device only)")
+        m = jax.tree.leaves(state.worker_params)[0].shape[0]
+        assert m % self._num_shards() == 0, (m, self._num_shards())
+        return m
 
     def _state_specs(self, state: EngineState):
         ax = P(self._worker_axes())
@@ -1654,17 +1307,15 @@ class PhaseEngine:
              donate_argnums=1)
     def run_phase(self, state: EngineState, batches, layout=None):
         """One compiled dispatch over a pre-staged (K, M, ...) batch
-        block. ``layout`` (:meth:`plane_layout`) runs a plane-form
-        state (:meth:`to_planes`) and returns one."""
+        block. ``layout`` (:meth:`plane_layout`, one device only) runs a
+        plane-form state (:meth:`to_planes`) and returns one."""
         if self.mesh is None:
             return self._phase(state, batches, lambda b: b, layout)
-        m = jax.tree.leaves(state.worker_params)[0].shape[0]
-        assert m % self._num_shards() == 0, (m, self._num_shards())
+        m = self._mesh_workers(state, layout)
         sspec = self._state_specs(state)
         ax = self._worker_axes()
         return jax.shard_map(
-            lambda s, xs: self._phase_sharded(s, xs, lambda b: b, m,
-                                              layout),
+            lambda s, xs: self._phase(s, xs, lambda b: b, m_global=m),
             mesh=self.mesh,
             in_specs=(sspec, jax.tree.map(lambda _: P(None, ax), batches)),
             out_specs=(sspec, self._trace_specs()),
@@ -1688,13 +1339,12 @@ class PhaseEngine:
         if self.mesh is None:
             return self._phase(state, idx_block, fetch_from(dataset),
                                layout)
-        m = jax.tree.leaves(state.worker_params)[0].shape[0]
-        assert m % self._num_shards() == 0, (m, self._num_shards())
+        m = self._mesh_workers(state, layout)
         sspec = self._state_specs(state)
         ax = self._worker_axes()
         return jax.shard_map(
-            lambda s, ds, idx: self._phase_sharded(
-                s, idx, fetch_from(ds), m, layout),
+            lambda s, ds, idx: self._phase(s, idx, fetch_from(ds),
+                                           m_global=m),
             mesh=self.mesh,
             in_specs=(sspec, jax.tree.map(lambda _: P(), dataset),
                       jax.tree.map(lambda _: P(None, ax), idx_block)),
@@ -1705,13 +1355,13 @@ class PhaseEngine:
     def carry(self, state: EngineState) -> str:
         """``"plane"`` or ``"leaf"``: what a phase of the tree-form
         ``state`` carries (:func:`carry_for`, on the default backend,
-        as the kernels choose theirs). ``flat=False`` and trees FlatSpec
-        cannot embed carry leaves everywhere."""
+        as the kernels choose theirs): leaves on a TPU and on every
+        mesh. ``flat=False`` and trees FlatSpec cannot embed carry
+        leaves on one device too."""
         wp = state.worker_params
         if not (self.flat and FlatSpec.supports(wp)):
             return "leaf"
-        return carry_for(jax.default_backend(), self.mesh is not None,
-                         self.collective)
+        return carry_for(jax.default_backend(), self.mesh is not None)
 
     def plane_layout(self, state: EngineState):
         """(FlatSpec, FlatOptSpec) of the flat-native carry for this
@@ -1764,36 +1414,32 @@ class PhaseEngine:
     def start_state(self, params, num_workers: int, seed: int = 0,
                     state: EngineState | None = None):
         """The state :meth:`run` carries: :meth:`init` (or the given
-        ``state``) in plane form where the flat-native path applies.
-        On a mesh it is built by one program whose output is already
-        split over the worker axes, so each device computes only its
-        own worker rows and none ever holds the whole state. Returns
-        (state, layout)."""
-        shardings = None
-        if self.mesh is not None:
-            from repro.sharding.specs import engine_state_sharding
-            shardings = lambda tree: engine_state_sharding(
-                self.mesh, tree, axes=self._worker_axes())
+        ``state``), in plane form where the flat-native path applies (one
+        device only). On a mesh a fresh state is built by one program
+        whose output is already split over the worker axes, so each
+        device computes only its own worker rows and none ever holds
+        the whole state. Returns (state, layout)."""
         if state is None:
             build, args = (lambda p: self.init(p, num_workers, seed)), \
                 (params,)
         else:
-            if shardings is not None:
-                # a resumed (or resized) state may sit on another mesh
-                state = jax.device_put(state, shardings(state))
             build, args = (lambda s: s), (state,)
-        layout = self.plane_layout(jax.eval_shape(build, *args))
-        # a given state the leaf carry runs as it stands (placed on the
-        # mesh above): a program rebuilding it would hold it twice
-        if shardings is None or (state is not None and layout is None):
+        if self.mesh is None:
+            layout = self.plane_layout(jax.eval_shape(build, *args))
             state = build(*args)
             if layout is not None:
                 state = self._to_planes(layout, state)
             return state, layout
-        fn = build if layout is None else \
-            (lambda *a: self.to_planes(layout, build(*a)))
-        return jax.jit(fn, out_shardings=shardings(
-            jax.eval_shape(fn, *args)))(*args), layout
+        from repro.sharding.specs import engine_state_sharding
+        shardings = lambda tree: engine_state_sharding(
+            self.mesh, tree, axes=self._worker_axes())
+        if state is not None:
+            # a resumed (or resized) state may sit on another mesh; it is
+            # placed, and run as it stands: a program rebuilding it would
+            # hold it twice
+            return jax.device_put(state, shardings(state)), None
+        return jax.jit(build, out_shardings=shardings(
+            jax.eval_shape(build, *args)))(*args), None
 
     def default_phase_len(self) -> int:
         """Compile-size heuristic: align phase blocks with the schedule's

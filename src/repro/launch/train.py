@@ -120,15 +120,11 @@ def main(argv=None):
                          "(PR 2 behavior: per-step pack/unpack around the "
                          "tree-mapped optimizer)")
     ap.add_argument("--shard", action="store_true",
-                    help="shard the flat (M, P) plane's worker axis over "
-                         "the available devices via shard_map (on CPU set "
+                    help="shard the workers (each leaf's worker axis) "
+                         "over the available devices via shard_map, "
+                         "worker means psum'd across them (on CPU set "
                          "XLA_FLAGS=--xla_force_host_platform_device_"
                          "count=N first)")
-    ap.add_argument("--collective", default="psum",
-                    choices=["psum", "gather"],
-                    help="sharded averaging collective: psum (production; "
-                         "one psum of column sums per event) or gather "
-                         "(validation; bit-identical to single-device)")
     ap.add_argument("--faults", default=None,
                     help="deterministic fault script (repro.faults): "
                          "comma-separated kind:m=<row>@t=<step> events, "
@@ -386,8 +382,7 @@ def main(argv=None):
         mesh = make_worker_mesh(args.workers)
         shards = mesh.shape["data"]
         print(f"[train] sharding {args.workers} workers over {shards} "
-              f"devices ({args.workers // shards} rows/shard, "
-              f"collective={args.collective})")
+              f"devices ({args.workers // shards} rows/shard)")
     sink = None
     if args.telemetry:
         sink = JsonlSink(args.telemetry)
@@ -406,7 +401,7 @@ def main(argv=None):
                          scan_unroll=args.scan_unroll or True,
                          flat=not args.tree_engine,
                          fused_opt=not args.no_fused_opt,
-                         mesh=mesh, collective=args.collective,
+                         mesh=mesh,
                          topology=topology, compression=compression,
                          faults=faults, telemetry=sink is not None)
     if faults is not None and not faults.is_trivial:

@@ -134,7 +134,7 @@ def _flat(axes):
 
 
 # --------------------------------------------------------------------------
-# Flat (M, P) plane sharding (the phase engine's worker-axis layout)
+# Worker-axis sharding (the phase engine's layout on a mesh)
 # --------------------------------------------------------------------------
 
 def mesh_worker_axes(mesh) -> tuple:
@@ -145,9 +145,9 @@ def mesh_worker_axes(mesh) -> tuple:
 
 
 def plane_sharding(mesh, *, axes=None):
-    """NamedSharding for the flat (M, P) plane — and for any engine leaf
-    with a leading worker axis: M splits over the worker mesh axes, all
-    trailing dims (the P columns) stay replicated within a worker
+    """NamedSharding for any engine leaf with a leading worker axis (a
+    mesh carries the worker state as leaves): M splits over the worker
+    mesh axes, all trailing dims stay replicated within a worker
     shard."""
     axes = tuple(axes) if axes else mesh_worker_axes(mesh)
     return jax.sharding.NamedSharding(mesh, P(axes))

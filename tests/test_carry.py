@@ -1,11 +1,13 @@
-"""The phase's choice of carry: the (M, P) plane where unpacking a row
-into the leaves is free, the leaves themselves on a TPU. The rule reads
-only what the code can observe — the default backend, as the kernels
-do, whether a mesh shards the phase and under which collective; never
-the model — and
-both carries train the same numbers: a bf16 decoder reaches the same
-momentum, losses and dispersion to f32 roundoff, its bf16 params at
-most one ulp apart, with and without a fault plan."""
+"""The phase's choice of carry: the (M, P) plane on one device where
+unpacking a row into the leaves is free, the leaves themselves on a TPU
+and on every mesh. The rule reads only what the code can observe — the
+default backend, as the kernels do, and whether a mesh shards the
+phase; never the model — and both carries train the same numbers: a
+bf16 decoder reaches the same momentum, losses and dispersion to f32
+roundoff, its bf16 params at most one ulp apart, with and without a
+fault plan."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,22 +24,19 @@ from repro.telemetry.events import MemorySink
 M = 2
 
 
-@pytest.mark.parametrize("platform,sharded,collective,want", [
-    ("tpu", False, "psum", "leaf"),
-    ("tpu", True, "psum", "leaf"),
-    ("cpu", False, "psum", "plane"),
-    ("cpu", True, "psum", "plane"),
-    ("gpu", False, "psum", "plane"),
-    ("gpu", True, "psum", "plane"),
-    ("tpu", True, "gather", "plane"),
-    ("cpu", True, "gather", "plane"),
-], ids=["tpu", "tpu-mesh", "cpu", "cpu-mesh", "gpu", "gpu-mesh",
-        "tpu-mesh-gather", "cpu-mesh-gather"])
-def test_carry_rule(platform, sharded, collective, want):
-    """A TPU carries leaves, on one device or a mesh under psum; the
-    gather collective exists to reproduce the single-device plane bit
-    for bit, so it keeps the plane everywhere."""
-    assert carry_for(platform, sharded, collective) == want
+@pytest.mark.parametrize("platform,sharded,want", [
+    ("tpu", False, "leaf"),
+    ("tpu", True, "leaf"),
+    ("cpu", False, "plane"),
+    ("cpu", True, "leaf"),
+    ("gpu", False, "plane"),
+    ("gpu", True, "leaf"),
+], ids=["tpu", "tpu-mesh", "cpu", "cpu-mesh", "gpu", "gpu-mesh"])
+def test_carry_rule(platform, sharded, want):
+    """A TPU carries leaves on one device, and every mesh carries them
+    on every platform (one phase body serves each shard); one device
+    off a TPU keeps the plane."""
+    assert carry_for(platform, sharded) == want
 
 
 def _loss(params, batch, rng):
@@ -57,19 +56,17 @@ VECTORS = {"w": jnp.ones(6), "b": jnp.ones(2)}
     ("tpu", MATRIX, {}, "leaf"),
     ("tpu", VECTORS, {}, "leaf"),
     ("tpu", MATRIX, {"mesh": True}, "leaf"),
-    ("tpu", MATRIX, {"mesh": True, "collective": "gather"}, "plane"),
-    ("cpu", MATRIX, {"mesh": True}, "plane"),
+    ("cpu", MATRIX, {"mesh": True}, "leaf"),
     ("cpu", MATRIX, {"mesh": True, "flat": False}, "leaf"),
 ], ids=["cpu-matrix", "cpu-vectors", "flat-false", "no-f32-image",
-        "tpu-matrix", "tpu-vectors", "tpu-mesh", "tpu-mesh-gather",
-        "cpu-mesh", "cpu-mesh-flat-false"])
+        "tpu-matrix", "tpu-vectors", "tpu-mesh", "cpu-mesh",
+        "cpu-mesh-flat-false"])
 def test_engine_carry_reads_the_state(monkeypatch, backend, params, kw,
                                       want):
     """Off a TPU the plane is free, unless ``flat=False`` or a leaf has
     no float32 image; on a TPU every tree carries its leaves, a tree of
-    vectors as well as one of matrices (leaf ranks do not enter), on one
-    device and on a mesh under psum — the gather collective keeps the
-    plane."""
+    vectors as well as one of matrices (leaf ranks do not enter), and so
+    does every mesh, on any platform."""
     if kw.get("mesh"):
         kw = dict(kw, mesh=jax.make_mesh((1,), ("data",)))
     engine = PhaseEngine(_loss, Momentum(lr=0.1), AveragingSchedule(
@@ -147,14 +144,13 @@ def test_leaf_and_plane_carries_agree_on_a_bf16_decoder(workers, faults):
     assert differ <= total * 1e-3, (differ, total)
 
 
-def test_leaf_carry_runs_on_a_mesh_and_gather_keeps_the_plane():
-    """``flat=False`` on a mesh carries the leaves under psum (a
-    one-device mesh here; tests/test_sharded.py shards eight) and trains
-    what one device trains; the gather collective validates the plane
-    carry and refuses the leaves."""
+def test_leaf_carry_on_a_mesh_trains_what_one_device_trains():
+    """A mesh carries the leaves under psum (a one-device mesh here;
+    tests/test_sharded.py shards eight), as its records say, and trains
+    what the one-device leaf carry trains."""
     mesh = jax.make_mesh((1,), ("data",))
     _, h_one, s_one, _ = _decoder_run(flat=False)
-    _, h_mesh, s_mesh, rec = _decoder_run(flat=False, mesh=mesh)
+    _, h_mesh, s_mesh, rec = _decoder_run(mesh=mesh)
     assert [r["carry"] for r in rec] == ["leaf", "leaf"]
     assert h_mesh["averages"] == h_one["averages"] == 2
     np.testing.assert_allclose([v for _, v in h_mesh["loss"]],
@@ -166,5 +162,28 @@ def test_leaf_carry_runs_on_a_mesh_and_gather_keeps_the_plane():
                     jax.tree.leaves(s_one.opt_state)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-7)
+
+
+def test_gather_collective_and_mesh_layout_are_refused():
+    """``psum`` is the only collective: ``gather`` is refused when the
+    engine is built. A mesh phase refuses a plane-form state: the plane
+    form exists on one device only."""
+    mesh = jax.make_mesh((1,), ("data",))
     with pytest.raises(ValueError, match="gather"):
-        _decoder_run(flat=False, mesh=mesh, collective="gather")
+        PhaseEngine(_loss, Momentum(lr=0.1), AveragingSchedule(
+            "periodic", 4), mesh=mesh, collective="gather")
+    one = PhaseEngine(_loss, Momentum(lr=0.1),
+                      AveragingSchedule("periodic", 4))
+    state = one.init(MATRIX, M)
+    layout = one.plane_layout(state)
+    assert layout is not None
+    planes = one.to_planes(layout, state)
+    engine = dataclasses.replace(one, mesh=mesh)
+    assert engine.plane_layout(state) is None
+    batches = {"x": jnp.zeros((4, M, 1))}
+    with pytest.raises(ValueError, match="one device only"):
+        engine.run_phase(planes, batches, layout=layout)
+    with pytest.raises(ValueError, match="one device only"):
+        engine.run_phase_indexed(planes, {"x": jnp.zeros((8, 1))},
+                                 jnp.zeros((4, M, 1), jnp.int32),
+                                 layout=layout)

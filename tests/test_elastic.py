@@ -22,8 +22,8 @@ Covers the repro.elastic subsystem end to end:
   - the calibrated post-resize dispersion prediction
     (variance_model.predict_post_resize_dispersion) against a
     simulated K-step window;
-  - sharded resize under shard_map with both psum and gather
-    collectives (subprocess with 8 host devices, like test_faults).
+  - sharded resize under shard_map, the mesh's leaf carry with psum'd
+    worker means (subprocess with 8 host devices, like test_faults).
 """
 import os
 import subprocess
@@ -719,8 +719,9 @@ plan = FaultPlan.parse("crash:m=1@t=4,rejoin:m=1@t=10", WORKERS,
 kw = dict(steps=STEPS, seed=3, record_every=1)
 noop = ElasticPlan(WORKERS, ((8, WORKERS),))
 
-# SGD keeps the shard_map programs bitwise (see test_faults); the
-# elastic layer only adds phase cuts and host-side row repacks
+# a no-op plan runs the plain run's shard_map program, cut at segment
+# boundaries (the elastic layer only adds phase cuts and host-side row
+# repacks): bitwise
 from repro.launch.mesh import make_worker_mesh
 SCHEDS = {
     "oneshot": AveragingSchedule("oneshot"),
@@ -735,46 +736,36 @@ SCHEDS = {
                                          budget_horizon=STEPS),
 }
 for sname, sched in SCHEDS.items():
-    for coll in ("psum", "gather"):
-        mesh = make_worker_mesh(WORKERS)
-        eng = PhaseEngine(loss_fn, SGD(0.05), sched, faults=plan,
-                          mesh=mesh, collective=coll)
-        f0, h0 = eng.run(params, factory(WORKERS, 1, STEPS),
-                         num_workers=WORKERS, seed=3, record_every=1)
-        f1, h1 = run_elastic(eng, params, factory, noop, **kw)
-        np.testing.assert_array_equal(np.asarray(f0["w"]),
-                                      np.asarray(f1["w"]))
-        assert h0["loss"] == h1["loss"], (sname, coll)
-        assert h0["averages"] == h1["averages"]
-        print("noop-ok", sname, coll)
+    mesh = make_worker_mesh(WORKERS)
+    eng = PhaseEngine(loss_fn, SGD(0.05), sched, faults=plan, mesh=mesh)
+    f0, h0 = eng.run(params, factory(WORKERS, 1, STEPS),
+                     num_workers=WORKERS, seed=3, record_every=1)
+    f1, h1 = run_elastic(eng, params, factory, noop, **kw)
+    np.testing.assert_array_equal(np.asarray(f0["w"]),
+                                  np.asarray(f1["w"]))
+    assert h0["loss"] == h1["loss"], sname
+    assert h0["averages"] == h1["averages"]
+    print("noop-ok", sname)
 
-# a real resize under both collectives: gather matches the unsharded
-# elastic run bitwise; psum agrees to f32 roundoff
+# a real resize on the mesh agrees with the unsharded elastic run to f32
+# roundoff, with the same decision stream
 resize = ElasticPlan(WORKERS, ((6, 3), (12, 4)), curriculum=2)
 eng0 = PhaseEngine(loss_fn, SGD(0.05), AveragingSchedule("periodic", 4),
                    faults=plan)
 fu, hu = run_elastic(eng0, params, factory, resize, **kw)
-for coll in ("gather", "psum"):
-    eng = PhaseEngine(loss_fn, SGD(0.05), AveragingSchedule("periodic", 4),
-                      faults=plan, mesh=make_worker_mesh(WORKERS),
-                      collective=coll)
-    fs, hs = run_elastic(eng, params, factory, resize, **kw)
-    assert hs["resizes"] == [(6, 4, 3), (12, 3, 4)]
-    if coll == "gather":
-        np.testing.assert_array_equal(np.asarray(fu["w"]),
-                                      np.asarray(fs["w"]))
-        assert hu["loss"] == hs["loss"]
-    else:
-        np.testing.assert_allclose(np.asarray(fu["w"]),
-                                   np.asarray(fs["w"]),
-                                   rtol=1e-5, atol=1e-6)
-        assert hu["averages"] == hs["averages"]
-    print("resize-ok", coll)
+eng = PhaseEngine(loss_fn, SGD(0.05), AveragingSchedule("periodic", 4),
+                  faults=plan, mesh=make_worker_mesh(WORKERS))
+fs, hs = run_elastic(eng, params, factory, resize, **kw)
+assert hs["resizes"] == [(6, 4, 3), (12, 3, 4)]
+np.testing.assert_allclose(np.asarray(fu["w"]), np.asarray(fs["w"]),
+                           rtol=1e-5, atol=1e-6)
+assert hu["averages"] == hs["averages"]
+print("resize-ok")
 print("ALL-OK")
 """
 
 
-def test_sharded_resize_both_collectives():
+def test_sharded_resize():
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8")

@@ -110,20 +110,17 @@ sys.path.insert(0, {tests!r})
 from test_spans import (K, WORKERS, _batches, _engine, _params, scopes_of)
 from repro.core import PhaseEngine
 assert len(jax.devices()) == 4
-out = {{}}
 mesh = jax.make_mesh((4,), ("data",))
-for coll in ("psum", "gather"):
-    eng = _engine(mesh=mesh, collective=coll)
-    state, layout = eng.start_state(_params(), WORKERS, 0)
-    stk = jax.tree.map(lambda *x: jnp.stack(x), *_batches()[:K])
-    txt = PhaseEngine.run_phase.lower(eng, state, stk,
-                                      layout=layout).compile().as_text()
-    red = [m.group(1) for m in re.finditer(
-        r'all-reduce[^\n]*op_name="([^"]*)"', txt)]
-    out[coll] = dict(scopes=sorted(scopes_of(txt)),
-                     psum_scopes=sorted({{s for n in red for s in
-                                          re.findall(r"engine\.[a-z_]+", n)}}))
-print(json.dumps(out))
+eng = _engine(mesh=mesh)
+state, _ = eng.start_state(_params(), WORKERS, 0)
+stk = jax.tree.map(lambda *x: jnp.stack(x), *_batches()[:K])
+txt = PhaseEngine.run_phase.lower(eng, state, stk).compile().as_text()
+red = [m.group(1) for m in re.finditer(
+    r'all-reduce[^\n]*op_name="([^"]*)"', txt)]
+print(json.dumps(dict(scopes=sorted(scopes_of(txt)),
+                      psum_scopes=sorted({{s for n in red for s in
+                                           re.findall(r"engine\.[a-z_]+",
+                                                      n)}}))))
 """
 
 
@@ -138,11 +135,12 @@ def test_sharded_phase_carries_every_scope():
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
-    for coll in ("psum", "gather"):
-        assert set(res[coll]["scopes"]) == SCOPES, coll
-    # psum mode: the per-step dispersion psum is the update's, the
-    # event's mean comes from it; the loss psum stays unscoped
-    assert "engine.update" in res["psum"]["psum_scopes"]
+    # a mesh carries the leaves: every scope but the plane's unpack and
+    # pack
+    assert set(res["scopes"]) == SCOPES - {"engine.unpack", "engine.pack"}
+    # the per-step dispersion psum is the update's, the event's mean
+    # comes from it; the loss psum stays unscoped
+    assert "engine.update" in res["psum_scopes"]
 
 
 def _stream():

@@ -4,8 +4,8 @@ The load-bearing invariant (docs/TELEMETRY.md): ``telemetry=True``
 threads a metrics accumulator through the phase scan carry and flushes
 it with the phase's existing trace fetch — so telemetry ON vs OFF is
 bit-identical in the final EngineState across every engine path, every
-schedule, compression, faults, checkpoint/resume, and the sharded
-collectives (subprocess), and adds ZERO extra host syncs (the
+schedule, compression, faults, checkpoint/resume, and the mesh phase
+(subprocess), and adds ZERO extra host syncs (the
 device_get count per run is unchanged). On top of that: the metrics
 themselves must agree with the independently recorded history, the
 JSONL schema round-trips (with future-version refusal), ``RunLog``
@@ -366,23 +366,20 @@ batches = lambda: [{"x": Xj[idx[t]], "y": yj[idx[t]]} for t in range(STEPS)]
 mesh = jax.make_mesh((8,), ("data",))
 sch = AveragingSchedule("periodic", 8)
 kw = dict(num_workers=WORKERS, seed=3, record_every=1, phase_len=16)
-for coll in ("psum", "gather"):
-    off = PhaseEngine(loss_fn, Momentum(lr=0.05, mu=0.9), sch,
-                      mesh=mesh, collective=coll)
-    on = PhaseEngine(loss_fn, Momentum(lr=0.05, mu=0.9), sch,
-                     mesh=mesh, collective=coll, telemetry=True)
-    f0, h0, s0 = off.run(params, batches(), return_state=True, **kw)
-    sink = MemorySink()
-    f1, h1, s1 = on.run(params, batches(), return_state=True,
-                        sink=sink, **kw)
-    for a, b in zip(jax.tree.leaves(s0), jax.tree.leaves(s1)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert h0 == h1
-    pm = [r for r in sink.records if r["type"] == "phase_metrics"]
-    assert sum(r["steps"] for r in pm) == STEPS
-    assert sum(r["events"] for r in pm) == h1["averages"]
-    assert all(r["alive_mean"] == WORKERS for r in pm)
-    print("ok", coll)
+off = PhaseEngine(loss_fn, Momentum(lr=0.05, mu=0.9), sch, mesh=mesh)
+on = PhaseEngine(loss_fn, Momentum(lr=0.05, mu=0.9), sch, mesh=mesh,
+                 telemetry=True)
+f0, h0, s0 = off.run(params, batches(), return_state=True, **kw)
+sink = MemorySink()
+f1, h1, s1 = on.run(params, batches(), return_state=True, sink=sink, **kw)
+for a, b in zip(jax.tree.leaves(s0), jax.tree.leaves(s1)):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+assert h0 == h1
+pm = [r for r in sink.records if r["type"] == "phase_metrics"]
+assert sum(r["steps"] for r in pm) == STEPS
+assert sum(r["events"] for r in pm) == h1["averages"]
+assert all(r["alive_mean"] == WORKERS for r in pm)
+assert all(r["carry"] == "leaf" for r in pm)
 print("ALL-OK")
 """
 
