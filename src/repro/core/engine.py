@@ -62,8 +62,9 @@ Schedules lower to on-device control flow as follows:
 Because the fused passes always measure the dispersion, the per-step
 ``dispersion`` trace is the true Eq. 4 diagnostic on EVERY step (it used
 to read 0.0 between averaging events), in all four paths: flat-native,
-flat, tree, and the host loop — and on a mesh, where each shard psums
-its column sums and then its squared distances once per step.
+flat, tree, and the host loop — and on a mesh, where an all-to-all of
+each leaf's rows gives every shard 1/S of the columns to reduce, and
+one scalar psum sums their squared deviations, once per step.
 
 A :class:`repro.topology.Topology` generalizes the "all"-scope event
 from the full mean to one doubly-stochastic mixing-matrix application
@@ -77,7 +78,9 @@ counter, rng splits, batch fetch, fault transition), ``engine.unpack``,
 ``engine.fwd_bwd``, ``engine.pack``, ``engine.update`` (optimizer
 update, dispersion, schedule decision) and ``engine.average`` (the
 event) — so every op of the compiled phase carries at most one of them
-in its ``op_name``. :meth:`PhaseEngine.run` marks its host loop with
+in its ``op_name`` (on a mesh the dispersion's exchange and reduction
+run under ``engine.dispersion``, nested in ``engine.update``).
+:meth:`PhaseEngine.run` marks its host loop with
 ``jax.profiler.TraceAnnotation`` spans ``engine.next_block``,
 ``engine.dispatch``, ``engine.fetch`` and ``engine.record`` (and
 ``engine.stage`` on the staging thread), each with ``step=`` the
@@ -90,6 +93,7 @@ for `benchmarks/bench_engine.py` and the equivalence tests.
 """
 from __future__ import annotations
 
+import math
 import time
 from dataclasses import dataclass
 from functools import cached_property, partial
@@ -136,6 +140,17 @@ def replicate(tree, num_workers: int):
 def _as_leaves(mean, tree):
     """An f32 worker-mean tree rounded to ``tree``'s leaf dtypes."""
     return jax.tree.map(lambda g, x: g.astype(x.dtype), mean, tree)
+
+
+def _column_view(x, shards: int):
+    """A worker-rows-first leaf as the shards split its columns: along
+    its first axis after the rows where ``shards`` divides it (the leaf
+    keeps its layout), else flattened and padded with zero columns,
+    which deviate by nothing."""
+    if x.ndim > 1 and x.shape[1] % shards == 0:
+        return x
+    x = x.reshape(x.shape[0], -1)
+    return jnp.pad(x, ((0, 0), (0, -x.shape[1] % shards)))
 
 
 def unreplicate(tree):
@@ -274,9 +289,10 @@ class PhaseEngine:
     worker axis M of every leaf is split over the mesh's worker axes
     (``shard_axes``; defaults to ("pod","data") ∩ mesh axes), each
     shard runs :meth:`_phase`'s one body on its own rows, carrying the
-    leaves, and every worker mean is a psum over the worker axes (O(P)
-    bytes a device). ``collective`` names that reduction and takes
-    only ``"psum"``.
+    leaves; the per-step dispersion is reduced by columns after
+    all-to-alls of the rows (:meth:`_sharded_dispersion`), and the
+    all-mean event gathers that mean. ``collective`` names the mesh's
+    worker reduction and takes only ``"psum"``.
 
     ``topology`` (a :class:`repro.topology.Topology`) generalizes the
     "all"-scope averaging event from the full worker mean to one
@@ -821,10 +837,12 @@ class PhaseEngine:
 
         ``m_global`` runs the body on ONE shard of a mesh holding
         ``m_global`` workers (under shard_map; a mesh carries leaves):
-        the shard steps its own rows, and each worker mean is a psum
-        over the worker axes where one device takes a local mean — the
-        Eq. 4 dispersion's column sums in one combined all-reduce, the
-        all-mean event reusing them (:meth:`_psum_tree_average`).
+        the shard steps its own rows, and the Eq. 4 dispersion is
+        reduced by columns: an all-to-all of each leaf's rows hands
+        every shard all M workers' values of 1/S of the columns, and
+        one scalar psum sums the squared deviations
+        (:meth:`_sharded_dispersion`); the all-mean event all-gathers
+        the shards' columns of that mean (:meth:`_psum_tree_average`).
 
         Three carries, picked per :meth:`carry` and optimizer support:
           flat-native — the state in plane form (:meth:`to_planes`,
@@ -913,7 +931,7 @@ class PhaseEngine:
             # static under fp.has_rejoin, so crash-only plans trace
             # nothing extra
             if shard:
-                glob, _ = self._psum_mean(wp_c, alive_prev)
+                glob = self._psum_mean(wp_c, alive_prev)
                 wp_c = faults_mod.select_rows_tree(
                     replicate(_as_leaves(glob, wp_c), ml), wp_c, rejoined)
             elif use_flat:
@@ -938,7 +956,7 @@ class PhaseEngine:
 
         def body(carry, xs_t):
             wp_c, opt_c, outer_c, key, step, sst, resid, fst, acc = carry
-            alive = alive_full = umask = dscale = glob = None
+            alive = alive_full = umask = dscale = own = None
             with jax.named_scope("engine.batch"):
                 step = step + 1
                 key, sub = jax.random.split(key)
@@ -1004,9 +1022,8 @@ class PhaseEngine:
                     # consumes it and the trace records the true
                     # diagnostic on non-averaging steps too
                     if shard:
-                        disp, glob = self._psum_dispersion(wp_c,
-                                                           num_workers,
-                                                           alive)
+                        disp, own = self._sharded_dispersion(
+                            wp_c, num_workers, alive_full)
                     elif fp is not None:
                         disp = (faults_mod.masked_dispersion(wp_c, alive)
                                 if use_flat else
@@ -1022,9 +1039,9 @@ class PhaseEngine:
                                                      state.dec_key,
                                                      event_cost=ec,
                                                      disp_scale=dscale)
-                # a shard's events reuse this step's psum'd worker mean
+                # a shard's events reuse its columns of this step's mean
                 event = (average if not shard else
-                         partial(self._psum_tree_average, glob=glob,
+                         partial(self._psum_tree_average, own=own,
                                  m_global=num_workers,
                                  alive_full=alive_full))
                 if sched.kind == "minibatch":
@@ -1140,51 +1157,77 @@ class PhaseEngine:
             idx = idx * self.mesh.shape[a] + jax.lax.axis_index(a)
         return idx
 
-    def _psum_mean(self, wp, mask=None, n=None):
-        """The worker mean of every leaf over all shards, in f32, from
-        one shard's rows: their column sums (each leaf at its own
-        precision, as :func:`worker_dispersion` measures it; rows
-        outside ``mask`` left out) psum'd over the worker axes — the
-        whole tree in ONE call, so XLA emits one combined all-reduce,
-        not one per leaf. Divides by ``n`` without a mask, else by the
-        psum'd count of ``mask``. Returns (mean tree, count)."""
+    def _psum_mean(self, wp, mask):
+        """The mean of every leaf over the rows of ``mask`` on all
+        shards, in f32 (a rejoin's warm start): this shard's masked
+        column sums, each leaf at its own precision, and the count
+        psum'd over the worker axes in ONE call, so XLA emits one
+        combined all-reduce, not one per leaf."""
         def colsum(x):
-            xf = widen(x)
-            if mask is not None:
-                xf = xf * mask.reshape((-1,) + (1,) * (x.ndim - 1))
+            xf = widen(x) * mask.reshape((-1,) + (1,) * (x.ndim - 1))
             return jnp.sum(xf, axis=0)
-        sums = jax.tree.map(colsum, wp)
+        sums, n = jax.lax.psum((jax.tree.map(colsum, wp), jnp.sum(mask)),
+                               self._worker_axes())
+        return jax.tree.map(lambda s: s / n, sums)
+
+    def _sharded_dispersion(self, wp, m_global: int, alive_full=None):
+        """The Eq. 4 dispersion over every shard's workers (the rows of
+        ``alive_full`` only under a fault plan), reduced by columns:
+        all-to-alls of each leaf's rows, in the leaf's own dtype, hand
+        this shard all M workers' values of its 1/S of the leaf's
+        columns (:func:`_column_view`). On them it forms the f32 mean
+        and the two-pass squared deviations as :func:`worker_dispersion`
+        does; one scalar psum sums them over the shards. Returns
+        (dispersion, this shard's columns of the mean rounded to each
+        leaf's dtype), which the all-mean event gathers
+        (:meth:`_psum_tree_average`)."""
+        ax, s = self._worker_axes(), self._num_shards()
+        n = m_global if alive_full is None else jnp.sum(alive_full)
+
+        def owner(x):
+            x = _column_view(x, s)
+            # one all-to-all per local row i, whose columns bound for
+            # each shard are contiguous (exchanging all rows at once
+            # makes the compiler copy the leaf into a shard-major
+            # layout first): it returns the (S, ...) values of workers
+            # k * ml + i, k the shard in _shard_index order
+            xf = [widen(jax.lax.all_to_all(
+                r.reshape((s, -1) + r.shape[1:]), ax, 0, 0, tiled=True))
+                for r in x]
+            w = ([1] * len(xf) if alive_full is None else list(
+                alive_full.reshape((s, -1) + (1,) * (x.ndim - 1))
+                .swapaxes(0, 1)))
+            mean = sum(jnp.sum(v * a, axis=0) for v, a in zip(xf, w)) / n
+            d = sum(jnp.sum(jnp.square(v - mean[None]) * a)
+                    for v, a in zip(xf, w))
+            return d, mean.astype(x.dtype)
+
+        leaves, tdef = jax.tree.flatten(wp)
+        with jax.named_scope("engine.dispersion"):
+            sq, own = zip(*map(owner, leaves))
+            return jax.lax.psum(sum(sq), ax) / n, tdef.unflatten(own)
+
+    def _gather_mean(self, own, wp):
+        """The whole mean tree, in each leaf's dtype, from every
+        shard's columns of it (:meth:`_sharded_dispersion`)."""
         ax = self._worker_axes()
-        if mask is None:
-            sums = jax.lax.psum(sums, ax)
-        else:
-            sums, n = jax.lax.psum((sums, jnp.sum(mask)), ax)
-        return jax.tree.map(lambda s: s / n, sums), n
 
-    def _psum_dispersion(self, wp, m_global: int, alive=None):
-        """The Eq. 4 dispersion over every shard's workers (alive rows
-        only under a fault plan): the psum'd worker mean, then this
-        shard's squared distances from it summed locally and psum'd as
-        one scalar. Returns (dispersion, f32 mean tree), the mean for
-        this step's all-mean event to reuse."""
-        glob, n = self._psum_mean(wp, alive, m_global)
-
-        def sq(x, g):
-            d = jnp.square(widen(x) - g[None])
-            if alive is not None:
-                d = d * alive.reshape((-1,) + (1,) * (x.ndim - 1))
-            return jnp.sum(d)
-        local = sum(jax.tree.leaves(jax.tree.map(sq, wp, glob)))
-        return jax.lax.psum(local, self._worker_axes()) / n, glob
+        def leaf(g, x):
+            g = jax.lax.all_gather(g, ax, axis=0, tiled=True)
+            # a flattened leaf drops its padding
+            return g if g.shape == x.shape[1:] else \
+                g[:math.prod(x.shape[1:])].reshape(x.shape[1:])
+        return jax.tree.map(leaf, own, wp)
 
     def _psum_tree_average(self, wp, outer_c, scope: str, W=None,
-                           alive=None, *, glob, m_global: int,
+                           alive=None, *, own, m_global: int,
                            alive_full=None):
         """The averaging event on one shard's leaves, no optimizer
-        update. The all-scope mean is this step's ``glob`` (the
-        dispersion's psum'd mean) rounded to each leaf's dtype, so an
-        event step adds no second all-reduce; the outer optimizer steps
-        on it, replicated.
+        update. The all-scope mean is gathered from this step's ``own``
+        (each shard's columns of the dispersion's mean, already in the
+        leaf's dtype), so an event step adds one all-gather in the
+        leaves' dtypes and no all-reduce; the outer optimizer steps on
+        it, replicated.
         Group means and a mixing topology's ``W @ x`` need every row:
         each leaf is all_gathered, :meth:`_tree_average` runs on the
         whole worker set, and this shard's rows are kept — O(M) rows
@@ -1201,7 +1244,7 @@ class PhaseEngine:
                                           alive=alive_full)[0]
                 return jax.lax.dynamic_slice_in_dim(full, i0, ml, 0)
             return jax.tree.map(leaf, wp), outer_c
-        avg = _as_leaves(glob, wp)
+        avg = self._gather_mean(own, wp)
         if alive is not None:
             # dead rows keep their last parameters
             return faults_mod.select_rows_tree(replicate(avg, ml), wp,

@@ -2,7 +2,8 @@
 worker axes.
 
 A mesh carries the leaves on every platform, each shard stepping its
-own worker rows with the worker means psum'd. One subprocess with
+own worker rows, the per-step dispersion reduced by columns after an
+all-to-all of the rows. One subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (jax fixes its
 device count at import, so the parent process can't flip it) runs it
 against the single-device leaf engine, one parametrised case each:
@@ -12,8 +13,10 @@ shard boundaries (ring, random gossip pairs), a fault plan, a
 compressed wire (alone and with a ring mix, whose encoded rows are
 all-gathered), the indexed data plane, the telemetry accumulator, a
 fresh state built split over the mesh, and a checkpoint save and resume
-of the sharded state. Decision streams match exactly, every other
-number to f32 roundoff.
+of the sharded state; the column-reduced dispersion and event mean
+alone against one device's on the gathered rows (padded leaves, bf16
+and f32, 1 or 2 rows a shard, a fault mask, two worker axes). Decision
+streams match exactly, every other number to f32 roundoff.
 
 In-process tests cover the sharding spec helpers.
 """
@@ -247,6 +250,64 @@ def resume_in_place():
 
 CASES["resume-in-place"] = resume_in_place
 
+
+def column_dispersion(ml, faults=False, mesh=MESH):
+    # the per-step dispersion reduced by columns over the shards, and the
+    # event mean gathered from them, against one device's on the
+    # gathered rows: bf16 and f32 leaves, split along their first axis
+    # (16 x 3, 24) or flattened, with zero columns padded where no shard
+    # count divides the element count (5 x 7, one per worker) and none
+    # where it does (3 x 8)
+    from jax.sharding import PartitionSpec as P
+    from repro.core.averaging import worker_dispersion
+    from repro.core.engine import _as_leaves
+    from repro.faults import masked_dispersion_tree
+    from repro.kernels.ref import widen
+    axes = mesh.axis_names
+    m = ml * mesh.devices.size
+    r = np.random.default_rng(ml)
+    shapes = {"a": ((5, 7), jnp.bfloat16), "b": ((16, 3), jnp.float32),
+              "c": ((24,), jnp.bfloat16), "d": ((), jnp.float32),
+              "e": ((3, 8), jnp.bfloat16)}
+    wp = {k: jnp.asarray(r.standard_normal((m,) + s), dt)
+          for k, (s, dt) in shapes.items()}
+    alive = (jnp.asarray(r.permutation(m) % 3 != 0, jnp.float32)
+             if faults else None)
+    eng = PhaseEngine(loss_fn, Momentum(lr=0.05, mu=0.9), PERIODIC,
+                      mesh=mesh)
+
+    def shard(wp, alive):
+        disp, own = eng._sharded_dispersion(wp, m, alive)
+        return disp, eng._gather_mean(own, wp)
+    disp, mean = jax.shard_map(
+        shard, mesh=mesh, in_specs=(P(axes), P()), out_specs=(P(), P()),
+        check_vma=False)(wp, alive)
+    if alive is None:
+        want = worker_dispersion(wp)
+        glob = jax.tree.map(lambda x: jnp.mean(widen(x), axis=0), wp)
+    else:
+        want = masked_dispersion_tree(wp, alive)
+        glob = jax.tree.map(lambda x: jnp.sum(
+            widen(x) * alive.reshape((-1,) + (1,) * (x.ndim - 1)), axis=0)
+            / jnp.sum(alive), wp)
+    close(disp, want, "dispersion")
+    for k, g in _as_leaves(glob, wp).items():
+        assert mean[k].dtype == g.dtype and mean[k].shape == g.shape, k
+        # f32 reassociation may move a rounding by one unit of the dtype
+        np.testing.assert_allclose(
+            np.asarray(mean[k], np.float32), np.asarray(g, np.float32),
+            rtol=float(jnp.finfo(g.dtype).eps), atol=1e-7, err_msg=k)
+
+
+for _ml in (1, 2):
+    CASES[f"column-dispersion-ml{_ml}"] = \
+        lambda ml=_ml: column_dispersion(ml)
+    CASES[f"column-dispersion-ml{_ml}-faults"] = \
+        lambda ml=_ml: column_dispersion(ml, faults=True)
+# rows in shard order over two worker axes
+CASES["column-dispersion-pod-data"] = lambda: column_dispersion(
+    2, mesh=jax.make_mesh((2, 4), ("pod", "data")))
+
 results = {}
 for name, case in CASES.items():
     try:
@@ -263,7 +324,10 @@ LEAF_CASES = [f"schedule-{n}" for n in (
     "outer", "hierarchical-groups-cross-shards", "ring-mix",
     "gossip-pairs-mix", "faults-crash-rejoin-straggle", "compressed-int8",
     "compressed-ring-mix", "indexed-data", "telemetry",
-    "sharded-start-state", "checkpoint-resume", "resume-in-place"]
+    "sharded-start-state", "checkpoint-resume", "resume-in-place",
+    "column-dispersion-ml1", "column-dispersion-ml1-faults",
+    "column-dispersion-ml2", "column-dispersion-ml2-faults",
+    "column-dispersion-pod-data"]
 
 
 def _run_8_devices(script: str):

@@ -4,8 +4,9 @@ loop's spans.
 Every part of the compiled phase step runs under one ``jax.named_scope``
 (``engine.batch`` / ``unpack`` / ``fwd_bwd`` / ``pack`` / ``update`` /
 ``average``), which reaches the compiled module as ``op_name`` metadata;
-no op carries two. ``PhaseEngine.run`` marks each phase's host loop with
-``engine.next_block`` -> ``engine.dispatch`` -> ``engine.fetch`` ->
+no op carries two, but for the mesh dispersion's ``engine.dispersion``,
+nested in ``engine.update``. ``PhaseEngine.run`` marks each phase's host
+loop with ``engine.next_block`` -> ``engine.dispatch`` -> ``engine.fetch`` ->
 ``engine.record`` spans on the driving thread, tagged with the phase's
 first step. Neither changes what is computed: the trained state is
 bit-identical with the scopes stripped and with a trace recording.
@@ -33,6 +34,8 @@ SCOPES = {"engine.batch", "engine.unpack", "engine.fwd_bwd", "engine.pack",
 HOST_LOOP = ["engine.next_block", "engine.dispatch", "engine.fetch",
              "engine.record"]
 _SCOPE = re.compile(r"engine\.[a-z_]+")
+# a sub-scope and the scope it runs inside
+NESTED = {"engine.dispersion": "engine.update"}
 
 
 def _loss(params, batch, rng):
@@ -66,13 +69,16 @@ def _engine(sched=None, **kw):
 
 def scopes_of(hlo_text: str) -> set:
     """The engine scopes named in a compiled module's ``op_name``
-    metadata; fails if one op's name stack holds two of them (a fused
-    op lists its parts' names joined by ``;``)."""
+    metadata; fails if one op's name stack holds two of them, but for
+    a sub-scope directly inside its own (``NESTED``; a fused op lists
+    its parts' names joined by ``;``)."""
     found = set()
     for name in re.findall(r'op_name="([^"]*)"', hlo_text):
         for part in name.split(";"):
             s = set(_SCOPE.findall(part))
-            assert len(s) <= 1, part
+            for sub in s & NESTED.keys():
+                assert f"{NESTED[sub]}/{sub}/" in part, part
+            assert len(s - NESTED.keys()) <= 1, part
             found |= s
     return found
 
@@ -115,12 +121,13 @@ eng = _engine(mesh=mesh)
 state, _ = eng.start_state(_params(), WORKERS, 0)
 stk = jax.tree.map(lambda *x: jnp.stack(x), *_batches()[:K])
 txt = PhaseEngine.run_phase.lower(eng, state, stk).compile().as_text()
-red = [m.group(1) for m in re.finditer(
-    r'all-reduce[^\n]*op_name="([^"]*)"', txt)]
+def scopes(op):
+    return sorted({{s for m in re.finditer(
+        op + r'[^\n]*op_name="([^"]*)"', txt)
+        for s in re.findall(r"engine\.[a-z_]+", m.group(1))}})
 print(json.dumps(dict(scopes=sorted(scopes_of(txt)),
-                      psum_scopes=sorted({{s for n in red for s in
-                                           re.findall(r"engine\.[a-z_]+",
-                                                      n)}}))))
+                      psum_scopes=scopes("all-reduce"),
+                      a2a_scopes=scopes("all-to-all"))))
 """
 
 
@@ -136,11 +143,14 @@ def test_sharded_phase_carries_every_scope():
     assert out.returncode == 0, out.stderr[-3000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
     # a mesh carries the leaves: every scope but the plane's unpack and
-    # pack
-    assert set(res["scopes"]) == SCOPES - {"engine.unpack", "engine.pack"}
-    # the per-step dispersion psum is the update's, the event's mean
-    # comes from it; the loss psum stays unscoped
-    assert "engine.update" in res["psum_scopes"]
+    # pack, and the dispersion's inside the update
+    assert set(res["scopes"]) == (SCOPES - {"engine.unpack", "engine.pack"}
+                                  | {"engine.dispersion"})
+    # the per-step dispersion's exchange and its scalar psum are the
+    # update's, under engine.dispersion; the event gathers its mean; the
+    # loss psum stays unscoped
+    assert res["a2a_scopes"] == ["engine.dispersion", "engine.update"]
+    assert "engine.dispersion" in res["psum_scopes"]
 
 
 def _stream():

@@ -15,7 +15,9 @@ holds at a time), and the persistent compilation cache is off around
 these compiles: a described device's executable cannot be read back.
 """
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -181,6 +183,21 @@ def test_leaf_carry_phase_compiles_without_the_plane(one_chip, monkeypatch):
 # the engine built it while a mesh could still carry the plane (f32
 # planes, gradient plane, jnp update; 16 layers)
 MESH_PLANE_16L_BYTES = 5_158_996_992
+# the leaf carry's compiled phase there, per device, while its per-step
+# dispersion psum'd every leaf's f32 column sums (16 layers)
+MESH_PSUM_16L_BYTES = 4_930_793_984
+
+
+def _collectives(hlo_text: str, op: str) -> list:
+    """The arrays each ``op`` (or its async start) of a compiled
+    module returns, as (dtype, shape) pairs, one list per op."""
+    out = []
+    for m in re.finditer(r"= (\(.*?\)|\S+) " + op + r"(?:-start)?\(",
+                         hlo_text):
+        out.append([(d, tuple(int(n) for n in dims.split(",") if n))
+                    for d, dims in re.findall(r"(\w+)\[([\d,]*)\]",
+                                              m.group(1))])
+    return out
 
 
 def test_leaf_carry_phase_compiles_without_the_plane_on_the_mesh(
@@ -190,7 +207,11 @@ def test_leaf_carry_phase_compiles_without_the_plane_on_the_mesh(
     periodic K=4, Momentum. A mesh carries the leaves; its sharded phase
     holds no (2, P) plane buffer and no Mosaic call, and needs less HBM
     per device than the plane carry of the same state did
-    (``MESH_PLANE_16L_BYTES``, 4.80 GiB). Cut to 16 layers, not 4: the
+    (``MESH_PLANE_16L_BYTES``, 4.80 GiB). The per-step dispersion is
+    reduced by columns: one all-to-all per leaf and local row, and no
+    all-reduce of a leaf's f32 column sums (only scalars and small
+    gathers), with no more HBM than when it psum'd them
+    (``MESH_PSUM_16L_BYTES``, 4.59 GiB). Cut to 16 layers, not 4: the
     leaf step's own temporaries (the tied embedding's f32 gradient
     above all, as on one chip) outweighed the planes of a few layers,
     and each layer narrowed the gap: 2.54 against 2.06 GiB at 4 layers,
@@ -226,5 +247,17 @@ def test_leaf_carry_phase_compiles_without_the_plane_on_the_mesh(
     row = f"[{workers // 4},{FlatSpec.of(tree.worker_params).width}]"
     assert row not in leaf.as_text()
     assert "tpu_custom_call" not in leaf.as_text()
+    text = leaf.as_text()
+    leaves = jax.tree.leaves(tree.worker_params)
+    assert len(_collectives(text, "all-to-all")) == \
+        workers // 4 * len(leaves)
+    smallest = min(4 * math.prod(x.shape[1:]) for x in leaves)
+    for arrays in _collectives(text, "all-reduce"):
+        for dtype, shape in arrays:
+            # bits from the HLO type's name (bf16, f32, s32; pred: 8)
+            nbytes = int(re.sub(r"\D", "", dtype) or 8) // 8 * \
+                math.prod(shape)
+            assert nbytes < smallest, (dtype, shape)
     print(f"per device: leaf carry {_memory_total(leaf)} B")
     assert _memory_total(leaf) < MESH_PLANE_16L_BYTES
+    assert _memory_total(leaf) <= MESH_PSUM_16L_BYTES
