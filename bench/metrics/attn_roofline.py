@@ -1,7 +1,7 @@
 """attn_roofline: the windowed attention kernel's share of the bf16
 peak. The work is :func:`attn_flops_per_step`, the attention term that
 ``bench.flops`` counts for the whole step (scores and weighted sums,
-forward and backward, over the mean causal context in the window;
+forward and backward, over each layer group's mean causal context;
 recomputation is not counted), for one device's share of the tokens,
 times the traced steps, over the kernel's device time (``attn_ms``) and
 the peak. None where the trace holds no call of the kernel. Layer:
@@ -19,11 +19,9 @@ _spec.loader.exec_module(attn_ms)
 
 
 def attn_flops_per_step(shape, traffic) -> float:
-    """layers x tokens per step x 12 x query width x mean context."""
+    """Tokens per step x :func:`bench.flops.attn_flops_per_token`."""
     tokens = traffic["workers"] * traffic["batch"] * traffic["seq"]
-    q = shape["heads"] * shape["head_dim"]
-    return (shape["layers"] * tokens * 12 * q
-            * flops.mean_context(traffic["seq"], shape["window"]))
+    return tokens * flops.attn_flops_per_token(shape, traffic["seq"])
 
 
 def read(ctx):

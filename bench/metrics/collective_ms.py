@@ -1,7 +1,9 @@
 """collective_ms: device time per step of every all-reduce, all-gather,
 reduce-scatter, all-to-all and collective-permute op (and their async
 starts and ends), averaged over the cell's devices. Layer: the
-averaging collective (``_flat_native_step_psum``, ``_psum_avg_event``)."""
+collectives of a mesh: the Eq. 4 dispersion's all-to-alls and scalar
+psum (``engine.dispersion``, inside ``engine.update``) and the
+all-mean event's all-gather (``engine.average``)."""
 from bench import trace as tr
 
 OPS = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",

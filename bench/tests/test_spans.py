@@ -53,8 +53,7 @@ SPANS = [["engine.next_block", 10, 2, 5, "python3#1"],
          ["engine.next_block", 184, 3, 13, "python3#1"],
          ["engine.dispatch", 187, 5, 13, "python3#1"]]
 MADE = {"ops": {0: OPS}, "spans": SPANS}
-READERS = ("pack_unpack_ms", "fwd_bwd_ms", "avg_event_ms", "host_bubble_ms",
-           "input_wait_ms")
+READERS = ("fwd_bwd_ms", "avg_event_ms", "host_bubble_ms", "input_wait_ms")
 
 
 @pytest.mark.parametrize("name,want", [
@@ -103,7 +102,8 @@ def _ctx(names, t0, t1, steps):
 def test_readers_on_a_made_up_trace(capsys):
     ctx = _ctx(MADE, 100, 200, 1)
     read = {n: spec.reader(n) for n in READERS}
-    assert read["pack_unpack_ms"](ctx) == pytest.approx(30e-6)
+    assert spans.scoped_ms(ctx, "plumbing", ("engine.unpack", "engine.pack")
+                           ) == pytest.approx(30e-6)
     assert read["fwd_bwd_ms"](ctx) == pytest.approx(30e-6)
     assert read["avg_event_ms"](ctx) == pytest.approx(5e-6)
     # host spans: the boundary 9 -> 13 ends inside [100, 200]
@@ -115,7 +115,7 @@ def test_readers_on_a_made_up_trace(capsys):
     # dynamic-update-slice with no op_name, counted under pack
     split = {m[0]: (float(m[1]), float(m[2])) for m in re.findall(
         r"\] (\w+): (\S+) ms per step, (\S+) of it", err)}
-    assert split["pack_unpack_ms"] == pytest.approx((30e-6, 6e-6))
+    assert split["plumbing"] == pytest.approx((30e-6, 6e-6))
     assert split["fwd_bwd_ms"] == pytest.approx((30e-6, 0.0))
     # per step: two steps halve the device readings
     ctx.trace.steps = 2
@@ -128,9 +128,9 @@ def test_readers_find_nothing_in_a_trace_without_names():
     bare = {"ops": {0: [e[:3] + ["", ""] for e in OPS]}, "spans": []}
     for names in (bare, None):
         ctx = _ctx(names, 100, 200, 1)
-        assert [spec.reader(n)(ctx) for n in READERS] == [None] * 5
+        assert [spec.reader(n)(ctx) for n in READERS] == [None] * 4
     ctx.trace = None
-    assert [spec.reader(n)(ctx) for n in READERS] == [None] * 5
+    assert [spec.reader(n)(ctx) for n in READERS] == [None] * 4
 
 
 def test_spans_of_a_profiled_run_on_the_cpu(tmp_path):
@@ -319,8 +319,6 @@ def test_recorded_v5e_boundary():
                                                   "engine.pack"]
     ctx = _ctx(f, t0, t1, 1)
     read = {n: spec.reader(n)(ctx) for n in READERS}
-    assert read["pack_unpack_ms"] == pytest.approx(
-        (26518574 + 6625563) / 1e6)
     assert read["fwd_bwd_ms"] == pytest.approx(3586606 / 1e6)
     assert read["avg_event_ms"] == pytest.approx(18208229 / 1e6)
     # fetch of step 17's phase ends at 1,105,942,248; the dispatch of

@@ -61,12 +61,8 @@ def test_recorded_v5e_stretch():
     top = tr.top_ops(t["devices"][0], t0, t1, n=1)[0]
     assert top[0].startswith("tpu_custom_call (f32[2,361821120]")
     assert top[1] == pytest.approx(0.027030382)
-    # one step's update: 11,578,275,840 B at 819 GB/s in 27.030382 ms
     ctx = _ctx(t, t0, t1, steps=1)
     assert ctx.width == 361_821_120
-    assert flops.update_bytes(2, ctx.width, 1) == 11_578_275_840
-    share = spec.reader("update_roofline")(ctx)
-    assert share == pytest.approx(100 * 11_578_275_840 / 819e9 / 0.027030382)
     assert spec.reader("collective_ms")(ctx) is None   # one chip
     ctx.trace.busy_s = [86_344_794 / 1e9]
     assert spec.reader("device_idle_frac")(ctx) == pytest.approx(
